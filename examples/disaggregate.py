@@ -47,6 +47,7 @@ from repro.serve.engine import InferenceEngine, Request
 from repro.serve.kvstore import KVStore, StoreConfig
 from repro.serve.kvstore.remote import (LoopbackTransport, TCPStoreServer,
                                         TCPTransport)
+from repro.launch.compile_cache import use_compile_cache
 
 MANIFEST = "manifest"                   # blob announcing the shipped uids
 
@@ -194,6 +195,7 @@ def role_decode(args) -> int:
 
 
 def main(argv=None) -> int:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--tcp", action="store_true",
                     help="single process, but through a localhost TCP peer")

@@ -16,6 +16,7 @@ from repro.configs.base import RoutingConfig
 from repro.core.attention import full_attention
 from repro.core.kmeans import init_kmeans
 from repro.core.routing import routed_attention
+from repro.launch.compile_cache import use_compile_cache
 
 
 def bench(fn, *args, reps=3):
@@ -27,6 +28,7 @@ def bench(fn, *args, reps=3):
 
 
 def main():
+    use_compile_cache()
     B, H, dh = 1, 4, 64
     print(f"{'n':>7} {'k=sqrt(n)':>9} {'full ms':>9} {'routing ms':>11} "
           f"{'speedup':>8}")

@@ -12,9 +12,11 @@ from repro.configs.base import (ModelConfig, RoutingConfig, RunConfig,
 from repro.data.synthetic import SyntheticLoader
 from repro.serve.serving import init_cache, make_serve_step, prefill
 from repro.train.train_step import init_train_state, make_train_step
+from repro.launch.compile_cache import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     cfg = ModelConfig(
         name="rt-quickstart", family="dense", num_layers=2, d_model=128,
         num_heads=4, num_kv_heads=4, d_ff=256, vocab_size=64,

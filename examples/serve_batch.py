@@ -16,9 +16,11 @@ import jax
 from repro.configs.base import ModelConfig, RoutingConfig
 from repro.models.model import init_model
 from repro.serve.engine import InferenceEngine, Request, SamplingParams
+from repro.launch.compile_cache import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     cfg = ModelConfig(
         name="rt-serve", family="dense", num_layers=4, d_model=256,
         num_heads=8, num_kv_heads=4, d_ff=512, vocab_size=1024,

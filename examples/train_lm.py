@@ -16,6 +16,7 @@ from repro.configs.base import (RunConfig, TrainConfig, with_overrides,
                                 RoutingConfig, ModelConfig)
 from repro.data.synthetic import SyntheticLoader
 from repro.train.trainer import Trainer
+from repro.launch.compile_cache import use_compile_cache
 
 
 def default_100m() -> ModelConfig:
@@ -30,6 +31,7 @@ def default_100m() -> ModelConfig:
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="rt-100m",
                     choices=["rt-100m"] + sorted(ARCHS))

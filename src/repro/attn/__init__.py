@@ -19,7 +19,6 @@ matrix in tests/test_attn_registry.py. See DESIGN.md §8.
 """
 from __future__ import annotations
 
-import os
 from typing import NamedTuple, Optional
 
 import jax
@@ -46,13 +45,12 @@ class AttnOutput(NamedTuple):
 
 
 def _platform(platform: Optional[str]) -> str:
-    """Resolution platform: explicit arg > REPRO_ATTN_PLATFORM env >
-    detected backend. The env override (paired with
-    REPRO_FORCE_INTERPRET=1, see kernels.common.default_interpret) lets
-    tests exercise TPU auto-selection — fused apply, paged decode — end
-    to end on a CPU host."""
-    return (platform or os.environ.get("REPRO_ATTN_PLATFORM")
-            or jax.default_backend())
+    """Resolution platform: the explicit arg, else the backend the
+    program runs on. Kernels still compile only where the program really
+    runs on a TPU (``interpret`` derives from the real backend), so a
+    caller that resolves for "tpu" on a CPU host runs the TPU backends in
+    interpret mode."""
+    return platform or jax.default_backend()
 
 
 def _grad_guard(out, name):
@@ -101,7 +99,7 @@ def attend(spec: AttentionSpec, q, k, v, *, state=None, positions=None,
     clear BackendResolutionError instead of an opaque tracing failure.
     """
     plat = _platform(platform)
-    interpret = _default_interpret(None, plat)
+    interpret = _default_interpret(None)
     if cache is not None:
         if pad_mask is not None:
             # decode validity lives in the cache (ring positions, page

@@ -551,10 +551,11 @@ registry.register(Backend(
 # back to the reference; the shard_map train path (per-device programs,
 # no mesh at attend) runs the kernel in distributed training (§9).
 # No max_seq_elems cap: the kernel auto-switches its memory plan at the
-# VMEM residency budget (kernels.common.FUSED_RESIDENT_ELEMS, N·dh =
-# 8192·128) — whole-plane VMEM residency below it, double-buffered
-# per-row DMA paging above (VMEM bounded by the tile sizes, not N), so
-# paper-scale N=8k–32k stays fused forward and backward.
+# VMEM residency budget (kernels.common.FUSED_RESIDENT_BYTES, bytes of
+# the resident planes) — whole-plane VMEM residency below it,
+# double-buffered per-row DMA paging above (VMEM bounded by the tile
+# sizes, not N), so paper-scale N=8k–32k stays fused forward and
+# backward.
 registry.register(Backend(
     variant="routing", impl="pallas_fused",
     apply=_make_routing_apply("pallas_fused"), priority=20,

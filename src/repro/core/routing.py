@@ -119,8 +119,8 @@ def routed_attention(q: jax.Array,
     pad_mask: (B, N) bool, True = real token. Padding is excluded from
         top-k selection, attention, and centroid updates (paper Section 4.1).
     impl: "xla" reference | "pallas" gathered kernel | "pallas_fused"
-        gather-free kernel (sequence-layout q/k/v, scalar-prefetch
-        membership — no (B,H,k,w,dh) q/k/v intermediates in HBM; the
+        gather-free kernel (sequence-layout q/k/v, SMEM membership
+        blocks — no (B,H,k,w,dh) q/k/v intermediates in HBM; the
         memory plan auto-switches to double-buffered VMEM paging past the
         residency budget) | "pallas_fused_paged" / "pallas_fused_unpaged"
         force that plan.
@@ -181,8 +181,8 @@ def routed_attention(q: jax.Array,
 
     if impl in _FUSED_IMPLS:
         # gather-free: q/k/v stay in sequence layout; the kernel pulls
-        # member rows through the scalar-prefetched indices and the mask
-        # reads the (B,N) position/validity arrays directly. The paged
+        # member rows through per-cluster SMEM index blocks and the mask
+        # compares pre-gathered member positions. The paged
         # suffix forces the kernel's memory plan; bare "pallas_fused"
         # auto-switches on the VMEM residency budget.
         from repro.kernels import ops as kops

@@ -1,7 +1,5 @@
 """Shared plumbing for the Pallas kernels.
 
-Two things every kernel file needs:
-
 * ``default_interpret``: the platform-derived Pallas interpret default.
   Kernels compile with Mosaic only on TPU; everywhere else (CPU CI, the
   dev container) they run in interpret mode with identical semantics.
@@ -10,60 +8,67 @@ Two things every kernel file needs:
   benchmark interpret mode (and a CPU caller cannot crash into Mosaic).
 * ``float0_like``: custom-VJP cotangents for integer operands (membership
   indices, positions). jax requires ``float0`` for int-dtype primals.
-* ``FUSED_RESIDENT_ELEMS`` / ``fused_paged_default``: the shared rule for
-  when the fused routing kernel keeps the whole (N, dh) sequence plane
-  resident in VMEM vs pages it through double-buffered DMA chunks. The
-  kernel layer, the backend registry, and the benches all derive from
-  this one constant so the auto-switch point cannot drift between them.
+* ``FUSED_RESIDENT_BYTES`` / ``fused_paged_default`` /
+  ``fused_vmem_limit``: the shared rule for when the fused routing kernel
+  keeps whole (N, dh) sequence planes resident in VMEM vs streams member
+  rows from HBM, and the VMEM limit each plan compiles with. The kernel
+  layer, the backend registry, and the benches all derive from these so
+  the switch point cannot drift between them.
 """
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax
 import numpy as np
-from jax.experimental.pallas import tpu as pltpu
-
-# jax renamed TPUCompilerParams -> CompilerParams around 0.5; support both.
-CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 NEG = -1e9
 
-# N*dh budget for whole-plane VMEM residency in the fused routing kernel.
-# At or below it the unpaged kernel (plane as a pipelined input block) is
-# the fast path: one bulk DMA per (batch*head) plane, row pulls from VMEM.
-# Above it the paged kernel streams member rows from HBM instead — was the
-# hard `max_seq_elems` registration cliff before the paged path existed.
-FUSED_RESIDENT_ELEMS = 8192 * 128
+# VMEM budget for the fused routing kernel's resident planes. The plan
+# holds each (N, dh) float32 plane of the current batch·head as a
+# pipelined input block, which the pipeline double-buffers. At rt-enwik8
+# widths (N=8192, dh=128) that is 16 MiB shared-QK (q, v) and 24 MiB with
+# separate keys — both resident; N=16384 pages. v5e has 128 MiB of VMEM;
+# the compile, not this constant, is the final word (tests/test_tpu_compile).
+FUSED_RESIDENT_BYTES = 24 << 20
+# room for the tiles, accumulators and pipelined per-cluster blocks
+# around the planes (well under 1 MiB at bq = bk = 128, dh = 128)
+_TILE_HEADROOM_BYTES = 8 << 20
 
 
-def fused_paged_default(n: int, dh: int, paged: Optional[bool] = None) -> bool:
+def fused_resident_bytes(n: int, dh: int, planes: int) -> int:
+    """VMEM bytes the resident plan's ``planes`` (N, dh) float32 planes
+    take, double-buffered."""
+    return planes * 2 * n * dh * 4
+
+
+# seq_len·head_dim cap of the forced resident plan, for the registry:
+# the three-plane (separate keys) worst case
+FUSED_RESIDENT_ELEMS = FUSED_RESIDENT_BYTES // fused_resident_bytes(1, 1, 3)
+
+
+def fused_paged_default(n: int, dh: int, planes: int,
+                        paged: Optional[bool] = None) -> bool:
     """Resolve a ``paged`` argument for the fused routing kernel: None
-    auto-pages exactly when the sequence plane would blow the VMEM
-    residency budget; an explicit bool wins."""
+    pages exactly when the resident planes would exceed
+    ``FUSED_RESIDENT_BYTES``; an explicit bool wins."""
     if paged is None:
-        return n * dh > FUSED_RESIDENT_ELEMS
+        return fused_resident_bytes(n, dh, planes) > FUSED_RESIDENT_BYTES
     return bool(paged)
 
 
-def default_interpret(interpret: Optional[bool] = None,
-                      platform: Optional[str] = None) -> bool:
-    """Resolve an ``interpret`` argument: None derives from the platform
-    (compiled on TPU, interpret elsewhere); an explicit bool wins.
-    ``platform`` overrides the detected backend (attn.attend passes the
-    platform it resolved backends against) — this function is the single
-    source of the rule.
+def fused_vmem_limit(n: int, dh: int, planes: int, resident: bool) -> int:
+    """``vmem_limit_bytes`` for a fused routing kernel call."""
+    return ((fused_resident_bytes(n, dh, planes) if resident else 0)
+            + _TILE_HEADROOM_BYTES)
 
-    ``REPRO_FORCE_INTERPRET=1`` forces interpret mode for derived (None)
-    arguments: paired with ``REPRO_ATTN_PLATFORM=tpu`` it lets tests run
-    the full TPU backend-resolution path (fused apply + paged decode) on
-    a CPU host without crashing into Mosaic. Explicit bools still win.
-    """
+
+def default_interpret(interpret: Optional[bool] = None) -> bool:
+    """Resolve an ``interpret`` argument: None derives from the backend
+    the program runs on (compiled on TPU, interpret elsewhere); an
+    explicit bool wins."""
     if interpret is None:
-        if os.environ.get("REPRO_FORCE_INTERPRET", "") not in ("", "0"):
-            return True
-        return (platform or jax.default_backend()) != "tpu"
+        return jax.default_backend() != "tpu"
     return bool(interpret)
 
 

@@ -34,7 +34,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import NEG as _NEG
-from repro.kernels.common import CompilerParams as _CompilerParams
 from repro.kernels.common import default_interpret
 
 
@@ -84,7 +83,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
     def _done():
         l = jnp.maximum(l_ref[...], 1e-30)
         o_ref[0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
-        lse_ref[0] = m_ref[...] + jnp.log(l)
+        lse_ref[0, 0] = m_ref[...] + jnp.log(l)
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref, dq_ref,
@@ -105,8 +104,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref, dq_ref,
         k = k_ref[0].astype(jnp.float32)
         v = v_ref[0].astype(jnp.float32)
         do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0]
-        dsum = dsum_ref[0]
+        lse = lse_ref[0, 0]
+        dsum = dsum_ref[0, 0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
         p = jnp.exp(s - lse[:, None])
         if causal:
@@ -140,8 +139,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref,
         k = k_ref[0].astype(jnp.float32)
         v = v_ref[0].astype(jnp.float32)
         do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0]
-        dsum = dsum_ref[0]
+        lse = lse_ref[0, 0]
+        dsum = dsum_ref[0, 0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
         p = jnp.exp(s - lse[:, None])
         if causal:
@@ -189,18 +188,18 @@ def _fwd_call(q, k, v, causal, bq, bk, interpret):
         ],
         out_specs=[
             pl.BlockSpec((1, bq, dh), lambda bh, iq, ik: (bh, iq, 0)),
-            pl.BlockSpec((1, bq), lambda bh, iq, ik: (bh, iq)),
+            pl.BlockSpec((1, 1, bq), lambda bh, iq, ik: (bh, 0, iq)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, N, dh), q.dtype),
-            jax.ShapeDtypeStruct((B * H, N), jnp.float32),
+            jax.ShapeDtypeStruct((B * H, 1, N), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, dh), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf)
@@ -214,14 +213,14 @@ def _bwd_call(q, k, v, out, lse, do, causal, bq, bk, interpret):
     qf, kf, vf = _flatten(q, k, v)
     dof = do.reshape(B * H, N, dh)
     dsum = (do.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)
-    dsum = dsum.reshape(B * H, N)
+    dsum = dsum.reshape(B * H, 1, N)
     kv_index = _kv_index(H, Hkv)
     scale = 1.0 / (dh ** 0.5)
-    params = _CompilerParams(
+    params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
 
     q_at = lambda bh, iq, ik: (bh, iq, 0)
-    r_at = lambda bh, iq, ik: (bh, iq)
+    r_at = lambda bh, iq, ik: (bh, 0, iq)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, bq=bq, bk=bk, causal=causal,
                           scale=scale),
@@ -231,8 +230,8 @@ def _bwd_call(q, k, v, out, lse, do, causal, bq, bk, interpret):
             pl.BlockSpec((1, bk, dh), kv_index),
             pl.BlockSpec((1, bk, dh), kv_index),
             pl.BlockSpec((1, bq, dh), q_at),
-            pl.BlockSpec((1, bq), r_at),
-            pl.BlockSpec((1, bq), r_at),
+            pl.BlockSpec((1, 1, bq), r_at),
+            pl.BlockSpec((1, 1, bq), r_at),
         ],
         out_specs=pl.BlockSpec((1, bq, dh), q_at),
         out_shape=jax.ShapeDtypeStruct((B * H, N, dh), jnp.float32),
@@ -243,7 +242,7 @@ def _bwd_call(q, k, v, out, lse, do, causal, bq, bk, interpret):
 
     # dk/dv per *query* head; the kv-head group sum happens below in XLA
     q_at2 = lambda bh, ik, iq: (bh, iq, 0)
-    r_at2 = lambda bh, ik, iq: (bh, iq)
+    r_at2 = lambda bh, ik, iq: (bh, 0, iq)
     kv_at2 = lambda bh, ik, iq: kv_index(bh, 0, ik)
     k_out = lambda bh, ik, iq: (bh, ik, 0)
     dk, dv = pl.pallas_call(
@@ -255,8 +254,8 @@ def _bwd_call(q, k, v, out, lse, do, causal, bq, bk, interpret):
             pl.BlockSpec((1, bk, dh), kv_at2),
             pl.BlockSpec((1, bk, dh), kv_at2),
             pl.BlockSpec((1, bq, dh), q_at2),
-            pl.BlockSpec((1, bq), r_at2),
-            pl.BlockSpec((1, bq), r_at2),
+            pl.BlockSpec((1, 1, bq), r_at2),
+            pl.BlockSpec((1, 1, bq), r_at2),
         ],
         out_specs=[
             pl.BlockSpec((1, bk, dh), k_out),

@@ -25,9 +25,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import NEG as _NEG
-from repro.kernels.common import CompilerParams as _CompilerParams
 from repro.kernels.common import default_interpret
 
 
@@ -53,7 +53,7 @@ def _kernel(q_ref, kp_ref, kc_ref, kn_ref, vp_ref, vc_ref, vn_ref, o_ref,
     l = jnp.maximum(p.sum(-1, keepdims=True), 1e-30)
     o = jax.lax.dot_general(p / l, v, (((1,), (0,)), ((), ())))
     o_ref[0] = o.astype(o_ref.dtype)
-    lse_ref[0] = (m + jnp.log(l))[:, 0]
+    lse_ref[0, 0] = (m + jnp.log(l))[:, 0]
 
 
 def _bwd_dq_kernel(q_ref, kp_ref, kc_ref, kn_ref, vp_ref, vc_ref, vn_ref,
@@ -66,8 +66,8 @@ def _bwd_dq_kernel(q_ref, kp_ref, kc_ref, kn_ref, vp_ref, vc_ref, vn_ref,
     k = jnp.concatenate([x.astype(jnp.float32) for x in ks], axis=0)
     v = jnp.concatenate([x.astype(jnp.float32) for x in vs], axis=0)
     do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0]
-    dsum = dsum_ref[0]
+    lse = lse_ref[0, 0]
+    dsum = dsum_ref[0, 0]
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
     cw = k.shape[0]
     pos_q = b * w + jax.lax.broadcasted_iota(jnp.int32, (w, cw), 0)
@@ -101,8 +101,8 @@ def _bwd_dkv_kernel(k_ref, v_ref, *refs, w, causal, scale, nb, deltas):
                                            lse_refs, dsum_refs):
         q = q_r[0].astype(jnp.float32)
         do = do_r[0].astype(jnp.float32)
-        lse = lse_r[0]
-        dsum = dsum_r[0]
+        lse = lse_r[0, 0]
+        dsum = dsum_r[0, 0]
         # intended (unclamped) query positions: rows outside [0, nb*w)
         # belong to a block that does not exist and mask to zero
         pos_q = (b + d) * w + jax.lax.broadcasted_iota(jnp.int32, (w, w), 0)
@@ -142,7 +142,7 @@ def _q_at(nb, delta):
 
 def _r_at(nb, delta):
     def index(bh, b):
-        return (bh, jnp.clip(b + delta, 0, nb - 1))
+        return (bh, 0, jnp.clip(b + delta, 0, nb - 1))
     return index
 
 
@@ -164,13 +164,13 @@ def _fwd_call(q, k, v, w, causal, interpret):
         ],
         out_specs=[
             pl.BlockSpec((1, w, dh), lambda bh, b: (bh, b, 0)),
-            pl.BlockSpec((1, w), lambda bh, b: (bh, b)),
+            pl.BlockSpec((1, 1, w), lambda bh, b: (bh, 0, b)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, N, dh), q.dtype),
-            jax.ShapeDtypeStruct((B * H, N), jnp.float32),
+            jax.ShapeDtypeStruct((B * H, 1, N), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, kf, kf, vf, vf, vf)
@@ -186,12 +186,12 @@ def _bwd_call(q, k, v, lse, out, do, w, causal, interpret):
     vf = v.reshape(B * Hkv, N, dh)
     dof = do.reshape(B * H, N, dh)
     dsum = (do.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)
-    dsum = dsum.reshape(B * H, N)
+    dsum = dsum.reshape(B * H, 1, N)
     scale = 1.0 / (dh ** 0.5)
-    params = _CompilerParams(dimension_semantics=("parallel", "arbitrary"))
+    params = pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
     kv_spec = lambda d: pl.BlockSpec((1, w, dh), _kv_at(H, Hkv, nb, d))
     q_spec = lambda d: pl.BlockSpec((1, w, dh), _q_at(nb, d))
-    r_spec = lambda d: pl.BlockSpec((1, w), _r_at(nb, d))
+    r_spec = lambda d: pl.BlockSpec((1, 1, w), _r_at(nb, d))
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, w=w, causal=causal, scale=scale,

@@ -8,32 +8,31 @@ whole sequence, four in shared-QK mode before the dedupe) and the kernel
 streams the cluster blocks with flash-style online softmax.
 
 ``routed_attention_fused`` — the *gather-free* kernel: q/k/v stay in
-sequence layout (B,H,N,dh); the (B,H,k,w) membership indices ride in as
-scalar-prefetch operands (``PrefetchScalarGridSpec``, SMEM), the per-
-(batch·head) sequence plane is the kernel's input block, and each grid
-step pulls exactly the bq/bk member rows it needs from VMEM — the same
-page-table trick TPU paged attention uses, at row granularity. No gathered
-(B,H,k,w,dh) q/k/v tensor ever reaches HBM, and shared-QK causal mode
-reads keys from the q plane (one VMEM-resident buffer instead of two).
-Positions are read from the (B,N) sequence-level arrays through the same
-indices, so the causal mask still compares original positions
-(pos_q >= pos_k) and padded keys arrive pre-encoded as pos = SENTINEL.
+sequence layout (B,H,N,dh) and every grid step pulls exactly the bq/bk
+member rows of its cluster tile with per-row ``make_async_copy`` DMAs into
+revolving double-buffered VMEM slots (tile ik+1's DMAs issue before tile
+ik's compute runs) — the page-table trick TPU paged attention uses, at row
+granularity. The cluster's (w,) membership indices arrive per grid step as
+a blocked SMEM input (1 KiB at w=256, whatever B, H or N), and the member
+positions for the causal mask as lane-dense int32 VMEM blocks pre-gathered
+in XLA (4 B/row). No gathered (B,H,k,w,dh) q/k/v tensor ever reaches HBM,
+and shared-QK causal mode reads keys from the q plane.
 
-The fused kernel has two memory plans behind one entry point
-(``paged=None`` auto-switches on the ``FUSED_RESIDENT_ELEMS`` budget):
+The fused kernel has two memory plans that differ only in where the row
+DMAs read from (``paged=None`` auto-switches on the byte budget in
+kernels/common.py):
 
-* *unpaged* — the sequence plane is the kernel's input block (whole
-  (N, dh) plane resident in VMEM, one bulk DMA per batch·head). Fastest
-  while the plane fits; refuses nothing but wastes nothing either.
-* *paged* — q/k/v stay in HBM (``memory_space=ANY``); every grid step
-  pulls exactly the bq/bk member rows of its cluster tile with per-row
-  ``make_async_copy`` DMAs into revolving double-buffered VMEM slots
-  (tile ik+1's DMAs issue before tile ik's compute runs), so VMEM live
-  bytes are O(bq·dh + 4·bk·dh) — independent of N. Membership indices
-  AND pre-gathered int32 positions ride in SMEM as scalar-prefetch
-  operands (4 B/row, so the causal mask needs no position DMAs). This
-  kills the old ``seq_len·head_dim ≈ 1M`` registration cliff: paper-scale
-  N=8k–32k runs fused, forward and backward.
+* *resident* — the (N, dh) sequence plane of the current batch·head is
+  the kernel's input block: one bulk DMA per plane, row DMAs VMEM->VMEM.
+* *paged* — q/k/v stay in HBM (``memory_space=ANY``) and the row DMAs
+  read HBM directly, so VMEM live bytes are O(bq·dh + 4·bk·dh) —
+  independent of N.
+
+Same kernels, same tiles, same arithmetic: the two plans' forward outputs
+are bit-identical. Single-row DMAs need 32-bit rows (Mosaic tiles 16-bit
+dtypes two rows per sublane), so 16-bit planes are widened to float32 in
+XLA before the kernel; the kernel computes in float32 either way, its
+matmuls at full float32 precision (``_dot``).
 
 Both kernels are differentiable (``jax.custom_vjp``): the forward emits
 per-row lse stats (m + log l); the backward recomputes p = exp(s - lse)
@@ -42,6 +41,9 @@ tile by tile — no (w x w) matrix is ever stored — and runs a dq kernel
 cluster-block structure. The fused backward produces per-cluster gradient
 blocks and scatter-adds them to sequence layout in XLA (duplicate
 memberships accumulate, exactly the transpose of the implicit gather).
+
+Row stats (lse, dsum) and positions travel as (..., 1, w) arrays so that
+their blocks' last two dims, (1, b), tile on the chip.
 
 Grid: (B·H·k clusters, w/bq, w/bk) gathered; (B·H, k, w/bq, w/bk) fused,
 KV axis sequential; (m, l, acc) scratch in VMEM. MXU-aligned: bq = bk =
@@ -57,11 +59,21 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import NEG as _NEG
-from repro.kernels.common import CompilerParams as _CompilerParams
 from repro.kernels.common import (default_interpret, float0_like,
-                                  fused_paged_default)
+                                  fused_paged_default, fused_vmem_limit)
 
 SENTINEL = 2 ** 30          # python int: usable inside the kernel body
+
+
+def _dot(a, b, ca, cb):
+    """Contract dim ``ca`` of ``a`` with dim ``cb`` of ``b`` in full
+    float32. Mosaic's default rounds MXU operands to bfloat16; shared-QK
+    routing softmax is saturated (each row's self score is sqrt(dh)), so
+    the backward's ds = p·(dp - dsum) is a difference of near-equal terms,
+    and a bfloat16-rounded dp against the float32 dsum buries dq/dk in
+    rounding noise."""
+    return jax.lax.dot_general(a, b, (((ca,), (cb,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST)
 
 
 def _keep_mask(pq, pk, causal):
@@ -91,9 +103,9 @@ def _kernel(q_ref, k_ref, v_ref, pq_ref, pk_ref, o_ref, lse_ref,
     q = q_ref[0].astype(jnp.float32)                  # (bq, dh)
     k = k_ref[0].astype(jnp.float32)                  # (bk, dh)
     v = v_ref[0].astype(jnp.float32)
-    pq = pq_ref[0]                                    # (bq,) int32
-    pk = pk_ref[0]                                    # (bk,) int32
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
+    pq = pq_ref[0, 0]                                 # (bq,) int32
+    pk = pk_ref[0, 0]                                 # (bk,) int32
+    s = _dot(q, k, 1, 1) * scale
     keep = _keep_mask(pq, pk, causal)
     s = jnp.where(keep, s, _NEG)
     m_prev = m_ref[...]
@@ -102,14 +114,14 @@ def _kernel(q_ref, k_ref, v_ref, pq_ref, pk_ref, o_ref, lse_ref,
     corr = jnp.exp(m_prev - m_new)
     l_ref[...] = l_ref[...] * corr + p.sum(-1)
     acc_ref[...] = acc_ref[...] * corr[:, None] + \
-        jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())))
+        _dot(p, v, 1, 0)
     m_ref[...] = m_new
 
     @pl.when(ik == nk - 1)
     def _done():
         l = jnp.maximum(l_ref[...], 1e-30)
         o_ref[0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
-        lse_ref[0] = m_ref[...] + jnp.log(l)
+        lse_ref[0, 0] = m_ref[...] + jnp.log(l)
 
 
 def _g_dq_kernel(q_ref, k_ref, v_ref, pq_ref, pk_ref, do_ref, lse_ref,
@@ -125,12 +137,12 @@ def _g_dq_kernel(q_ref, k_ref, v_ref, pq_ref, pk_ref, do_ref, lse_ref,
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
     do = do_ref[0].astype(jnp.float32)
-    keep = _keep_mask(pq_ref[0], pk_ref[0], causal)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
-    p = jnp.where(keep, jnp.exp(s - lse_ref[0][:, None]), 0.0)
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))
-    ds = p * (dp - dsum_ref[0][:, None]) * scale
-    dq_acc[...] += jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())))
+    keep = _keep_mask(pq_ref[0, 0], pk_ref[0, 0], causal)
+    s = _dot(q, k, 1, 1) * scale
+    p = jnp.where(keep, jnp.exp(s - lse_ref[0, 0][:, None]), 0.0)
+    dp = _dot(do, v, 1, 1)
+    ds = p * (dp - dsum_ref[0, 0][:, None]) * scale
+    dq_acc[...] += _dot(ds, k, 1, 0)
 
     @pl.when(ik == nk - 1)
     def _done():
@@ -152,13 +164,13 @@ def _g_dkv_kernel(q_ref, k_ref, v_ref, pq_ref, pk_ref, do_ref, lse_ref,
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
     do = do_ref[0].astype(jnp.float32)
-    keep = _keep_mask(pq_ref[0], pk_ref[0], causal)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
-    p = jnp.where(keep, jnp.exp(s - lse_ref[0][:, None]), 0.0)
-    dv_acc[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())))
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))
-    ds = p * (dp - dsum_ref[0][:, None]) * scale
-    dk_acc[...] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())))
+    keep = _keep_mask(pq_ref[0, 0], pk_ref[0, 0], causal)
+    s = _dot(q, k, 1, 1) * scale
+    p = jnp.where(keep, jnp.exp(s - lse_ref[0, 0][:, None]), 0.0)
+    dv_acc[...] += _dot(p, do, 0, 0)
+    dp = _dot(do, v, 1, 1)
+    ds = p * (dp - dsum_ref[0, 0][:, None]) * scale
+    dk_acc[...] += _dot(ds, q, 0, 0)
 
     @pl.when(iq == nq - 1)
     def _done():
@@ -176,23 +188,23 @@ def _g_fwd_call(qf, kf, vf, pqf, pkf, causal, bq, bk, interpret):
             pl.BlockSpec((1, bq, dh), lambda c, iq, ik: (c, iq, 0)),
             pl.BlockSpec((1, bk, dh), lambda c, iq, ik: (c, ik, 0)),
             pl.BlockSpec((1, bk, dh), lambda c, iq, ik: (c, ik, 0)),
-            pl.BlockSpec((1, bq), lambda c, iq, ik: (c, iq)),
-            pl.BlockSpec((1, bk), lambda c, iq, ik: (c, ik)),
+            pl.BlockSpec((1, 1, bq), lambda c, iq, ik: (c, 0, iq)),
+            pl.BlockSpec((1, 1, bk), lambda c, iq, ik: (c, 0, ik)),
         ],
         out_specs=[
             pl.BlockSpec((1, bq, dh), lambda c, iq, ik: (c, iq, 0)),
-            pl.BlockSpec((1, bq), lambda c, iq, ik: (c, iq)),
+            pl.BlockSpec((1, 1, bq), lambda c, iq, ik: (c, 0, iq)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n, w, dh), qf.dtype),
-            jax.ShapeDtypeStruct((n, w), jnp.float32),
+            jax.ShapeDtypeStruct((n, 1, w), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, dh), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf, pqf, pkf)
@@ -203,13 +215,14 @@ def _g_bwd_call(qf, kf, vf, pqf, pkf, out, lse, do, causal, bq, bk,
                 interpret):
     n, w, dh = qf.shape
     dsum = (do.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)
+    dsum = dsum.reshape(n, 1, w)
     scale = 1.0 / (dh ** 0.5)
-    params = _CompilerParams(
+    params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
     q_at = lambda c, iq, ik: (c, iq, 0)
     k_at = lambda c, iq, ik: (c, ik, 0)
-    rq_at = lambda c, iq, ik: (c, iq)
-    rk_at = lambda c, iq, ik: (c, ik)
+    rq_at = lambda c, iq, ik: (c, 0, iq)
+    rk_at = lambda c, iq, ik: (c, 0, ik)
     dq = pl.pallas_call(
         functools.partial(_g_dq_kernel, causal=causal, scale=scale),
         grid=(n, w // bq, w // bk),
@@ -217,11 +230,11 @@ def _g_bwd_call(qf, kf, vf, pqf, pkf, out, lse, do, causal, bq, bk,
             pl.BlockSpec((1, bq, dh), q_at),
             pl.BlockSpec((1, bk, dh), k_at),
             pl.BlockSpec((1, bk, dh), k_at),
-            pl.BlockSpec((1, bq), rq_at),
-            pl.BlockSpec((1, bk), rk_at),
+            pl.BlockSpec((1, 1, bq), rq_at),
+            pl.BlockSpec((1, 1, bk), rk_at),
             pl.BlockSpec((1, bq, dh), q_at),
-            pl.BlockSpec((1, bq), rq_at),
-            pl.BlockSpec((1, bq), rq_at),
+            pl.BlockSpec((1, 1, bq), rq_at),
+            pl.BlockSpec((1, 1, bq), rq_at),
         ],
         out_specs=pl.BlockSpec((1, bq, dh), q_at),
         out_shape=jax.ShapeDtypeStruct((n, w, dh), jnp.float32),
@@ -233,8 +246,8 @@ def _g_bwd_call(qf, kf, vf, pqf, pkf, out, lse, do, causal, bq, bk,
     # swapped grid: key tile parallel, query sweep sequential
     q_at2 = lambda c, ik, iq: (c, iq, 0)
     k_at2 = lambda c, ik, iq: (c, ik, 0)
-    rq_at2 = lambda c, ik, iq: (c, iq)
-    rk_at2 = lambda c, ik, iq: (c, ik)
+    rq_at2 = lambda c, ik, iq: (c, 0, iq)
+    rk_at2 = lambda c, ik, iq: (c, 0, ik)
     dk, dv = pl.pallas_call(
         functools.partial(_g_dkv_kernel, causal=causal, scale=scale),
         grid=(n, w // bk, w // bq),
@@ -242,11 +255,11 @@ def _g_bwd_call(qf, kf, vf, pqf, pkf, out, lse, do, causal, bq, bk,
             pl.BlockSpec((1, bq, dh), q_at2),
             pl.BlockSpec((1, bk, dh), k_at2),
             pl.BlockSpec((1, bk, dh), k_at2),
-            pl.BlockSpec((1, bq), rq_at2),
-            pl.BlockSpec((1, bk), rk_at2),
+            pl.BlockSpec((1, 1, bq), rq_at2),
+            pl.BlockSpec((1, 1, bk), rk_at2),
             pl.BlockSpec((1, bq, dh), q_at2),
-            pl.BlockSpec((1, bq), rq_at2),
-            pl.BlockSpec((1, bq), rq_at2),
+            pl.BlockSpec((1, 1, bq), rq_at2),
+            pl.BlockSpec((1, 1, bq), rq_at2),
         ],
         out_specs=[
             pl.BlockSpec((1, bk, dh), k_at2),
@@ -302,10 +315,10 @@ def routed_attention_blocks(qg, kg, vg, pos_q, pos_k, causal=True,
     qf = qg.reshape(n, w, dh)
     kf = kg.reshape(n, w, dh)
     vf = vg.reshape(n, w, dh)
-    pqf = pos_q.reshape(n, w).astype(jnp.int32)
-    pkf = pos_k.reshape(n, w).astype(jnp.int32)
+    pqf = pos_q.reshape(n, 1, w).astype(jnp.int32)
+    pkf = pos_k.reshape(n, 1, w).astype(jnp.int32)
     if valid_k is not None:
-        pkf = jnp.where(valid_k.reshape(n, w), pkf, SENTINEL)
+        pkf = jnp.where(valid_k.reshape(n, 1, w), pkf, SENTINEL)
     out = _routed_gathered(bool(causal), int(bq), int(bk),
                            default_interpret(interpret), qf, kf, vf, pqf,
                            pkf)
@@ -313,369 +326,70 @@ def routed_attention_blocks(qg, kg, vg, pos_q, pos_k, causal=True,
 
 
 # ---------------------------------------------------------------------------
-# Fused gather-free kernel: sequence-layout q/k/v + scalar-prefetch indices
+# Fused gather-free kernel: sequence-layout q/k/v, member rows streamed by
+# per-row DMA through revolving double-buffered VMEM slots
 # ---------------------------------------------------------------------------
-def _rows(seq, idx):
-    """Pull ``idx`` rows of the VMEM-resident sequence plane. Mosaic
-    lowers the sublane gather via dynamic_gather (one-row DMAs on older
-    toolchains); indices are always < N so clip never fires."""
-    return jnp.take(seq, idx, axis=0, mode="clip")
-
-
-def _f_fwd_kernel(qi_ref, ki_ref, *refs, shared, causal, scale, bq, bk):
-    if shared:
-        (q_ref, v_ref, pq_ref, pk_ref, o_ref, lse_ref,
-         qt_ref, pqt_ref, m_ref, l_ref, acc_ref) = refs
-        k_ref = q_ref
-    else:
-        (q_ref, k_ref, v_ref, pq_ref, pk_ref, o_ref, lse_ref,
-         qt_ref, pqt_ref, m_ref, l_ref, acc_ref) = refs
-    b = pl.program_id(0)
-    c = pl.program_id(1)
-    iq = pl.program_id(2)
-    ik = pl.program_id(3)
-    nk = pl.num_programs(3)
-
-    @pl.when(ik == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        qidx = qi_ref[b, c, pl.ds(iq * bq, bq)]
-        qt_ref[...] = _rows(q_ref[0], qidx).astype(jnp.float32)
-        pqt_ref[...] = _rows(pq_ref[0], qidx)
-
-    kidx = ki_ref[b, c, pl.ds(ik * bk, bk)]
-    k = _rows(k_ref[0], kidx).astype(jnp.float32)
-    v = _rows(v_ref[0], kidx).astype(jnp.float32)
-    pk = _rows(pk_ref[0], kidx)
-    q = qt_ref[...]
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
-    keep = _keep_mask(pqt_ref[...], pk, causal)
-    s = jnp.where(keep, s, _NEG)
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(-1))
-    p = jnp.where(keep, jnp.exp(s - m_new[:, None]), 0.0)
-    corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + p.sum(-1)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + \
-        jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())))
-    m_ref[...] = m_new
-
-    @pl.when(ik == nk - 1)
-    def _done():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_ref[...] + jnp.log(l)
-
-
-def _f_dq_kernel(qi_ref, ki_ref, *refs, shared, causal, scale, bq, bk):
-    if shared:
-        (q_ref, v_ref, pq_ref, pk_ref, do_ref, lse_ref, dsum_ref,
-         dq_ref, qt_ref, pqt_ref, dq_acc) = refs
-        k_ref = q_ref
-    else:
-        (q_ref, k_ref, v_ref, pq_ref, pk_ref, do_ref, lse_ref, dsum_ref,
-         dq_ref, qt_ref, pqt_ref, dq_acc) = refs
-    b = pl.program_id(0)
-    c = pl.program_id(1)
-    iq = pl.program_id(2)
-    ik = pl.program_id(3)
-    nk = pl.num_programs(3)
-
-    @pl.when(ik == 0)
-    def _init():
-        dq_acc[...] = jnp.zeros_like(dq_acc)
-        qidx = qi_ref[b, c, pl.ds(iq * bq, bq)]
-        qt_ref[...] = _rows(q_ref[0], qidx).astype(jnp.float32)
-        pqt_ref[...] = _rows(pq_ref[0], qidx)
-
-    kidx = ki_ref[b, c, pl.ds(ik * bk, bk)]
-    k = _rows(k_ref[0], kidx).astype(jnp.float32)
-    v = _rows(v_ref[0], kidx).astype(jnp.float32)
-    pk = _rows(pk_ref[0], kidx)
-    q = qt_ref[...]
-    do = do_ref[0, 0].astype(jnp.float32)
-    keep = _keep_mask(pqt_ref[...], pk, causal)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
-    p = jnp.where(keep, jnp.exp(s - lse_ref[0, 0][:, None]), 0.0)
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))
-    ds = p * (dp - dsum_ref[0, 0][:, None]) * scale
-    dq_acc[...] += jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())))
-
-    @pl.when(ik == nk - 1)
-    def _done():
-        dq_ref[0, 0] = dq_acc[...]
-
-
-def _f_dkv_kernel(qi_ref, ki_ref, *refs, shared, causal, scale, bq, bk):
-    if shared:
-        (q_ref, v_ref, pq_ref, pk_ref, do_ref, lse_ref, dsum_ref,
-         dk_ref, dv_ref, kt_ref, vt_ref, pkt_ref, dk_acc, dv_acc) = refs
-        k_ref = q_ref
-    else:
-        (q_ref, k_ref, v_ref, pq_ref, pk_ref, do_ref, lse_ref, dsum_ref,
-         dk_ref, dv_ref, kt_ref, vt_ref, pkt_ref, dk_acc, dv_acc) = refs
-    b = pl.program_id(0)
-    c = pl.program_id(1)
-    ik = pl.program_id(2)
-    iq = pl.program_id(3)
-    nq = pl.num_programs(3)
-
-    @pl.when(iq == 0)
-    def _init():
-        dk_acc[...] = jnp.zeros_like(dk_acc)
-        dv_acc[...] = jnp.zeros_like(dv_acc)
-        kidx = ki_ref[b, c, pl.ds(ik * bk, bk)]
-        kt_ref[...] = _rows(k_ref[0], kidx).astype(jnp.float32)
-        vt_ref[...] = _rows(v_ref[0], kidx).astype(jnp.float32)
-        pkt_ref[...] = _rows(pk_ref[0], kidx)
-
-    qidx = qi_ref[b, c, pl.ds(iq * bq, bq)]
-    q = _rows(q_ref[0], qidx).astype(jnp.float32)
-    pq = _rows(pq_ref[0], qidx)
-    do = do_ref[0, 0].astype(jnp.float32)
-    k = kt_ref[...]
-    v = vt_ref[...]
-    keep = _keep_mask(pq, pkt_ref[...], causal)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
-    p = jnp.where(keep, jnp.exp(s - lse_ref[0, 0][:, None]), 0.0)
-    dv_acc[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())))
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))
-    ds = p * (dp - dsum_ref[0, 0][:, None]) * scale
-    dk_acc[...] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())))
-
-    @pl.when(iq == nq - 1)
-    def _done():
-        dk_ref[0, 0] = dk_acc[...]
-        dv_ref[0, 0] = dv_acc[...]
-
-
-def _f_specs(N, dh, H, shared):
-    """Common fused in_specs: q [k] v sequence planes + the (B,N)
-    position arrays — all index maps ignore the cluster/tile axes (the
-    plane is revisited across every step of its (batch·head)) and take
-    the two trailing scalar-prefetch refs as *_."""
-    plane = lambda b, c, i2, i3, *_: (b, 0, 0)
-    posp = lambda b, c, i2, i3, *_: (b // H, 0)
-    specs = [pl.BlockSpec((1, N, dh), plane)]          # q
-    if not shared:
-        specs.append(pl.BlockSpec((1, N, dh), plane))  # k
-    specs.append(pl.BlockSpec((1, N, dh), plane))      # v
-    specs += [pl.BlockSpec((1, N), posp),              # pos_q (B,N)
-              pl.BlockSpec((1, N), posp)]              # pos_k (B,N)
-    return specs
-
-
-def _f_q_blk(bq, dh):
-    at = lambda b, c, iq, ik, *_: (b, c, iq, 0)
-    rat = lambda b, c, iq, ik, *_: (b, c, iq)
-    return (pl.BlockSpec((1, 1, bq, dh), at), pl.BlockSpec((1, 1, bq), rat))
-
-
-def _f_q_blk_swapped(bq, dh):
-    at = lambda b, c, ik, iq, *_: (b, c, iq, 0)
-    rat = lambda b, c, ik, iq, *_: (b, c, iq)
-    return (pl.BlockSpec((1, 1, bq, dh), at), pl.BlockSpec((1, 1, bq), rat))
-
-
-def _f_fwd_call(qf, kf, vf, qi, ki, posq, posk, shared, causal, bq, bk, H,
-                interpret):
-    BH, N, dh = qf.shape
-    _, kc, w = qi.shape
-    nq, nk = w // bq, w // bk
-    oq_at, olse_at = _f_q_blk(bq, dh)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(BH, kc, nq, nk),
-        in_specs=_f_specs(N, dh, H, shared),
-        out_specs=[oq_at, olse_at],
-        scratch_shapes=[
-            pltpu.VMEM((bq, dh), jnp.float32),
-            pltpu.VMEM((bq,), jnp.int32),
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq, dh), jnp.float32),
-        ])
-    operands = (qi, ki, qf) + (() if shared else (kf,)) + (vf, posq, posk)
-    out, lse = pl.pallas_call(
-        functools.partial(_f_fwd_kernel, shared=shared, causal=causal,
-                          scale=1.0 / (dh ** 0.5), bq=bq, bk=bk),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((BH, kc, w, dh), qf.dtype),
-            jax.ShapeDtypeStruct((BH, kc, w), jnp.float32),
-        ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
-        interpret=interpret,
-    )(*operands)
-    return out, lse
-
-
-def _f_bwd_call(qf, kf, vf, qi, ki, posq, posk, out, lse, do, shared,
-                causal, bq, bk, H, interpret):
-    BH, N, dh = qf.shape
-    _, kc, w = qi.shape
-    nq, nk = w // bq, w // bk
-    dsum = (do.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)
-    scale = 1.0 / (dh ** 0.5)
-    kern_kw = dict(shared=shared, causal=causal, scale=scale, bq=bq,
-                   bk=bk)
-    params4 = _CompilerParams(
-        dimension_semantics=("parallel", "parallel", "parallel",
-                            "arbitrary"))
-
-    q_at, r_at = _f_q_blk(bq, dh)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(BH, kc, nq, nk),
-        in_specs=_f_specs(N, dh, H, shared)
-        + [q_at, r_at, r_at],                     # do, lse, dsum
-        out_specs=q_at,                           # dqg per-cluster blocks
-        scratch_shapes=[
-            pltpu.VMEM((bq, dh), jnp.float32),
-            pltpu.VMEM((bq,), jnp.int32),
-            pltpu.VMEM((bq, dh), jnp.float32),
-        ])
-    operands = ((qi, ki, qf) + (() if shared else (kf,))
-                + (vf, posq, posk, do, lse, dsum))
-    dqg = pl.pallas_call(
-        functools.partial(_f_dq_kernel, **kern_kw),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((BH, kc, w, dh), jnp.float32),
-        compiler_params=params4,
-        interpret=interpret,
-    )(*operands)
-
-    # swapped grid: key tile parallel over (b, c, ik), query sweep inner
-    q_at2, r_at2 = _f_q_blk_swapped(bq, dh)
-    k_out = lambda b, c, ik, iq, *_: (b, c, ik, 0)
-    grid_spec2 = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(BH, kc, nk, nq),
-        in_specs=_f_specs(N, dh, H, shared)
-        + [q_at2, r_at2, r_at2],
-        out_specs=[pl.BlockSpec((1, 1, bk, dh), k_out),
-                   pl.BlockSpec((1, 1, bk, dh), k_out)],
-        scratch_shapes=[
-            pltpu.VMEM((bk, dh), jnp.float32),
-            pltpu.VMEM((bk, dh), jnp.float32),
-            pltpu.VMEM((bk,), jnp.int32),
-            pltpu.VMEM((bk, dh), jnp.float32),
-            pltpu.VMEM((bk, dh), jnp.float32),
-        ])
-    dkg, dvg = pl.pallas_call(
-        functools.partial(_f_dkv_kernel, **kern_kw),
-        grid_spec=grid_spec2,
-        out_shape=[
-            jax.ShapeDtypeStruct((BH, kc, w, dh), jnp.float32),
-            jax.ShapeDtypeStruct((BH, kc, w, dh), jnp.float32),
-        ],
-        compiler_params=params4,
-        interpret=interpret,
-    )(*operands)
-
-    # scatter-add per-cluster gradient blocks back to sequence layout —
-    # the exact transpose of the kernel's implicit gather; duplicate
-    # memberships accumulate
-    bi = jnp.arange(BH)[:, None]
-    qi2 = qi.reshape(BH, -1)
-    ki2 = ki.reshape(BH, -1)
-    dq = jnp.zeros((BH, N, dh), jnp.float32).at[bi, qi2].add(
-        dqg.reshape(BH, -1, dh))
-    dk = jnp.zeros((BH, N, dh), jnp.float32).at[bi, ki2].add(
-        dkg.reshape(BH, -1, dh))
-    dv = jnp.zeros((BH, N, dh), jnp.float32).at[bi, ki2].add(
-        dvg.reshape(BH, -1, dh))
-    return dq.astype(qf.dtype), dk.astype(kf.dtype), dv.astype(vf.dtype)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4, 5))
-def _routed_fused(shared, causal, bq, bk, H, interpret, qf, kf, vf, qi, ki,
-                  posq, posk):
-    out, _ = _f_fwd_call(qf, kf, vf, qi, ki, posq, posk, shared, causal,
-                         bq, bk, H, interpret)
-    return out
-
-
-def _routed_fused_fwd(shared, causal, bq, bk, H, interpret, qf, kf, vf, qi,
-                      ki, posq, posk):
-    out, lse = _f_fwd_call(qf, kf, vf, qi, ki, posq, posk, shared, causal,
-                           bq, bk, H, interpret)
-    return out, (qf, kf, vf, qi, ki, posq, posk, out, lse)
-
-
-def _routed_fused_bwd(shared, causal, bq, bk, H, interpret, res, do):
-    qf, kf, vf, qi, ki, posq, posk, out, lse = res
-    dq, dk, dv = _f_bwd_call(qf, kf, vf, qi, ki, posq, posk, out, lse, do,
-                             shared, causal, bq, bk, H, interpret)
-    return (dq, dk, dv, float0_like(qi), float0_like(ki),
-            float0_like(posq), float0_like(posk))
-
-
-_routed_fused.defvjp(_routed_fused_fwd, _routed_fused_bwd)
-
-
-# ---------------------------------------------------------------------------
-# Paged fused kernel: q/k/v stay in HBM; member rows stream through
-# revolving double-buffered VMEM slots via per-row async DMA
-# ---------------------------------------------------------------------------
-def _dma_start_rows(hbm, b, idx_ref, c, base, rows, dst, sem):
-    """Issue one-row async copies ``hbm[b, idx_ref[b, c, base+j]] ->
-    dst[j]`` for j < rows, all signalling the same semaphore. Cluster
-    membership has no sequence locality, so rows — not contiguous chunks —
-    are the DMA unit; the scalar-prefetch index table in SMEM drives the
-    source addresses (the same trick the paged decode kernel uses)."""
+def _dma_start_rows(src, plane, idx_ref, base, rows, dst, sem):
+    """Issue one-row async copies ``src[plane, idx[base+j]] -> dst[j]``
+    for j < rows, all signalling the same semaphore. Cluster membership
+    has no sequence locality, so rows — not contiguous chunks — are the
+    DMA unit; the cluster's SMEM index block drives the source addresses
+    (the same trick the paged decode kernel uses)."""
     def body(j, _):
-        row = idx_ref[b, c, base + j]
-        pltpu.make_async_copy(hbm.at[b, pl.ds(row, 1)],
+        row = idx_ref[0, 0, 0, base + j]
+        pltpu.make_async_copy(src.at[plane, pl.ds(row, 1)],
                               dst.at[pl.ds(j, 1)], sem).start()
         return 0
     jax.lax.fori_loop(0, rows, body, 0, unroll=False)
 
 
-def _dma_wait_rows(hbm, b, rows, dst, sem):
+def _dma_wait_rows(src, rows, dst, sem):
     """Wait the ``rows`` one-row copies previously started into ``dst``
     (the wait descriptor only needs the byte count, so src row 0 serves
     for every j)."""
     def body(j, _):
-        pltpu.make_async_copy(hbm.at[b, pl.ds(0, 1)],
+        pltpu.make_async_copy(src.at[0, pl.ds(0, 1)],
                               dst.at[pl.ds(j, 1)], sem).wait()
         return 0
     jax.lax.fori_loop(0, rows, body, 0, unroll=False)
 
 
-def _p_fwd_kernel(qi_ref, ki_ref, pqg_ref, pkg_ref, *refs, shared, causal,
-                  scale, bq, bk):
+def _unpack(refs, shared, n_tail):
+    """Split a fused kernel's refs into (q, k, v) sources and the rest;
+    shared-QK reads keys from the q plane."""
     if shared:
-        (q_hbm, v_hbm, o_ref, lse_ref, qt_ref, kt_ref, vt_ref,
-         m_ref, l_ref, acc_ref, q_sem, k_sem, v_sem) = refs
-        k_hbm = q_hbm
+        q_src, v_src, *rest = refs
+        k_src = q_src
     else:
-        (q_hbm, k_hbm, v_hbm, o_ref, lse_ref, qt_ref, kt_ref, vt_ref,
-         m_ref, l_ref, acc_ref, q_sem, k_sem, v_sem) = refs
-    b = pl.program_id(0)
-    c = pl.program_id(1)
+        q_src, k_src, v_src, *rest = refs
+    assert len(rest) == n_tail, (len(rest), n_tail)
+    return q_src, k_src, v_src, rest
+
+
+def _fwd_kernel(qi_ref, ki_ref, pq_ref, pk_ref, *refs, shared, causal,
+                scale, bq, bk, resident):
+    q_src, k_src, v_src, rest = _unpack(refs, shared, 11)
+    (o_ref, lse_ref, qt_ref, kt_ref, vt_ref, m_ref, l_ref, acc_ref,
+     q_sem, k_sem, v_sem) = rest
+    plane = 0 if resident else pl.program_id(0)
     iq = pl.program_id(2)
     ik = pl.program_id(3)
     nk = pl.num_programs(3)
 
     def start_kv(t, slot):
-        _dma_start_rows(k_hbm, b, ki_ref, c, t * bk, bk,
-                        kt_ref.at[slot], k_sem.at[slot])
-        _dma_start_rows(v_hbm, b, ki_ref, c, t * bk, bk,
-                        vt_ref.at[slot], v_sem.at[slot])
+        _dma_start_rows(k_src, plane, ki_ref, t * bk, bk, kt_ref.at[slot],
+                        k_sem.at[slot])
+        _dma_start_rows(v_src, plane, ki_ref, t * bk, bk, vt_ref.at[slot],
+                        v_sem.at[slot])
 
     @pl.when(ik == 0)
     def _prologue():
         m_ref[...] = jnp.full_like(m_ref, _NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
-        _dma_start_rows(q_hbm, b, qi_ref, c, iq * bq, bq, qt_ref, q_sem)
+        _dma_start_rows(q_src, plane, qi_ref, iq * bq, bq, qt_ref, q_sem)
         start_kv(0, 0)
-        _dma_wait_rows(q_hbm, b, bq, qt_ref, q_sem)
+        _dma_wait_rows(q_src, bq, qt_ref, q_sem)
 
     # double-buffer: tile ik+1's DMAs are in flight while tile ik computes
     @pl.when(ik + 1 < nk)
@@ -683,16 +397,14 @@ def _p_fwd_kernel(qi_ref, ki_ref, pqg_ref, pkg_ref, *refs, shared, causal,
         start_kv(ik + 1, (ik + 1) % 2)
 
     slot = ik % 2
-    _dma_wait_rows(k_hbm, b, bk, kt_ref.at[slot], k_sem.at[slot])
-    _dma_wait_rows(v_hbm, b, bk, vt_ref.at[slot], v_sem.at[slot])
+    _dma_wait_rows(k_src, bk, kt_ref.at[slot], k_sem.at[slot])
+    _dma_wait_rows(v_src, bk, vt_ref.at[slot], v_sem.at[slot])
 
-    q = qt_ref[...].astype(jnp.float32)
-    k = kt_ref[slot].astype(jnp.float32)
-    v = vt_ref[slot].astype(jnp.float32)
-    pq = pqg_ref[b, c, pl.ds(iq * bq, bq)]
-    pk = pkg_ref[b, c, pl.ds(ik * bk, bk)]
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
-    keep = _keep_mask(pq, pk, causal)
+    q = qt_ref[...]
+    k = kt_ref[slot]
+    v = vt_ref[slot]
+    s = _dot(q, k, 1, 1) * scale
+    keep = _keep_mask(pq_ref[0, 0, 0], pk_ref[0, 0, 0], causal)
     s = jnp.where(keep, s, _NEG)
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, s.max(-1))
@@ -700,83 +412,69 @@ def _p_fwd_kernel(qi_ref, ki_ref, pqg_ref, pkg_ref, *refs, shared, causal,
     corr = jnp.exp(m_prev - m_new)
     l_ref[...] = l_ref[...] * corr + p.sum(-1)
     acc_ref[...] = acc_ref[...] * corr[:, None] + \
-        jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())))
+        _dot(p, v, 1, 0)
     m_ref[...] = m_new
 
     @pl.when(ik == nk - 1)
     def _done():
         l = jnp.maximum(l_ref[...], 1e-30)
         o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_ref[...] + jnp.log(l)
+        lse_ref[0, 0, 0] = m_ref[...] + jnp.log(l)
 
 
-def _p_dq_kernel(qi_ref, ki_ref, pqg_ref, pkg_ref, *refs, shared, causal,
-                 scale, bq, bk):
-    if shared:
-        (q_hbm, v_hbm, do_ref, lse_ref, dsum_ref, dq_ref,
-         qt_ref, kt_ref, vt_ref, dq_acc, q_sem, k_sem, v_sem) = refs
-        k_hbm = q_hbm
-    else:
-        (q_hbm, k_hbm, v_hbm, do_ref, lse_ref, dsum_ref, dq_ref,
-         qt_ref, kt_ref, vt_ref, dq_acc, q_sem, k_sem, v_sem) = refs
-    b = pl.program_id(0)
-    c = pl.program_id(1)
+def _dq_kernel(qi_ref, ki_ref, pq_ref, pk_ref, *refs, shared, causal,
+               scale, bq, bk, resident):
+    q_src, k_src, v_src, rest = _unpack(refs, shared, 11)
+    (do_ref, lse_ref, dsum_ref, dq_ref, qt_ref, kt_ref, vt_ref, dq_acc,
+     q_sem, k_sem, v_sem) = rest
+    plane = 0 if resident else pl.program_id(0)
     iq = pl.program_id(2)
     ik = pl.program_id(3)
     nk = pl.num_programs(3)
 
     def start_kv(t, slot):
-        _dma_start_rows(k_hbm, b, ki_ref, c, t * bk, bk,
-                        kt_ref.at[slot], k_sem.at[slot])
-        _dma_start_rows(v_hbm, b, ki_ref, c, t * bk, bk,
-                        vt_ref.at[slot], v_sem.at[slot])
+        _dma_start_rows(k_src, plane, ki_ref, t * bk, bk, kt_ref.at[slot],
+                        k_sem.at[slot])
+        _dma_start_rows(v_src, plane, ki_ref, t * bk, bk, vt_ref.at[slot],
+                        v_sem.at[slot])
 
     @pl.when(ik == 0)
     def _prologue():
         dq_acc[...] = jnp.zeros_like(dq_acc)
-        _dma_start_rows(q_hbm, b, qi_ref, c, iq * bq, bq, qt_ref, q_sem)
+        _dma_start_rows(q_src, plane, qi_ref, iq * bq, bq, qt_ref, q_sem)
         start_kv(0, 0)
-        _dma_wait_rows(q_hbm, b, bq, qt_ref, q_sem)
+        _dma_wait_rows(q_src, bq, qt_ref, q_sem)
 
     @pl.when(ik + 1 < nk)
     def _prefetch():
         start_kv(ik + 1, (ik + 1) % 2)
 
     slot = ik % 2
-    _dma_wait_rows(k_hbm, b, bk, kt_ref.at[slot], k_sem.at[slot])
-    _dma_wait_rows(v_hbm, b, bk, vt_ref.at[slot], v_sem.at[slot])
+    _dma_wait_rows(k_src, bk, kt_ref.at[slot], k_sem.at[slot])
+    _dma_wait_rows(v_src, bk, vt_ref.at[slot], v_sem.at[slot])
 
-    q = qt_ref[...].astype(jnp.float32)
-    k = kt_ref[slot].astype(jnp.float32)
-    v = vt_ref[slot].astype(jnp.float32)
-    pq = pqg_ref[b, c, pl.ds(iq * bq, bq)]
-    pk = pkg_ref[b, c, pl.ds(ik * bk, bk)]
+    q = qt_ref[...]
+    k = kt_ref[slot]
+    v = vt_ref[slot]
     do = do_ref[0, 0].astype(jnp.float32)
-    keep = _keep_mask(pq, pk, causal)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
-    p = jnp.where(keep, jnp.exp(s - lse_ref[0, 0][:, None]), 0.0)
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))
-    ds = p * (dp - dsum_ref[0, 0][:, None]) * scale
-    dq_acc[...] += jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())))
+    keep = _keep_mask(pq_ref[0, 0, 0], pk_ref[0, 0, 0], causal)
+    s = _dot(q, k, 1, 1) * scale
+    p = jnp.where(keep, jnp.exp(s - lse_ref[0, 0, 0][:, None]), 0.0)
+    dp = _dot(do, v, 1, 1)
+    ds = p * (dp - dsum_ref[0, 0, 0][:, None]) * scale
+    dq_acc[...] += _dot(ds, k, 1, 0)
 
     @pl.when(ik == nk - 1)
     def _done():
         dq_ref[0, 0] = dq_acc[...]
 
 
-def _p_dkv_kernel(qi_ref, ki_ref, pqg_ref, pkg_ref, *refs, shared, causal,
-                  scale, bq, bk):
-    if shared:
-        (q_hbm, v_hbm, do_ref, lse_ref, dsum_ref, dk_ref, dv_ref,
-         qt_ref, kt_ref, vt_ref, dk_acc, dv_acc,
-         q_sem, k_sem, v_sem) = refs
-        k_hbm = q_hbm
-    else:
-        (q_hbm, k_hbm, v_hbm, do_ref, lse_ref, dsum_ref, dk_ref, dv_ref,
-         qt_ref, kt_ref, vt_ref, dk_acc, dv_acc,
-         q_sem, k_sem, v_sem) = refs
-    b = pl.program_id(0)
-    c = pl.program_id(1)
+def _dkv_kernel(qi_ref, ki_ref, pq_ref, pk_ref, *refs, shared, causal,
+                scale, bq, bk, resident):
+    q_src, k_src, v_src, rest = _unpack(refs, shared, 13)
+    (do_ref, lse_ref, dsum_ref, dk_ref, dv_ref, qt_ref, kt_ref, vt_ref,
+     dk_acc, dv_acc, q_sem, k_sem, v_sem) = rest
+    plane = 0 if resident else pl.program_id(0)
     ik = pl.program_id(2)
     iq = pl.program_id(3)
     nq = pl.num_programs(3)
@@ -787,34 +485,32 @@ def _p_dkv_kernel(qi_ref, ki_ref, pqg_ref, pkg_ref, *refs, shared, causal,
     def _prologue():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
-        _dma_start_rows(k_hbm, b, ki_ref, c, ik * bk, bk, kt_ref, k_sem)
-        _dma_start_rows(v_hbm, b, ki_ref, c, ik * bk, bk, vt_ref, v_sem)
-        _dma_start_rows(q_hbm, b, qi_ref, c, 0, bq, qt_ref.at[0],
+        _dma_start_rows(k_src, plane, ki_ref, ik * bk, bk, kt_ref, k_sem)
+        _dma_start_rows(v_src, plane, ki_ref, ik * bk, bk, vt_ref, v_sem)
+        _dma_start_rows(q_src, plane, qi_ref, 0, bq, qt_ref.at[0],
                         q_sem.at[0])
-        _dma_wait_rows(k_hbm, b, bk, kt_ref, k_sem)
-        _dma_wait_rows(v_hbm, b, bk, vt_ref, v_sem)
+        _dma_wait_rows(k_src, bk, kt_ref, k_sem)
+        _dma_wait_rows(v_src, bk, vt_ref, v_sem)
 
     @pl.when(iq + 1 < nq)
     def _prefetch():
-        _dma_start_rows(q_hbm, b, qi_ref, c, (iq + 1) * bq, bq,
+        _dma_start_rows(q_src, plane, qi_ref, (iq + 1) * bq, bq,
                         qt_ref.at[(iq + 1) % 2], q_sem.at[(iq + 1) % 2])
 
     slot = iq % 2
-    _dma_wait_rows(q_hbm, b, bq, qt_ref.at[slot], q_sem.at[slot])
+    _dma_wait_rows(q_src, bq, qt_ref.at[slot], q_sem.at[slot])
 
-    q = qt_ref[slot].astype(jnp.float32)
-    k = kt_ref[...].astype(jnp.float32)
-    v = vt_ref[...].astype(jnp.float32)
-    pq = pqg_ref[b, c, pl.ds(iq * bq, bq)]
-    pk = pkg_ref[b, c, pl.ds(ik * bk, bk)]
+    q = qt_ref[slot]
+    k = kt_ref[...]
+    v = vt_ref[...]
     do = do_ref[0, 0].astype(jnp.float32)
-    keep = _keep_mask(pq, pk, causal)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
-    p = jnp.where(keep, jnp.exp(s - lse_ref[0, 0][:, None]), 0.0)
-    dv_acc[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())))
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))
-    ds = p * (dp - dsum_ref[0, 0][:, None]) * scale
-    dk_acc[...] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())))
+    keep = _keep_mask(pq_ref[0, 0, 0], pk_ref[0, 0, 0], causal)
+    s = _dot(q, k, 1, 1) * scale
+    p = jnp.where(keep, jnp.exp(s - lse_ref[0, 0, 0][:, None]), 0.0)
+    dv_acc[...] += _dot(p, do, 0, 0)
+    dp = _dot(do, v, 1, 1)
+    ds = p * (dp - dsum_ref[0, 0, 0][:, None]) * scale
+    dk_acc[...] += _dot(ds, q, 0, 0)
 
     @pl.when(iq == nq - 1)
     def _done():
@@ -822,156 +518,177 @@ def _p_dkv_kernel(qi_ref, ki_ref, pqg_ref, pkg_ref, *refs, shared, causal,
         dv_ref[0, 0] = dv_acc[...]
 
 
-def _p_specs(shared):
-    """Paged fused in_specs: q [k] v stay in HBM (ANY memory space) — the
-    kernel DMAs member rows itself, nothing is staged as an input block."""
-    return [pl.BlockSpec(memory_space=pltpu.ANY)] * (2 if shared else 3)
+def _fused_in_specs(N, dh, w, bq, bk, shared, resident, swapped):
+    """in_specs shared by the three fused kernels: the cluster's index
+    blocks (SMEM), its member positions (VMEM rows), then the q [k] v
+    sources — the whole (N, dh) plane of the batch·head when resident,
+    untouched HBM (ANY) when paged."""
+    if swapped:                                   # grid (b, c, ik, iq)
+        tq = lambda b, c, ik, iq: (b, c, 0, iq)
+        tk = lambda b, c, ik, iq: (b, c, 0, ik)
+    else:                                         # grid (b, c, iq, ik)
+        tq = lambda b, c, iq, ik: (b, c, 0, iq)
+        tk = lambda b, c, iq, ik: (b, c, 0, ik)
+    idx = pl.BlockSpec((1, 1, 1, w), lambda b, c, i2, i3: (b, c, 0, 0),
+                       memory_space=pltpu.SMEM)
+    if resident:
+        src = pl.BlockSpec((1, N, dh), lambda b, c, i2, i3: (b, 0, 0))
+    else:
+        src = pl.BlockSpec(memory_space=pl.ANY)
+    return ([idx, idx, pl.BlockSpec((1, 1, 1, bq), tq),
+             pl.BlockSpec((1, 1, 1, bk), tk)]
+            + [src] * (2 if shared else 3))
 
 
-def _p_fwd_call(qf, kf, vf, qi, ki, pqg, pkg, shared, causal, bq, bk,
-                interpret):
+def _row_blocks(bq, dh, swapped):
+    """(tile, row-stat) BlockSpecs of the per-cluster q-side arrays."""
+    if swapped:
+        at = lambda b, c, ik, iq: (b, c, iq, 0)
+        rat = lambda b, c, ik, iq: (b, c, 0, iq)
+    else:
+        at = lambda b, c, iq, ik: (b, c, iq, 0)
+        rat = lambda b, c, iq, ik: (b, c, 0, iq)
+    return (pl.BlockSpec((1, 1, bq, dh), at),
+            pl.BlockSpec((1, 1, 1, bq), rat))
+
+
+def _sems(q2, kv2):
+    dma = pltpu.SemaphoreType.DMA
+    return [dma((2,)) if q2 else dma] + [dma((2,)) if kv2 else dma] * 2
+
+
+def _fused_params(N, dh, shared, resident):
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel",
+                             "arbitrary"),
+        vmem_limit_bytes=fused_vmem_limit(N, dh, 2 if shared else 3,
+                                          resident))
+
+
+def _fused_fwd_call(qf, kf, vf, qi, ki, pqg, pkg, shared, causal, bq, bk,
+                    resident, interpret):
     BH, N, dh = qf.shape
-    _, kc, w = qi.shape
-    nq, nk = w // bq, w // bk
-    oq_at, olse_at = _f_q_blk(bq, dh)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(BH, kc, nq, nk),
-        in_specs=_p_specs(shared),
-        out_specs=[oq_at, olse_at],
-        scratch_shapes=[
-            pltpu.VMEM((bq, dh), qf.dtype),
-            pltpu.VMEM((2, bk, dh), kf.dtype),
-            pltpu.VMEM((2, bk, dh), vf.dtype),
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq, dh), jnp.float32),
-            pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-        ])
-    operands = (qi, ki, pqg, pkg, qf) + (() if shared else (kf,)) + (vf,)
+    _, kc, _, w = qi.shape
+    f32 = jnp.float32
+    o_at, lse_at = _row_blocks(bq, dh, swapped=False)
+    srcs = (qf,) + (() if shared else (kf,)) + (vf,)
     out, lse = pl.pallas_call(
-        functools.partial(_p_fwd_kernel, shared=shared, causal=causal,
-                          scale=1.0 / (dh ** 0.5), bq=bq, bk=bk),
-        grid_spec=grid_spec,
+        functools.partial(_fwd_kernel, shared=shared, causal=causal,
+                          scale=1.0 / (dh ** 0.5), bq=bq, bk=bk,
+                          resident=resident),
+        grid=(BH, kc, w // bq, w // bk),
+        in_specs=_fused_in_specs(N, dh, w, bq, bk, shared, resident,
+                                 swapped=False),
+        out_specs=[o_at, lse_at],
         out_shape=[
-            jax.ShapeDtypeStruct((BH, kc, w, dh), qf.dtype),
-            jax.ShapeDtypeStruct((BH, kc, w), jnp.float32),
+            jax.ShapeDtypeStruct((BH, kc, w, dh), f32),
+            jax.ShapeDtypeStruct((BH, kc, 1, w), f32),
         ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+        scratch_shapes=[
+            pltpu.VMEM((bq, dh), f32),
+            pltpu.VMEM((2, bk, dh), f32),
+            pltpu.VMEM((2, bk, dh), f32),
+            pltpu.VMEM((bq,), f32),
+            pltpu.VMEM((bq,), f32),
+            pltpu.VMEM((bq, dh), f32),
+        ] + _sems(q2=False, kv2=True),
+        compiler_params=_fused_params(N, dh, shared, resident),
         interpret=interpret,
-    )(*operands)
+    )(qi, ki, pqg, pkg, *srcs)
     return out, lse
 
 
-def _p_bwd_call(qf, kf, vf, qi, ki, pqg, pkg, out, lse, do, shared, causal,
-                bq, bk, interpret):
+def _fused_bwd_call(qf, kf, vf, qi, ki, pqg, pkg, out, lse, do, shared,
+                    causal, bq, bk, resident, interpret):
     BH, N, dh = qf.shape
-    _, kc, w = qi.shape
+    _, kc, _, w = qi.shape
+    f32 = jnp.float32
     nq, nk = w // bq, w // bk
-    dsum = (do.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)
-    scale = 1.0 / (dh ** 0.5)
-    kern_kw = dict(shared=shared, causal=causal, scale=scale, bq=bq, bk=bk)
-    params4 = _CompilerParams(
-        dimension_semantics=("parallel", "parallel", "parallel",
-                            "arbitrary"))
+    dsum = (do * out).sum(-1)[:, :, None, :]
+    kern_kw = dict(shared=shared, causal=causal, scale=1.0 / (dh ** 0.5),
+                   bq=bq, bk=bk, resident=resident)
+    params = _fused_params(N, dh, shared, resident)
+    srcs = (qf,) + (() if shared else (kf,)) + (vf,)
+    operands = (qi, ki, pqg, pkg) + srcs + (do, lse, dsum)
 
-    q_at, r_at = _f_q_blk(bq, dh)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(BH, kc, nq, nk),
-        in_specs=_p_specs(shared) + [q_at, r_at, r_at],   # do, lse, dsum
-        out_specs=q_at,                                   # dqg blocks
-        scratch_shapes=[
-            pltpu.VMEM((bq, dh), qf.dtype),
-            pltpu.VMEM((2, bk, dh), kf.dtype),
-            pltpu.VMEM((2, bk, dh), vf.dtype),
-            pltpu.VMEM((bq, dh), jnp.float32),
-            pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-        ])
-    operands = ((qi, ki, pqg, pkg, qf) + (() if shared else (kf,))
-                + (vf, do, lse, dsum))
+    q_at, r_at = _row_blocks(bq, dh, swapped=False)
     dqg = pl.pallas_call(
-        functools.partial(_p_dq_kernel, **kern_kw),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((BH, kc, w, dh), jnp.float32),
-        compiler_params=params4,
+        functools.partial(_dq_kernel, **kern_kw),
+        grid=(BH, kc, nq, nk),
+        in_specs=_fused_in_specs(N, dh, w, bq, bk, shared, resident,
+                                 swapped=False) + [q_at, r_at, r_at],
+        out_specs=q_at,                                   # dqg blocks
+        out_shape=jax.ShapeDtypeStruct((BH, kc, w, dh), f32),
+        scratch_shapes=[
+            pltpu.VMEM((bq, dh), f32),
+            pltpu.VMEM((2, bk, dh), f32),
+            pltpu.VMEM((2, bk, dh), f32),
+            pltpu.VMEM((bq, dh), f32),
+        ] + _sems(q2=False, kv2=True),
+        compiler_params=params,
         interpret=interpret,
     )(*operands)
 
     # swapped grid: key tile parallel over (b, c, ik), query sweep inner
-    q_at2, r_at2 = _f_q_blk_swapped(bq, dh)
-    k_out = lambda b, c, ik, iq, *_: (b, c, ik, 0)
-    grid_spec2 = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(BH, kc, nk, nq),
-        in_specs=_p_specs(shared) + [q_at2, r_at2, r_at2],
-        out_specs=[pl.BlockSpec((1, 1, bk, dh), k_out),
-                   pl.BlockSpec((1, 1, bk, dh), k_out)],
-        scratch_shapes=[
-            pltpu.VMEM((2, bq, dh), qf.dtype),
-            pltpu.VMEM((bk, dh), kf.dtype),
-            pltpu.VMEM((bk, dh), vf.dtype),
-            pltpu.VMEM((bk, dh), jnp.float32),
-            pltpu.VMEM((bk, dh), jnp.float32),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA,
-        ])
+    q_at2, r_at2 = _row_blocks(bq, dh, swapped=True)
+    k_out = pl.BlockSpec((1, 1, bk, dh), lambda b, c, ik, iq: (b, c, ik, 0))
     dkg, dvg = pl.pallas_call(
-        functools.partial(_p_dkv_kernel, **kern_kw),
-        grid_spec=grid_spec2,
+        functools.partial(_dkv_kernel, **kern_kw),
+        grid=(BH, kc, nk, nq),
+        in_specs=_fused_in_specs(N, dh, w, bq, bk, shared, resident,
+                                 swapped=True) + [q_at2, r_at2, r_at2],
+        out_specs=[k_out, k_out],
         out_shape=[
-            jax.ShapeDtypeStruct((BH, kc, w, dh), jnp.float32),
-            jax.ShapeDtypeStruct((BH, kc, w, dh), jnp.float32),
+            jax.ShapeDtypeStruct((BH, kc, w, dh), f32),
+            jax.ShapeDtypeStruct((BH, kc, w, dh), f32),
         ],
-        compiler_params=params4,
+        scratch_shapes=[
+            pltpu.VMEM((2, bq, dh), f32),
+            pltpu.VMEM((bk, dh), f32),
+            pltpu.VMEM((bk, dh), f32),
+            pltpu.VMEM((bk, dh), f32),
+            pltpu.VMEM((bk, dh), f32),
+        ] + _sems(q2=True, kv2=False),
+        compiler_params=params,
         interpret=interpret,
     )(*operands)
 
-    # chunked scatter-add of per-cluster gradient blocks to sequence
-    # layout (same transpose-of-the-gather as the unpaged path)
+    # scatter-add per-cluster gradient blocks back to sequence layout —
+    # the exact transpose of the kernel's implicit gather; duplicate
+    # memberships accumulate
     bi = jnp.arange(BH)[:, None]
     qi2 = qi.reshape(BH, -1)
     ki2 = ki.reshape(BH, -1)
-    dq = jnp.zeros((BH, N, dh), jnp.float32).at[bi, qi2].add(
-        dqg.reshape(BH, -1, dh))
-    dk = jnp.zeros((BH, N, dh), jnp.float32).at[bi, ki2].add(
-        dkg.reshape(BH, -1, dh))
-    dv = jnp.zeros((BH, N, dh), jnp.float32).at[bi, ki2].add(
-        dvg.reshape(BH, -1, dh))
-    return dq.astype(qf.dtype), dk.astype(kf.dtype), dv.astype(vf.dtype)
+    dq = jnp.zeros((BH, N, dh), f32).at[bi, qi2].add(dqg.reshape(BH, -1, dh))
+    dk = jnp.zeros((BH, N, dh), f32).at[bi, ki2].add(dkg.reshape(BH, -1, dh))
+    dv = jnp.zeros((BH, N, dh), f32).at[bi, ki2].add(dvg.reshape(BH, -1, dh))
+    return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4))
-def _routed_paged(shared, causal, bq, bk, interpret, qf, kf, vf, qi, ki,
-                  pqg, pkg):
-    out, _ = _p_fwd_call(qf, kf, vf, qi, ki, pqg, pkg, shared, causal,
-                         bq, bk, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4, 5))
+def _routed_fused(shared, causal, bq, bk, resident, interpret, qf, kf, vf,
+                  qi, ki, pqg, pkg):
+    out, _ = _fused_fwd_call(qf, kf, vf, qi, ki, pqg, pkg, shared, causal,
+                             bq, bk, resident, interpret)
     return out
 
 
-def _routed_paged_fwd(shared, causal, bq, bk, interpret, qf, kf, vf, qi,
-                      ki, pqg, pkg):
-    out, lse = _p_fwd_call(qf, kf, vf, qi, ki, pqg, pkg, shared, causal,
-                           bq, bk, interpret)
+def _routed_fused_fwd(shared, causal, bq, bk, resident, interpret, qf, kf,
+                      vf, qi, ki, pqg, pkg):
+    out, lse = _fused_fwd_call(qf, kf, vf, qi, ki, pqg, pkg, shared,
+                               causal, bq, bk, resident, interpret)
     return out, (qf, kf, vf, qi, ki, pqg, pkg, out, lse)
 
 
-def _routed_paged_bwd(shared, causal, bq, bk, interpret, res, do):
+def _routed_fused_bwd(shared, causal, bq, bk, resident, interpret, res, do):
     qf, kf, vf, qi, ki, pqg, pkg, out, lse = res
-    dq, dk, dv = _p_bwd_call(qf, kf, vf, qi, ki, pqg, pkg, out, lse, do,
-                             shared, causal, bq, bk, interpret)
+    dq, dk, dv = _fused_bwd_call(qf, kf, vf, qi, ki, pqg, pkg, out, lse, do,
+                                 shared, causal, bq, bk, resident, interpret)
     return (dq, dk, dv, float0_like(qi), float0_like(ki),
             float0_like(pqg), float0_like(pkg))
 
 
-_routed_paged.defvjp(_routed_paged_fwd, _routed_paged_bwd)
+_routed_fused.defvjp(_routed_fused_fwd, _routed_fused_bwd)
 
 
 def routed_attention_fused(q, k, v, q_idx, k_idx, positions, causal=True,
@@ -980,17 +697,17 @@ def routed_attention_fused(q, k, v, q_idx, k_idx, positions, causal=True,
     """Gather-free routed attention on sequence-layout tensors.
 
     q/v: (B,H,N,dh); k: like q, or None for shared-QK causal mode (keys
-    are read from the q buffer — one VMEM plane instead of two).
+    are read from the q plane).
     q_idx/k_idx: (B,H,k,w) sorted membership indices into the sequence.
     positions: (B,N) int32 original positions (the causal mask compares
     these). kvalid: (B,N) bool, True = attendable key (padding False).
     Returns per-cluster outputs (B,H,k,w,dh); callers scatter them back.
 
     ``paged=None`` auto-selects the memory plan: whole-plane VMEM
-    residency while N·dh fits ``FUSED_RESIDENT_ELEMS``, double-buffered
-    per-row DMA paging beyond it (VMEM bounded by the tile sizes, not N).
-    Pass True/False to force a plan. The paged path pre-gathers int32
-    positions per member (4 B/row, SMEM scalar-prefetch) — still no
+    residency while the planes fit the byte budget of
+    ``fused_paged_default``, per-row DMA straight from HBM beyond it (VMEM
+    bounded by the tile sizes, not N). Pass True/False to force a plan.
+    Member positions are pre-gathered in XLA (int32, 4 B/row) — still no
     gathered q/k/v tensor in HBM.
 
     Differentiable: flash-style custom VJP that recomputes p from saved
@@ -1002,28 +719,23 @@ def routed_attention_fused(q, k, v, q_idx, k_idx, positions, causal=True,
     bk = min(bk, w)
     assert w % bq == 0 and w % bk == 0, (w, bq, bk)
     shared = k is None
-    qf = q.reshape(B * H, N, dh)
-    kf = qf if shared else k.reshape(B * H, N, dh)
-    vf = v.reshape(B * H, N, dh)
-    qi = q_idx.reshape(B * H, kc, w).astype(jnp.int32)
-    ki = k_idx.reshape(B * H, kc, w).astype(jnp.int32)
+    f32 = jnp.float32
+    qf = q.reshape(B * H, N, dh).astype(f32)
+    kf = qf if shared else k.reshape(B * H, N, dh).astype(f32)
+    vf = v.reshape(B * H, N, dh).astype(f32)
+    qi = q_idx.reshape(B * H, kc, 1, w).astype(jnp.int32)
+    ki = k_idx.reshape(B * H, kc, 1, w).astype(jnp.int32)
     posq = positions.astype(jnp.int32)
     posk = (jnp.where(kvalid, posq, SENTINEL) if kvalid is not None
             else posq)
-    if fused_paged_default(N, dh, paged):
-        pq_src = jnp.broadcast_to(posq[:, None, :], (B, H, N))
-        pk_src = jnp.broadcast_to(posk[:, None, :], (B, H, N))
-        pqg = jnp.take_along_axis(pq_src.reshape(B * H, N),
-                                  qi.reshape(B * H, kc * w),
-                                  axis=1).reshape(B * H, kc, w)
-        pkg = jnp.take_along_axis(pk_src.reshape(B * H, N),
-                                  ki.reshape(B * H, kc * w),
-                                  axis=1).reshape(B * H, kc, w)
-        out = _routed_paged(shared, bool(causal), int(bq), int(bk),
-                            default_interpret(interpret), qf, kf, vf,
-                            qi, ki, pqg, pkg)
-    else:
-        out = _routed_fused(shared, bool(causal), int(bq), int(bk),
-                            int(H), default_interpret(interpret), qf, kf,
-                            vf, qi, ki, posq, posk)
-    return out.reshape(B, H, kc, w, dh)
+
+    def member_pos(pos, idx):
+        src = jnp.broadcast_to(pos[:, None, :], (B, H, N)).reshape(B * H, N)
+        return jnp.take_along_axis(src, idx.reshape(B * H, kc * w),
+                                   axis=1).reshape(B * H, kc, 1, w)
+
+    resident = not fused_paged_default(N, dh, 2 if shared else 3, paged)
+    out = _routed_fused(shared, bool(causal), int(bq), int(bk), resident,
+                        default_interpret(interpret), qf, kf, vf, qi, ki,
+                        member_pos(posq, qi), member_pos(posk, ki))
+    return out.reshape(B, H, kc, w, dh).astype(q.dtype)

@@ -52,8 +52,15 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import NEG as _NEG
-from repro.kernels.common import CompilerParams as _CompilerParams
 from repro.kernels.common import default_interpret
+
+
+def _dot(a, b, dims, dt):
+    """dot_general accumulated in float32 (Mosaic's only accumulator)
+    and rounded to ``dt`` — what the reference's einsum in ``dt``
+    produces."""
+    return jax.lax.dot_general(a, b, (dims, ((), ())),
+                               preferred_element_type=jnp.float32).astype(dt)
 
 
 def _decode_kernel(c_ref, rlen_ref, r_ref, v_ref, rk_ref, rv_ref, o_ref,
@@ -64,32 +71,30 @@ def _decode_kernel(c_ref, rlen_ref, r_ref, v_ref, rk_ref, rv_ref, o_ref,
     plen = rlen_ref[b, h, c]
     nvalid = jnp.minimum(plen, cap)
 
-    r = r_ref[0]                       # (1, dh)
+    r = r_ref[0, 0]                    # (1, dh)
     page_k = rk_ref[0, 0, 0]           # (cap, dh) — the selected page
     page_v = rv_ref[0, 0, 0]
 
     # mirror the reference op-for-op: dot in the promoted input dtype,
     # THEN cast f32, THEN divide (mul-by-reciprocal would not be bitwise)
     s_dt = jnp.promote_types(r.dtype, page_k.dtype)
-    logits = jax.lax.dot_general(r.astype(s_dt), page_k.astype(s_dt),
-                                 (((1,), (1,)), ((), ())))      # (1, cap)
-    logits = logits.astype(jnp.float32) / jnp.sqrt(dh)
+    logits = _dot(r.astype(s_dt), page_k.astype(s_dt), ((1,), (1,)), s_dt)
+    logits = logits.astype(jnp.float32) / jnp.sqrt(dh)          # (1, cap)
     slot_ok = jax.lax.broadcasted_iota(jnp.int32, (1, cap), 1) < nvalid
     logits = jnp.where(slot_ok, logits, _NEG)
     # reference divides the self score in r.dtype before the f32 cast;
     # a dot (not mul+reduce) so the accumulation order matches einsum's
-    self_logit = (jax.lax.dot_general(r, r, (((1,), (1,)), ((), ()))) /
+    self_logit = (_dot(r, r, ((1,), (1,)), r.dtype) /
                   jnp.sqrt(dh)).astype(jnp.float32)             # (1, 1)
     all_logits = jnp.concatenate([logits, self_logit], axis=1)  # (1,cap+1)
     attn = jax.nn.softmax(all_logits, axis=-1)
 
-    v_new = v_ref[0]                   # (1, dh)
+    v_new = v_ref[0, 0]                # (1, dh)
     vals_dt = jnp.promote_types(page_v.dtype, v_new.dtype)
     vals = jnp.concatenate([page_v.astype(vals_dt),
                             v_new.astype(vals_dt)], axis=0)     # (cap+1,dh)
-    o = jax.lax.dot_general(attn.astype(vals_dt), vals,
-                            (((1,), (0,)), ((), ())))           # (1, dh)
-    o_ref[0] = o.astype(o_ref.dtype)
+    o = _dot(attn.astype(vals_dt), vals, ((1,), (0,)), vals_dt)  # (1, dh)
+    o_ref[0, 0] = o.astype(o_ref.dtype)
 
 
 def paged_routing_decode(r, v_new, rk, rv, rlen, cluster, interpret=None):
@@ -109,7 +114,7 @@ def paged_routing_decode(r, v_new, rk, rv, rlen, cluster, interpret=None):
     """
     B, Hr, dh = r.shape
     kc, cap = rk.shape[2], rk.shape[3]
-    tok_at = lambda b, h, *_: (b, h, 0)
+    tok_at = lambda b, h, *_: (b, h, 0, 0)
     # the paged-attention move: the index map reads the prefetched
     # cluster id, so only the selected page is ever DMA'd to VMEM
     page_at = lambda b, h, c_ref, rlen_ref: (b, h, c_ref[b, h], 0, 0)
@@ -117,18 +122,20 @@ def paged_routing_decode(r, v_new, rk, rv, rlen, cluster, interpret=None):
         num_scalar_prefetch=2,
         grid=(B, Hr),
         in_specs=[
-            pl.BlockSpec((1, 1, dh), tok_at),            # r
-            pl.BlockSpec((1, 1, dh), tok_at),            # v_new
+            pl.BlockSpec((1, 1, 1, dh), tok_at),         # r
+            pl.BlockSpec((1, 1, 1, dh), tok_at),         # v_new
             pl.BlockSpec((1, 1, 1, cap, dh), page_at),   # rk page
             pl.BlockSpec((1, 1, 1, cap, dh), page_at),   # rv page
         ],
-        out_specs=pl.BlockSpec((1, 1, dh), tok_at))
+        out_specs=pl.BlockSpec((1, 1, 1, dh), tok_at))
     out_dtype = jnp.promote_types(rv.dtype, v_new.dtype)
-    return pl.pallas_call(
+    o = pl.pallas_call(
         functools.partial(_decode_kernel, cap=cap, dh=dh),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hr, dh), out_dtype),
-        compiler_params=_CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((B, Hr, 1, dh), out_dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=default_interpret(interpret),
-    )(cluster.astype(jnp.int32), rlen.astype(jnp.int32), r, v_new, rk, rv)
+    )(cluster.astype(jnp.int32), rlen.astype(jnp.int32),
+      r[:, :, None], v_new[:, :, None], rk, rv)
+    return o[:, :, 0]

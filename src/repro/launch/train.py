@@ -25,6 +25,53 @@ import functools
 from repro.launch import distributed
 
 
+def make_sharded_step(run, mesh, seq_parallel: bool = False):
+    """The launcher's train step on ``mesh``: GSPMD (data, model) rules
+    with activation constraints, or the int8_ef shard_map step. Returns
+    ``(step, ts_spec)``: ``step(ts, batch)`` commits the batch to its
+    data sharding and runs the jitted step (state donated, output pinned
+    to ``ts_spec`` so it round-trips into the next step); ``step.jitted``
+    is the jitted function, for ``.lower(...)`` inspection."""
+    import jax
+
+    from repro.attn import specs_for_model
+    from repro.dist import sharding as shd
+    from repro.train.train_step import init_train_state, make_train_step
+
+    cfg = run.model
+    compressed = run.train.grad_compression == "int8_ef"
+    use_fsdp = cfg.param_count() > 20e9
+    ts_shapes = jax.eval_shape(
+        functools.partial(init_train_state, run, mesh=mesh),
+        jax.random.PRNGKey(0))
+    ts_spec = shd.train_state_sharding(mesh, ts_shapes, fsdp=use_fsdp)
+    constrain = (None if compressed else shd.make_constrain_fn(
+        mesh, seq_parallel, fsdp_prefetch=use_fsdp,
+        attn_specs=specs_for_model(cfg)))
+    fn = make_train_step(run, constrain_fn=constrain, mesh=mesh)
+
+    def pinned_fn(ts, batch):
+        # pin the output state to the rule layout so it round-trips into
+        # the next step's in_shardings (GSPMD would otherwise pick its own
+        # layout for unconstrained outputs, e.g. scanned norm scales)
+        new_ts, metrics = fn(ts, batch)
+        new_ts = jax.tree.map(jax.lax.with_sharding_constraint,
+                              new_ts, ts_spec)
+        return new_ts, metrics
+
+    tc = run.train
+    b_spec = shd.batch_sharding(mesh, {"tokens": jax.ShapeDtypeStruct(
+        (tc.global_batch, tc.seq_len + 1), "int32")})
+    jitted = jax.jit(pinned_fn, in_shardings=(ts_spec, b_spec),
+                     donate_argnums=(0,))
+
+    def sharded_step(ts, batch):
+        return jitted(ts, jax.device_put(batch, b_spec))
+
+    sharded_step.jitted = jitted
+    return sharded_step, ts_spec
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b")
@@ -60,7 +107,9 @@ def main():
                     help="this process's rank (or $REPRO_PROCESS_ID)")
     args = ap.parse_args()
 
-    # before ANY other jax API: registers the global device view
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
+    # before any jax backend use: registers the global device view
     multi = distributed.initialize(coordinator=args.coordinator,
                                    num_processes=args.num_processes,
                                    process_id=args.process_id)
@@ -71,8 +120,6 @@ def main():
     from repro.configs import ARCHS, get_config, reduced_config
     from repro.configs.base import RunConfig, TrainConfig, with_overrides
     from repro.data.synthetic import SyntheticLoader
-    from repro.dist import sharding as shd
-    from repro.train.train_step import init_train_state, make_train_step
     from repro.train.trainer import Trainer
 
     if args.arch not in ARCHS:
@@ -108,32 +155,7 @@ def main():
           f"process={info['process_index']}/{info['process_count']} "
           f"multi_host={multi} compression={run.train.grad_compression}")
 
-    use_fsdp = cfg.param_count() > 20e9
-    ts_shapes = jax.eval_shape(
-        functools.partial(init_train_state, run, mesh=mesh),
-        jax.random.PRNGKey(0))
-    ts_spec = shd.train_state_sharding(mesh, ts_shapes, fsdp=use_fsdp)
-    from repro.attn import specs_for_model
-    constrain = (None if compressed else shd.make_constrain_fn(
-        mesh, args.seq_parallel, fsdp_prefetch=use_fsdp,
-        attn_specs=specs_for_model(cfg)))
-    fn = make_train_step(run, constrain_fn=constrain, mesh=mesh)
-
-    def pinned_fn(ts, batch):
-        # pin the output state to the rule layout so it round-trips into
-        # the next step's in_shardings (GSPMD would otherwise pick its own
-        # layout for unconstrained outputs, e.g. scanned norm scales)
-        new_ts, metrics = fn(ts, batch)
-        new_ts = jax.tree.map(jax.lax.with_sharding_constraint,
-                              new_ts, ts_spec)
-        return new_ts, metrics
-
-    def sharded_step(ts, batch):
-        b_spec = shd.batch_sharding(mesh, batch)
-        batch = jax.device_put(batch, b_spec)
-        return jax.jit(pinned_fn, in_shardings=(ts_spec, b_spec),
-                       donate_argnums=(0,))(ts, batch)
-
+    sharded_step, ts_spec = make_sharded_step(run, mesh, args.seq_parallel)
     loader = SyntheticLoader("markov", min(cfg.vocab_size, 512),
                              args.batch, args.seq)
     from repro.obs import trace as obs_trace

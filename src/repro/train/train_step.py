@@ -50,14 +50,12 @@ def _ef_devices(mesh=None) -> int:
 
 
 def init_ef_state(params, num_devices: int):
-    """Zero residuals, (D, *param.shape) fp32 per leaf.
-
-    Host-side numpy zeros (lazy calloc pages), NOT jnp: the tree is D x
-    total-params fp32 and would otherwise materialize on the default
-    device before the caller's sharded device_put gets a chance."""
-    import numpy as np
+    """Zero residuals, (D, *param.shape) fp32 per leaf. The tree is D x
+    total-params fp32: initialize it under ``jax.jit(...,
+    out_shardings=...)`` (as Trainer does on a mesh) so each device
+    builds only its own residual."""
     return jax.tree.map(
-        lambda p: np.zeros((num_devices,) + tuple(p.shape), np.float32),
+        lambda p: jnp.zeros((num_devices,) + tuple(p.shape), jnp.float32),
         params)
 
 
@@ -258,7 +256,6 @@ def make_compressed_train_step(run: RunConfig, impl=None,
     across devices. On a 1-device mesh the wire vanishes and the step
     degenerates to the exact uncompressed computation.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.dist.compression import int8_ef_psum_mean
@@ -266,7 +263,8 @@ def make_compressed_train_step(run: RunConfig, impl=None,
 
     tc = run.train
     if mesh is None:
-        mesh = jax.make_mesh((len(jax.devices()),), ("data",))
+        from repro.launch.mesh import auto_mesh
+        mesh = auto_mesh((len(jax.devices()),), ("data",))
     if _axis_size(mesh, "model") > 1:
         raise ValueError(
             "int8_ef grad compression is data-parallel only; got a mesh "
@@ -316,11 +314,11 @@ def make_compressed_train_step(run: RunConfig, impl=None,
         # fp32 leaves exactly (tiny payloads — not worth compressing)
         return mean_g, new_ef, pmean_tree(new_k), sync_metrics(metrics)
 
-    smapped = shard_map(
+    smapped = jax.shard_map(
         sharded_grads, mesh=mesh,
         in_specs=(P(), P(), P(dp), P(dp), P()),
         out_specs=(P(), P(dp), P(), P()),
-        check_rep=False)
+        check_vma=False)
 
     def train_step(ts: TrainState, batch: Dict[str, jax.Array]):
         lead = {e.shape[0] for e in jax.tree_util.tree_leaves(ts.ef_state)}

@@ -20,6 +20,7 @@ Fault-tolerance contract:
 from __future__ import annotations
 
 import contextlib
+import functools
 import signal
 import statistics
 import time
@@ -76,7 +77,12 @@ class Trainer:
     # ------------------------------------------------------------------
     def init_or_restore(self) -> TrainState:
         key = jax.random.PRNGKey(self.run.train.seed)
-        state = init_train_state(self.run, key, mesh=self.mesh)
+        init = functools.partial(init_train_state, self.run, mesh=self.mesh)
+        # on a mesh every leaf is built in its rule layout: no transient
+        # copy of the whole state (params, optimizer moments, the per-
+        # device int8_ef residuals) on device 0
+        state = (init(key) if self.shardings is None else
+                 jax.jit(init, out_shardings=self.shardings)(key))
         if self.mgr is not None and self.mgr.latest_step() is not None:
             # checkpoints hold the field-named dict, not the bare tuple,
             # so leaves are keyed "params/...", "ef_state/..." on disk
@@ -97,10 +103,6 @@ class Trainer:
                 state = legacy._replace(ef_state=state.ef_state)
             if "loader" in extra:
                 self.loader.restore(extra["loader"])
-        elif self.shardings is not None:
-            # fresh init on a mesh: commit the rule layout up front so
-            # the first step's in_shardings see it (no device-0 transient)
-            state = jax.device_put(state, self.shardings)
         self.state = state
         return state
 

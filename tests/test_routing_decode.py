@@ -10,9 +10,9 @@ The contract under test (kernels/routing_decode.py):
   bitwise equality of f32 reductions across differently-compiled
   programs is compiler-dependent — see the kernel docstring);
 * garbage in beyond-min(rlen,cap) page slots cannot leak;
-* TPU auto-resolution (and the REPRO_ATTN_PLATFORM/REPRO_FORCE_INTERPRET
-  forced-interpret path) picks pallas_paged for decode while apply stays
-  on pallas_fused.
+* TPU auto-resolution (also when a CPU host resolves for "tpu" and runs
+  the kernels in interpret mode) picks pallas_paged for decode while
+  apply stays on pallas_fused.
 """
 import jax
 import jax.numpy as jnp
@@ -45,6 +45,14 @@ def _mu(spec, key):
     mu = jax.random.normal(key, (Hr, spec.routing.num_clusters,
                                  spec.head_dim), jnp.float32)
     return mu / jnp.linalg.norm(mu, axis=-1, keepdims=True)
+
+
+def _resolve_for_tpu(monkeypatch):
+    """Make attention resolution default to the TPU backends on this
+    host; the kernels still run in interpret mode, since that derives
+    from the backend the program really runs on."""
+    monkeypatch.setattr(attn, "_platform",
+                        lambda platform=None: platform or "tpu")
 
 
 def _tree_bitwise(a, b):
@@ -145,20 +153,22 @@ def test_decode_resolution_mesh_falls_back_to_xla():
 
 
 def test_forced_interpret_env_resolution(monkeypatch):
-    """REPRO_ATTN_PLATFORM=tpu + REPRO_FORCE_INTERPRET=1 routes auto
-    resolution to the TPU backends in interpret mode on a CPU host."""
-    monkeypatch.setenv("REPRO_ATTN_PLATFORM", "tpu")
-    monkeypatch.setenv("REPRO_FORCE_INTERPRET", "1")
+    """Resolving for "tpu" on a CPU host routes auto resolution to the
+    TPU backends, which run in interpret mode there."""
+    from repro.kernels.common import default_interpret
+    _resolve_for_tpu(monkeypatch)
     for variant in ("routing", "local+routing"):
         assert attn.decode_backend(_spec(variant)).impl == "pallas_paged"
-    monkeypatch.delenv("REPRO_ATTN_PLATFORM")
-    assert attn.decode_backend(_spec("routing")).impl == "xla"
+    assert default_interpret(None) is (jax.default_backend() != "tpu")
+    monkeypatch.undo()
+    assert attn.decode_backend(_spec("routing")).impl == (
+        "pallas_paged" if jax.default_backend() == "tpu" else "xla")
 
 
 def test_model_decode_token_and_logit_parity(monkeypatch):
     """The acceptance gate: a real model decodes greedily for 24 steps
-    under forced-interpret TPU resolution (pallas_paged decode) and
-    under the default CPU resolution (xla decode) from the same prefill;
+    under TPU resolution (pallas_paged decode, interpret mode off the
+    chip) and under CPU resolution (xla decode) from the same prefill;
     token streams must match exactly, per-step vocab logits to ulps,
     and the cluster-page cache trajectories bit for bit."""
     cfg = ModelConfig(name="pd", family="dense", attention="local+routing",
@@ -174,14 +184,23 @@ def test_model_decode_token_and_logit_parity(monkeypatch):
     cache_x = cache
     cache_p = jax.tree.map(lambda x: x, cache)
 
-    step_xla = jax.jit(make_serve_step(cfg))
-    monkeypatch.setenv("REPRO_ATTN_PLATFORM", "tpu")
-    monkeypatch.setenv("REPRO_FORCE_INTERPRET", "1")
+    tok_x = tok_p = lg[:, -1].argmax(-1).astype(jnp.int32)
+    pos0 = jnp.full((B,), TP, jnp.int32)
+
+    def compiled_step():
+        # backends resolve while tracing: compile under each resolution
+        return jax.jit(make_serve_step(cfg)).lower(
+            params, kstate, cache, tok_x, pos0).compile()
+
+    monkeypatch.setattr(attn, "_platform", lambda platform=None: "cpu")
+    assert attn.decode_backend(
+        attn.spec_for_layer(cfg, cfg.attention)).impl == "xla"
+    step_xla = compiled_step()
+    _resolve_for_tpu(monkeypatch)
     assert attn.decode_backend(
         attn.spec_for_layer(cfg, cfg.attention)).impl == "pallas_paged"
-    step_paged = jax.jit(make_serve_step(cfg))
+    step_paged = compiled_step()
 
-    tok_x = tok_p = lg[:, -1].argmax(-1).astype(jnp.int32)
     for t in range(TP, TP + steps):
         pos = jnp.full((B,), t, jnp.int32)
         lg_x, cache_x = step_xla(params, kstate, cache_x, tok_x, pos)

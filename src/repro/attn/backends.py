@@ -76,6 +76,7 @@ from repro.core.local import local_attention
 from repro.core.routing import routed_attention
 from repro.kernels.common import FUSED_RESIDENT_ELEMS
 from repro.models import layers as L
+from repro.obs.trace import span
 
 _BIG_NEG = -1e9
 
@@ -90,9 +91,10 @@ def _rope_qk(spec: AttentionSpec, q, k, positions):
     B, _, N, _ = q.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(N, dtype=jnp.int32), (B, N))
-    q = L.apply_rope(q, positions, spec.rope_theta)
-    if k is not None:
-        k = L.apply_rope(k, positions, spec.rope_theta)
+    with span("model/attention_proj"):
+        q = L.apply_rope(q, positions, spec.rope_theta)
+        if k is not None:
+            k = L.apply_rope(k, positions, spec.rope_theta)
     return q, k
 
 
@@ -365,12 +367,12 @@ def _routing_decode_paged(spec, q, k, v, *, cache, pos, state=None,
     the Pallas kernel, which DMAs only the selected cluster page into
     VMEM through scalar-prefetched page tables (kernels.routing_decode)
     instead of materializing a gathered page copy in HBM."""
-    from repro.kernels.routing_decode import paged_routing_decode
+    from repro.kernels import ops as kops
     mu = state
     v = _expand_kv(v, spec.q_per_kv)
     r, c, plen = _route_token(q, mu, cache)
-    o = paged_routing_decode(r, v[:, :, 0], cache["rk"], cache["rv"],
-                             cache["rlen"], c, interpret=interpret)
+    o = kops.paged_routing_decode(r, v[:, :, 0], cache["rk"], cache["rv"],
+                                  cache["rlen"], c, interpret=interpret)
     new_cache = _write_page_slot(cache, r, v[:, :, 0], c, plen)
     return o[:, :, None, :], new_cache
 

@@ -34,6 +34,7 @@ import jax.numpy as jnp
 from repro.configs.base import RoutingConfig, with_overrides
 from repro.core.kmeans import (KMeansState, cluster_scores, ema_update,
                                normalize_routing)
+from repro.obs.trace import span
 
 _BIG_NEG = -1e9
 
@@ -164,20 +165,23 @@ def routed_attention(q: jax.Array,
     w = min(cfg.window or max(1, N // cfg.num_clusters), N)
     shared = cfg.share_qk and cfg.causal
 
-    r_q = normalize_routing(q)
-    if shared:
-        r_k, k_attn = r_q, r_q
-    else:
-        r_k = normalize_routing(k if k is not None else q)
-        k_attn = r_k
+    # spans (repro.obs) name the stages in the compiled step's op_name
+    # metadata, so the device trace attributes their time
+    with span("routing/assign"):
+        r_q = normalize_routing(q)
+        if shared:
+            r_k, k_attn = r_q, r_q
+        else:
+            r_k = normalize_routing(k if k is not None else q)
+            k_attn = r_k
 
-    scores_q = cluster_scores(r_q, state.mu)             # (B,H,N,k)
-    q_idx = balanced_topk(scores_q, w, pad_mask)         # (B,H,k,w)
-    if shared:
-        k_idx = q_idx
-    else:
-        scores_k = cluster_scores(r_k, state.mu)
-        k_idx = balanced_topk(scores_k, w, pad_mask)
+        scores_q = cluster_scores(r_q, state.mu)         # (B,H,N,k)
+        q_idx = balanced_topk(scores_q, w, pad_mask)     # (B,H,k,w)
+        if shared:
+            k_idx = q_idx
+        else:
+            scores_k = cluster_scores(r_k, state.mu)
+            k_idx = balanced_topk(scores_k, w, pad_mask)
 
     if impl in _FUSED_IMPLS:
         # gather-free: q/k/v stay in sequence layout; the kernel pulls
@@ -193,24 +197,25 @@ def routed_attention(q: jax.Array,
             paged=_FUSED_IMPLS[impl])
         attn = None
     else:
-        qg = _gather_rows(r_q, q_idx)                    # (B,H,k,w,dh)
-        # shared-QK causal: k_attn is r_q and k_idx is q_idx, so the key
-        # gather is identical to the query gather — reuse it
-        kg = qg if shared else _gather_rows(k_attn, k_idx)
-        vg = _gather_rows(v, k_idx)
-        pos = positions[:, None, :].astype(jnp.int32)
-        pos_q = jnp.take_along_axis(
-            jnp.broadcast_to(pos, (B, H, N)), q_idx.reshape(B, H, -1),
-            axis=2).reshape(B, H, q_idx.shape[2], w)
-        pos_k = pos_q if shared else jnp.take_along_axis(
-            jnp.broadcast_to(pos, (B, H, N)), k_idx.reshape(B, H, -1),
-            axis=2).reshape(B, H, k_idx.shape[2], w)
+        with span("routing/gather"):
+            qg = _gather_rows(r_q, q_idx)                # (B,H,k,w,dh)
+            # shared-QK causal: k_attn is r_q and k_idx is q_idx, so the
+            # key gather is identical to the query gather — reuse it
+            kg = qg if shared else _gather_rows(k_attn, k_idx)
+            vg = _gather_rows(v, k_idx)
+            pos = positions[:, None, :].astype(jnp.int32)
+            pos_q = jnp.take_along_axis(
+                jnp.broadcast_to(pos, (B, H, N)), q_idx.reshape(B, H, -1),
+                axis=2).reshape(B, H, q_idx.shape[2], w)
+            pos_k = pos_q if shared else jnp.take_along_axis(
+                jnp.broadcast_to(pos, (B, H, N)), k_idx.reshape(B, H, -1),
+                axis=2).reshape(B, H, k_idx.shape[2], w)
 
-        valid_k = None
-        if pad_mask is not None:
-            vm = jnp.broadcast_to(pad_mask[:, None, :], (B, H, N))
-            valid_k = jnp.take_along_axis(
-                vm, k_idx.reshape(B, H, -1), axis=2).reshape(pos_k.shape)
+            valid_k = None
+            if pad_mask is not None:
+                vm = jnp.broadcast_to(pad_mask[:, None, :], (B, H, N))
+                valid_k = jnp.take_along_axis(
+                    vm, k_idx.reshape(B, H, -1), axis=2).reshape(pos_k.shape)
 
         if impl == "pallas":
             from repro.kernels import ops as kops
@@ -219,14 +224,18 @@ def routed_attention(q: jax.Array,
                 valid_k=valid_k, interpret=interpret)
             attn = None
         else:
-            og, attn = _block_attention(qg, kg, vg, pos_q, pos_k,
-                                        cfg.causal, valid_k, return_attn)
+            with span("routing/attend"):
+                og, attn = _block_attention(qg, kg, vg, pos_q, pos_k,
+                                            cfg.causal, valid_k,
+                                            return_attn)
 
-    out = _scatter_rows(og, q_idx, N, cfg.scatter_mode)
+    with span("routing/scatter"):
+        out = _scatter_rows(og, q_idx, N, cfg.scatter_mode)
     new_state = state
     if update_state:
-        new_state = ema_update(
-            state, r_q, None if shared else r_k, pad_mask, cfg.decay)
+        with span("routing/kmeans_update"):
+            new_state = ema_update(
+                state, r_q, None if shared else r_k, pad_mask, cfg.decay)
     stats = None
     if cfg.stats:
         # routing-health telemetry (repro.obs, DESIGN.md §10): reuses the

@@ -24,6 +24,7 @@ import jax
 from repro.kernels import flash_attention as _flash
 from repro.kernels import local_attention as _local
 from repro.kernels import routing_attention as _routing
+from repro.kernels import routing_decode as _decode
 from repro.obs.trace import span
 
 
@@ -68,3 +69,13 @@ def routed_attention_fused(q, k, v, q_idx, k_idx, positions, causal=True,
         return _routing.routed_attention_fused(
             q, k, v, q_idx, k_idx, positions, causal=causal, kvalid=kvalid,
             bq=bq, bk=bk, interpret=interpret, paged=paged)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def paged_routing_decode(r, v_new, rk, rv, rlen, cluster, interpret=None):
+    """One decoded token of routed attention over the cluster-paged cache
+    (kernels/routing_decode.py): the selected page is DMA'd by its
+    scalar-prefetched cluster id, never gathered in HBM."""
+    with span("kernels/paged_routing_decode"):
+        return _decode.paged_routing_decode(r, v_new, rk, rv, rlen, cluster,
+                                            interpret=interpret)

@@ -61,6 +61,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.common import NEG as _NEG
 from repro.kernels.common import (default_interpret, float0_like,
                                   fused_paged_default, fused_vmem_limit)
+from repro.obs.trace import span
 
 SENTINEL = 2 ** 30          # python int: usable inside the kernel body
 
@@ -604,7 +605,10 @@ def _fused_bwd_call(qf, kf, vf, qi, ki, pqg, pkg, out, lse, do, shared,
     _, kc, _, w = qi.shape
     f32 = jnp.float32
     nq, nk = w // bq, w // bk
-    dsum = (do * out).sum(-1)[:, :, None, :]
+    # the softmax backward's row sums, in XLA: the routing stage's
+    # attention work, outside the kernel's span
+    with span("routing/attend"):
+        dsum = (do * out).sum(-1)[:, :, None, :]
     kern_kw = dict(shared=shared, causal=causal, scale=1.0 / (dh ** 0.5),
                    bq=bq, bk=bk, resident=resident)
     params = _fused_params(N, dh, shared, resident)
@@ -655,13 +659,18 @@ def _fused_bwd_call(qf, kf, vf, qi, ki, pqg, pkg, out, lse, do, shared,
 
     # scatter-add per-cluster gradient blocks back to sequence layout —
     # the exact transpose of the kernel's implicit gather; duplicate
-    # memberships accumulate
-    bi = jnp.arange(BH)[:, None]
-    qi2 = qi.reshape(BH, -1)
-    ki2 = ki.reshape(BH, -1)
-    dq = jnp.zeros((BH, N, dh), f32).at[bi, qi2].add(dqg.reshape(BH, -1, dh))
-    dk = jnp.zeros((BH, N, dh), f32).at[bi, ki2].add(dkg.reshape(BH, -1, dh))
-    dv = jnp.zeros((BH, N, dh), f32).at[bi, ki2].add(dvg.reshape(BH, -1, dh))
+    # memberships accumulate. XLA work, so it takes the routing stage's
+    # span rather than the kernel's
+    with span("routing/scatter"):
+        bi = jnp.arange(BH)[:, None]
+        qi2 = qi.reshape(BH, -1)
+        ki2 = ki.reshape(BH, -1)
+        dq = jnp.zeros((BH, N, dh), f32).at[bi, qi2].add(
+            dqg.reshape(BH, -1, dh))
+        dk = jnp.zeros((BH, N, dh), f32).at[bi, ki2].add(
+            dkg.reshape(BH, -1, dh))
+        dv = jnp.zeros((BH, N, dh), f32).at[bi, ki2].add(
+            dvg.reshape(BH, -1, dh))
     return dq, dk, dv
 
 
@@ -735,7 +744,11 @@ def routed_attention_fused(q, k, v, q_idx, k_idx, positions, causal=True,
                                    axis=1).reshape(B * H, kc, 1, w)
 
     resident = not fused_paged_default(N, dh, 2 if shared else 3, paged)
+    # XLA's gathers of the members' positions take the routing stage's
+    # span, so the kernel's span holds the kernel's own time
+    with span("routing/gather"):
+        pqg, pkg = member_pos(posq, qi), member_pos(posk, ki)
     out = _routed_fused(shared, bool(causal), int(bq), int(bk), resident,
                         default_interpret(interpret), qf, kf, vf, qi, ki,
-                        member_pos(posq, qi), member_pos(posk, ki))
+                        pqg, pkg)
     return out.reshape(B, H, kc, w, dh).astype(q.dtype)

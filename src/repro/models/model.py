@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.models import layers as L
 from repro.models import transformer as T
+from repro.obs.trace import span
 
 
 def init_model(cfg: ModelConfig, key: jax.Array):
@@ -53,7 +54,8 @@ def apply_model(params, kstate, batch: Dict[str, jax.Array],
             x = jnp.where(batch["mask_spans"][..., None],
                           params["mask_emb"].astype(x.dtype), x)
     else:
-        x = L.embed(params["embed"], batch["tokens"])
+        with span("model/embed"):
+            x = L.embed(params["embed"], batch["tokens"])
     x, new_kstate, aux = T.apply_stack(
         params["stack"], kstate, x, cfg,
         positions=positions, pad_mask=pad_mask,
@@ -64,10 +66,12 @@ def apply_model(params, kstate, batch: Dict[str, jax.Array],
     epilogue = getattr(constrain_fn, "epilogue", None)
     if epilogue is not None:
         x = epilogue(x)          # SP epilogue: re-gather seq for the LM head
-    x = L.apply_norm(params["final_norm"], x, cfg.norm)
-    logits = L.logits_out(params["embed"], x, cfg.tie_embeddings,
-                          cfg.logit_softcap)
-    logits = mask_vocab_pad(logits, cfg)
+    # the head (final norm, unembed) and the loss share one span
+    with span("model/loss"):
+        x = L.apply_norm(params["final_norm"], x, cfg.norm)
+        logits = L.logits_out(params["embed"], x, cfg.tie_embeddings,
+                              cfg.logit_softcap)
+        logits = mask_vocab_pad(logits, cfg)
     return logits, new_kstate, aux
 
 

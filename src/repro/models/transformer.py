@@ -30,6 +30,7 @@ from repro.models import layers as L
 from repro.models import moe as moe_mod
 from repro.models import rglru as rglru_mod
 from repro.models import ssm as ssm_mod
+from repro.obs.trace import span
 
 AUX_KEYS = ("moe_lb_loss", "moe_z_loss", "moe_drop_frac")
 
@@ -156,12 +157,15 @@ def self_attention(p, h, cfg: ModelConfig, mode: str, kmu,
     """h: (B,N,d) -> ((B,N,d), new_kmu, stats). ``stats`` is the
     obs.RoutingStats aux of a routing variant with RoutingConfig.stats
     on, else None."""
-    q, k, v = L.qkv_project(p, h, cfg, positions, rope=False)
+    with span("model/attention_proj"):
+        q, k, v = L.qkv_project(p, h, cfg, positions, rope=False)
     out = attn_api.attend(spec_for_layer(cfg, mode), q, k, v, state=kmu,
                           positions=positions, pad_mask=pad_mask,
                           update_state=update_state, impl=impl, mesh=mesh,
                           needs_grad=needs_grad)
-    return L.out_project(p, out.out), out.state, out.stats
+    with span("model/attention_proj"):
+        o = L.out_project(p, out.out)
+    return o, out.state, out.stats
 
 
 def cross_attention(p, h, image_embeds, cfg: ModelConfig, pad_mask=None):
@@ -195,7 +199,10 @@ def apply_layer(spec: LayerSpec, p, kmu, x, cfg: ModelConfig, *,
     rngs = (jax.random.split(drop_rng, 2) if drop_rng is not None
             else (None, None))
     if spec.kind in ("attn", "moe", "cross"):
-        h = L.apply_norm(p["ln1"], x, cfg.norm)
+        # spans (repro.obs) at the sublayer boundaries: norms, residual
+        # adds and dropout go with the projections or the FFN they wrap
+        with span("model/attention_proj"):
+            h = L.apply_norm(p["ln1"], x, cfg.norm)
         if spec.kind == "cross":
             a = cross_attention(p["attn"], h, image_embeds, cfg)
             a = a * jnp.tanh(p["xgate_attn"]).astype(a.dtype)
@@ -207,17 +214,20 @@ def apply_layer(spec: LayerSpec, p, kmu, x, cfg: ModelConfig, *,
                 # rides in aux (popped by apply_stack / prefill into the
                 # scan ys; NOT one of the fixed AUX_KEYS scalars)
                 aux["routing_stats"] = a_stats
-        x = x + _dropout(a, cfg.dropout, rngs[0])
-        h2 = L.apply_norm(p["ln2"], x, cfg.norm)
-        if spec.kind == "moe":
-            ff, moe_aux = moe_mod.apply_moe(p["ffn"], h2, cfg, impl=moe_impl)
-            aux.update({k: jnp.asarray(v, jnp.float32)
-                        for k, v in moe_aux.items()})
-        else:
-            ff = L.apply_mlp(p["ffn"], h2, cfg.act)
-            if spec.kind == "cross":
-                ff = ff * jnp.tanh(p["xgate_ffn"]).astype(ff.dtype)
-        x = x + _dropout(ff, cfg.dropout, rngs[1])
+        with span("model/attention_proj"):
+            x = x + _dropout(a, cfg.dropout, rngs[0])
+        with span("model/ffn"):
+            h2 = L.apply_norm(p["ln2"], x, cfg.norm)
+            if spec.kind == "moe":
+                ff, moe_aux = moe_mod.apply_moe(p["ffn"], h2, cfg,
+                                                impl=moe_impl)
+                aux.update({k: jnp.asarray(v, jnp.float32)
+                            for k, v in moe_aux.items()})
+            else:
+                ff = L.apply_mlp(p["ffn"], h2, cfg.act)
+                if spec.kind == "cross":
+                    ff = ff * jnp.tanh(p["xgate_ffn"]).astype(ff.dtype)
+            x = x + _dropout(ff, cfg.dropout, rngs[1])
     elif spec.kind == "ssd":
         h = L.apply_norm(p["ln1"], x, cfg.norm)
         y, _ = ssm_mod.apply_ssd(p["mixer"], h, cfg)
@@ -322,8 +332,12 @@ def apply_stack(seg_params, seg_kstate, x, cfg: ModelConfig, *,
             return (x, aux), (new_k, stats_g)
 
         xs = (seg_params[si], seg_kstate[si], jnp.arange(G))
-        (x, aux_tot), (new_k, seg_st) = jax.lax.scan(
-            scan_body, (x, aux_tot), xs)
+        # the loop's own work (slicing each group's weights, stacking
+        # outputs and weight gradients) is named by this span; the
+        # layers' spans inside it name theirs
+        with span("model/stack"):
+            (x, aux_tot), (new_k, seg_st) = jax.lax.scan(
+                scan_body, (x, aux_tot), xs)
         new_seg_kstate.append(new_k)
         seg_stats.append(seg_st)
         layer_counter += G * len(pattern)

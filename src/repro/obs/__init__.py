@@ -10,9 +10,11 @@ Three layers, all importable from here:
             drift, balanced-vs-nearest mismatch, sampled attention
             recall) — plus summarize/flatten folds and the serving-side
             pages_health reader
-  trace     span(name) — named_scope + TraceAnnotation around kernels
-            and train/engine phases; profile(log_dir) — on-demand xplane
-            capture behind --profile-dir flags
+  trace     span(name) — named_scope + TraceAnnotation around kernels,
+            model layers, routing stages and train/engine phases;
+            step_span(step) — one training step on the profiler's
+            timeline; profile(log_dir) — on-demand xplane capture behind
+            --profile-dir flags
 
 This package sits at the bottom of the import DAG (jax + stdlib only):
 core/, train/, serve/, kernels/ all report through it, so it must never
@@ -27,4 +29,4 @@ from repro.obs.routing_stats import (RoutingStats,  # noqa: F401
                                      compute_routing_stats, pages_health)
 from repro.obs.schema import (SchemaError, validate_jsonl,  # noqa: F401
                               validate_record)
-from repro.obs.trace import profile, span  # noqa: F401
+from repro.obs.trace import profile, span, step_span  # noqa: F401

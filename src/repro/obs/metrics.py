@@ -13,10 +13,10 @@ Three primitives:
              trainer's step-time distribution
 
 ``Registry`` is a typed name -> instrument map with ``summary()`` (flat
-dict, histograms expanded to count/mean/min/max/p50/p90/p99) and
-``to_csv()``. One process-wide default registry exists for code that has
-no better home for its instruments; subsystems that own a lifecycle
-(EngineMetrics, Trainer) hold their own Registry.
+dict, histograms expanded to count/mean/min/max/p50/p90/p99). One
+process-wide default registry exists for code that has no better home
+for its instruments; subsystems that own a lifecycle (EngineMetrics,
+Trainer) hold their own Registry.
 
 ``JsonlSink`` writes one schema-versioned JSON line per event (see
 repro.obs.schema for the record contract and the validating CLI);
@@ -165,12 +165,6 @@ class Registry:
             else:
                 out[name] = inst.value
         return out
-
-    def to_csv(self) -> str:
-        lines = ["name,value"]
-        for k, v in self.summary().items():
-            lines.append(f"{k},{'' if v is None else v}")
-        return "\n".join(lines) + "\n"
 
 
 _DEFAULT = Registry()

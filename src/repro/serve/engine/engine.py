@@ -97,6 +97,9 @@ class Request:
     sampling: SamplingParams = field(default_factory=SamplingParams)
     arrival_step: int = 0       # engine step at which the request shows up
     priority: int = 0           # higher admits first and preempts lower
+    # when the request was due, on the time.perf_counter clock: TTFT and
+    # queue wait run from it (None: from submit())
+    arrival_time: Optional[float] = None
     state: str = WAITING
     output: List[int] = field(default_factory=list)
 
@@ -371,7 +374,8 @@ class InferenceEngine:
         request.state = WAITING
         self.scheduler.submit(request)
         self.metrics.on_submit(request.uid, request.prompt_len,
-                               self.step_count)
+                               self.step_count,
+                               arrival_time=request.arrival_time)
         return SessionHandle(self, request)
 
     # -- slot accounting ---------------------------------------------------
@@ -664,6 +668,7 @@ class InferenceEngine:
             self._resume_into(slot, req)
         else:
             self._parked.pop(req.uid, None)     # held-before-prefill
+            self.metrics.on_admit(req.uid)
             self._prefill_into(slot, req)
 
     def _prefill_into(self, slot: int, req: Request) -> None:

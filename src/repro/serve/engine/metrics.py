@@ -8,11 +8,14 @@ batcher is supposed to move versus lock-step batching, and
 costs the same jitted call regardless of how many slots are active).
 
 Latency distributions are backed by ``repro.obs`` histograms:
-  engine/ttft_s          per-request time to first token
+  engine/ttft_s          per-request time to first token, from the
+                         request's arrival (``Request.arrival_time`` on
+                         the perf-counter clock; ``submit()`` when unset)
+  engine/queue_wait_s    per-request arrival to first admission
   engine/decode_step_s   wall time of each batched decode dispatch
-  engine/itl_s           per-request mean inter-token latency
-                         (finish - first token) / (n_generated - 1),
-                         recorded at finish for requests with >= 2 tokens
+  engine/itl_s           every gap between consecutive tokens of a
+                         request (``RequestStats.itl_s`` stays the
+                         per-request mean)
 ``summary()`` keeps every pre-existing key and adds their p50/p90/p99.
 """
 from __future__ import annotations
@@ -30,20 +33,27 @@ class RequestStats:
     prompt_len: int
     submit_time: float
     arrival_step: int = 0
+    arrival_time: Optional[float] = None    # None: the submit time
+    admit_time: Optional[float] = None      # first placement in a slot
     slot: Optional[int] = None
     prefill_step: Optional[int] = None      # engine step of the first token
     first_token_time: Optional[float] = None
+    last_token_time: Optional[float] = None
     finish_step: Optional[int] = None
     finish_time: Optional[float] = None
     n_generated: int = 0
     parks: int = 0                          # times parked to the KV store
     resumes: int = 0                        # times resumed from it
 
+    def __post_init__(self):
+        if self.arrival_time is None:
+            self.arrival_time = self.submit_time
+
     @property
     def ttft_s(self) -> Optional[float]:
         if self.first_token_time is None:
             return None
-        return self.first_token_time - self.submit_time
+        return self.first_token_time - self.arrival_time
 
     @property
     def itl_s(self) -> Optional[float]:
@@ -69,19 +79,30 @@ class EngineMetrics:
         self.occupancy_sum = 0          # active slots summed over decode steps
         self.obs = Registry()
         self._ttft = self.obs.histogram("engine/ttft_s")
+        self._queue_wait = self.obs.histogram("engine/queue_wait_s")
         self._decode_step = self.obs.histogram("engine/decode_step_s")
         self._itl = self.obs.histogram("engine/itl_s")
 
-    def on_submit(self, uid: int, prompt_len: int, step: int) -> None:
+    def on_submit(self, uid: int, prompt_len: int, step: int,
+                  arrival_time: Optional[float] = None) -> None:
         self.requests[uid] = RequestStats(uid, prompt_len, self.clock(),
-                                          arrival_step=step)
+                                          arrival_step=step,
+                                          arrival_time=arrival_time)
+
+    def on_admit(self, uid: int) -> None:
+        """First placement of a request in a slot (re-admissions after a
+        park are not queueing)."""
+        r = self.requests[uid]
+        if r.admit_time is None:
+            r.admit_time = self.clock()
+            self._queue_wait.record(r.admit_time - r.arrival_time)
 
     def on_prefill(self, uid: int, slot: int, step: int, n_tokens: int,
                    dt_s: float) -> None:
         r = self.requests[uid]
         r.slot, r.prefill_step = slot, step
         r.first_token_time = self.clock()
-        self._ttft.record(r.first_token_time - r.submit_time)
+        self._ttft.record(r.first_token_time - r.arrival_time)
         self.prefill_tokens += n_tokens
         self.prefill_time_s += dt_s
 
@@ -93,7 +114,12 @@ class EngineMetrics:
         self._decode_step.record(dt_s)
 
     def on_token(self, uid: int) -> None:
-        self.requests[uid].n_generated += 1
+        r = self.requests[uid]
+        r.n_generated += 1
+        now = self.clock()
+        if r.last_token_time is not None:
+            self._itl.record(now - r.last_token_time)
+        r.last_token_time = now
 
     def on_park(self, uid: int, step: int) -> None:
         self.requests[uid].parks += 1
@@ -107,8 +133,6 @@ class EngineMetrics:
         r = self.requests[uid]
         r.finish_step = step
         r.finish_time = self.clock()
-        if r.itl_s is not None:
-            self._itl.record(r.itl_s)
 
     @property
     def decode_tokens_per_s(self) -> float:
@@ -145,6 +169,7 @@ class EngineMetrics:
             "resumes": sum(r.resumes for r in self.requests.values()),
         }
         for hname, h in (("ttft", self._ttft), ("itl", self._itl),
+                         ("queue_wait", self._queue_wait),
                          ("decode_step", self._decode_step)):
             if h.count:
                 for p in (50, 90, 99):

@@ -90,7 +90,9 @@ def make_loss_fn(run: RunConfig, impl=None, moe_impl="einsum",
             moe_impl=moe_impl, remat=tc.remat, drop_rng=drop_rng,
             constrain_fn=constrain_fn, mesh=mesh, needs_grad=True)
         pad = inputs.get("pad_mask")
-        loss, metrics = lm_loss(logits, targets, pad, tc.z_loss, loss_mask)
+        with span("model/loss"):
+            loss, metrics = lm_loss(logits, targets, pad, tc.z_loss,
+                                    loss_mask)
         if mc.family == "moe":
             loss = (loss + MOE_LB_COEF * aux["moe_lb_loss"]
                     + MOE_Z_COEF * aux["moe_z_loss"])

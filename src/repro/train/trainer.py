@@ -9,7 +9,8 @@ Fault-tolerance contract:
   * SIGTERM/SIGINT (preemption notice) triggers a final synchronous
     checkpoint before exit — at most `ckpt_every` steps of work lost under
     normal operation, ~0 steps under graceful preemption.
-  * Straggler mitigation: per-step wall times feed a rolling median; steps
+  * Straggler mitigation: per-step wall times (from the batch draw to
+    the step's metrics on the host) feed a rolling median; steps
     slower than `straggler_factor` x median increment a counter and invoke
     `on_straggler` (hook for re-balancing grad-accum microbatches or
     alerting). On a real fleet this is fed per-host; here it is wired and
@@ -32,7 +33,7 @@ import jax.numpy as jnp
 from repro.ckpt.checkpoint import CheckpointManager
 from repro.configs.base import RunConfig
 from repro.data.synthetic import SyntheticLoader
-from repro.obs import JsonlSink, Registry, StepSeries
+from repro.obs import JsonlSink, Registry, StepSeries, span, step_span
 from repro.train.train_step import (TrainState, init_train_state,
                                     make_train_step)
 
@@ -149,25 +150,30 @@ class Trainer:
         self._install_preemption_handler()
         target = num_steps if num_steps is not None else self.run.train.steps
         it = iter(self.loader)
-        while int(self.state.step) < target and not self._preempted:
-            batch = next(it)
-            batch = {k: jnp.asarray(v) for k, v in batch.items()}
-            t0 = time.perf_counter()
-            self.state, metrics = self.step_fn(self.state, batch)
-            # step_time_s is measured BEFORE the host transfer: it times
-            # dispatch (+ compute, on synchronous backends), not the
-            # blocking device->host copy of the metrics themselves...
-            dt = time.perf_counter() - t0
-            # ...which happens here as ONE batched device_get of the
-            # whole dict instead of a per-leaf float() sync loop
-            metrics = jax.device_get(metrics)
-            step = int(self.state.step)
-            self._watch_stragglers(step, dt)
-            metrics["step_time_s"] = dt
-            self.obs.histogram("train/step_time_s").record(dt)
-            self._series.record(step, metrics)
-            if self.mgr is not None and step % self.ckpt_every == 0:
-                self._checkpoint()
+        step = int(self.state.step)
+        while step < target and not self._preempted:
+            # host spans (repro.obs) on the profiler's timeline: the
+            # device's idle gaps are named by the phase the host is in
+            with step_span(step + 1):
+                t0 = time.perf_counter()
+                with span("train/data"):
+                    batch = next(it)
+                    batch = {k: jnp.asarray(v) for k, v in batch.items()}
+                with span("train/dispatch"):
+                    self.state, metrics = self.step_fn(self.state, batch)
+                with span("train/fetch"):
+                    # ONE batched device_get of the whole dict; it waits
+                    # for the step, so step_time_s times the step
+                    metrics = jax.device_get(metrics)
+                    step = int(self.state.step)
+                dt = time.perf_counter() - t0
+                self._watch_stragglers(step, dt)
+                metrics["step_time_s"] = dt
+                self.obs.histogram("train/step_time_s").record(dt)
+                self._series.record(step, metrics)
+                if self.mgr is not None and step % self.ckpt_every == 0:
+                    with span("train/checkpoint"):
+                        self._checkpoint()
         # final (or preemption) checkpoint: synchronous
         self._checkpoint(blocking=True)
         if self.mgr is not None:

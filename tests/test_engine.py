@@ -645,3 +645,55 @@ def test_engine_hybrid_family(model):
             toks.append(int(jnp.argmax(lg1[0])))
             pos += 1
         assert out[r.uid] == toks, r.uid
+
+
+# ---------------------------------------------------------------------------
+# Request timing: TTFT and queue wait from the arrival, ITL per gap
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def timed_engine(model):
+    import time
+    params, kstate = model
+    eng = InferenceEngine(CFG, params, kstate, max_slots=1, max_len=MAX_LEN)
+    early = time.perf_counter() - 1.0       # due a second before submit
+    eng.run([Request(uid=0, prompt=[1, 2, 3, 4], max_new_tokens=5,
+                     arrival_time=early),
+             Request(uid=1, prompt=[5, 6, 7], max_new_tokens=4)])
+    return eng
+
+
+def test_ttft_runs_from_the_arrival_time(timed_engine):
+    m = timed_engine.metrics
+    late, prompt = m.requests[0], m.requests[1]
+    assert late.ttft_s == late.first_token_time - late.arrival_time
+    assert late.ttft_s - (late.first_token_time - late.submit_time) == \
+        pytest.approx(1.0, abs=0.05)
+    # without an arrival time the clock starts at submit()
+    assert prompt.arrival_time == prompt.submit_time
+    assert prompt.ttft_s == prompt.first_token_time - prompt.submit_time
+    assert m.obs.histogram("engine/ttft_s").count == 2
+
+
+def test_queue_wait_runs_from_arrival_to_admission(timed_engine):
+    m = timed_engine.metrics
+    h = m.obs.histogram("engine/queue_wait_s")
+    assert h.count == 2
+    first, second = m.requests[0], m.requests[1]
+    assert first.admit_time - first.arrival_time >= 1.0
+    # one slot: the second request waits for the first to finish
+    assert second.admit_time >= first.finish_time
+    waits = sorted(r.admit_time - r.arrival_time for r in (first, second))
+    assert [h.percentile(0), h.percentile(100)] == pytest.approx(waits)
+    assert "queue_wait_p50_s" in m.summary()
+
+
+def test_itl_records_every_gap(timed_engine):
+    m = timed_engine.metrics
+    h = m.obs.histogram("engine/itl_s")
+    # 5 and 4 tokens: 4 + 3 gaps, each recorded on its own
+    assert h.count == 7
+    for r in m.requests.values():
+        # the request's mean stays what existing callers read
+        assert r.itl_s == pytest.approx(
+            (r.finish_time - r.first_token_time) / (r.n_generated - 1))
+    assert h.percentile(100) >= max(r.itl_s for r in m.requests.values())

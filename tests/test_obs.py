@@ -25,7 +25,7 @@ from repro.obs import (Counter, Gauge, Histogram, JsonlSink, Registry,
                        SCHEMA_VERSION, StepSeries)
 from repro.obs.routing_stats import RoutingStats, pages_health, summarize
 from repro.obs.schema import SchemaError, validate_jsonl, validate_record
-from repro.obs.trace import profile, span
+from repro.obs.trace import profile, span, step_span
 
 
 # ---------------------------------------------------------------------------
@@ -48,8 +48,6 @@ def test_registry_instruments():
         float(np.percentile([1, 2, 3, 4], 50)))
     assert h.percentile(90) == pytest.approx(
         float(np.percentile([1, 2, 3, 4], 90)))
-    csv = reg.to_csv()
-    assert csv.startswith("name,value\n") and "steps,3.0" in csv
 
 
 def test_registry_type_mismatch_raises():
@@ -377,6 +375,114 @@ def test_span_names_hlo_and_nests():
     hlo = jax.jit(f).lower(jnp.ones((4,))).compile().as_text()
     assert "test/outer" in hlo and "inner" in hlo
     assert float(f(jnp.asarray(2.0))) == 4.0    # eager path works too
+
+
+# modules that open spans inside the compiled train step
+SPAN_MODULES = ("repro.core.routing", "repro.models.transformer",
+                "repro.models.model", "repro.attn.backends",
+                "repro.train.train_step", "repro.kernels.routing_attention",
+                "repro.kernels.ops")
+
+
+def _strip_metadata(hlo: str) -> str:
+    import re
+    body = hlo[hlo.index("ENTRY"):] if "ENTRY" in hlo else hlo
+    return re.sub(r",? metadata=\{[^}]*\}", "", body)
+
+
+def test_spans_change_only_metadata(monkeypatch):
+    """The step's spans are metadata: with every span a no-op the
+    compiled train step holds the same instructions, op_name aside."""
+    import contextlib
+    import importlib
+    from repro.train.train_step import init_train_state, make_train_step
+    run = _tiny_run(False)
+    state = init_train_state(run, jax.random.PRNGKey(0))
+    batch = {"tokens": np.zeros((2, 65), np.int32)}
+
+    def compiled():
+        return jax.jit(make_train_step(run)).lower(state, batch) \
+            .compile().as_text()
+
+    with_spans = compiled()
+    assert "routing/assign" in with_spans and "model/ffn" in with_spans
+    for name in SPAN_MODULES:
+        monkeypatch.setattr(importlib.import_module(name), "span",
+                            lambda _: contextlib.nullcontext())
+    without = compiled()
+    assert "routing/assign" not in without
+    assert _strip_metadata(with_spans) == _strip_metadata(without)
+
+
+class _Loader:
+    def __iter__(self):
+        return self
+
+    def __next__(self):
+        return {"tokens": np.zeros((1, 4), np.int32)}
+
+    def state(self):
+        return {}
+
+
+def _sleep(x):
+    import time
+    time.sleep(0.05)
+    return x
+
+
+class _LateResult:
+    """A result that reaches the host 50 ms after it is asked for, as an
+    asynchronously dispatched step's does."""
+
+    def __init__(self, value):
+        self.value = value
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(_sleep(self.value), dtype=dtype)
+
+
+def _callback_step(ts, batch):
+    s = jax.pure_callback(_sleep, jax.ShapeDtypeStruct((), jnp.int32),
+                          ts.step)
+    return ts._replace(step=s + 1), {"loss": s.astype(jnp.float32)}
+
+
+def _late_step(ts, batch):
+    # returns at once; the metrics arrive when fetched
+    return ts._replace(step=ts.step + 1), {"loss": _LateResult(0.0)}
+
+
+@pytest.mark.parametrize("step_fn", [jax.jit(_callback_step), _late_step],
+                         ids=["pure_callback", "late_result"])
+def test_trainer_step_time_includes_the_device_wait(step_fn):
+    """step_time_s runs to the step's metrics on the host: a step whose
+    result is ready only after a 50 ms wait reads >= 50 ms, also where
+    the dispatch itself returns at once."""
+    from repro.train.train_step import TrainState
+    from repro.train.trainer import Trainer
+    tr = Trainer(_tiny_run(False), _Loader(), step_fn=step_fn)
+    tr.state = TrainState({}, None, None, jnp.zeros((), jnp.int32))
+    assert tr.fit(3)["steps"] == 3
+    times = [h["step_time_s"] for h in tr.metrics_history]
+    assert len(times) == 3 and min(times) >= 0.05
+    assert tr.obs.histogram("train/step_time_s").percentile(50) >= 0.05
+
+
+def test_step_span_nests_under_an_active_profiler(tmp_path):
+    from jax.profiler import ProfileData
+    d = str(tmp_path / "prof")
+    with profile(d):
+        for i in range(2):
+            with step_span(i + 1), span("train/data"):
+                jax.block_until_ready(jnp.ones((4,)) * i)
+    with step_span(3):                  # no profiler: still a no-op
+        pass
+    found = [os.path.join(r, f) for r, _, fs in os.walk(d) for f in fs
+             if f.endswith(".xplane.pb")]
+    names = [e.name for p in ProfileData.from_file(found[0]).planes
+             for ln in p.lines for e in ln.events]
+    assert names.count("train/data") == 2 and "train" in names
 
 
 def test_profile_writes_capture(tmp_path):

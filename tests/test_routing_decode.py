@@ -219,3 +219,36 @@ def test_model_decode_token_and_logit_parity(monkeypatch):
                 if isinstance(l, dict) and name in l]
             assert all(bool((x == y).all()) for x, y in zip(a, b)), \
                 f"page cache {name} diverged at t={t}"
+
+
+def test_jitted_wrapper_names_the_kernel_and_keeps_its_work():
+    """kernels.ops.paged_routing_decode: the same program as the bare
+    kernel (bitwise equal output, the same compiled instructions) under
+    the ``kernels/paged_routing_decode`` span."""
+    import re
+
+    from repro.kernels import ops
+    from repro.kernels.routing_decode import paged_routing_decode
+    B, Hr, kc, cap, dh = 2, 2, 4, 8, 32
+    ks = jax.random.split(KEY, 5)
+    r = jax.random.normal(ks[0], (B, Hr, dh))
+    v = jax.random.normal(ks[1], (B, Hr, dh))
+    rk = jax.random.normal(ks[2], (B, Hr, kc, cap, dh))
+    rv = jax.random.normal(ks[3], (B, Hr, kc, cap, dh))
+    rlen = jax.random.randint(ks[4], (B, Hr, kc), 0, 2 * cap)
+    c = jnp.array([[0, 3], [2, 1]], jnp.int32)
+    args = (r, v, rk, rv, rlen, c)
+    bare = jax.jit(paged_routing_decode)
+    wrapped = jax.jit(lambda *a: ops.paged_routing_decode(*a))
+    assert bool(jnp.array_equal(bare(*args), wrapped(*args)))
+
+    def work(fn):
+        text = fn.lower(*args).compile().as_text()
+        # the entry's instructions (its signature names the arguments)
+        body = text[text.index("ENTRY"):].split("\n", 1)[1]
+        return text, sorted(re.sub(r",? metadata=\{[^}]*\}", "",
+                                   re.sub(r"%[\w.\-]+", "%", body))
+                            .splitlines())
+    text, w = work(wrapped)
+    assert "kernels/paged_routing_decode" in text
+    assert w == work(bare)[1]

@@ -9,7 +9,8 @@ SPAN = "routing/assign"
 
 
 def read(ctx):
-    if spans.attribution(ctx) is None:
+    att = spans.attribution(ctx)
+    if att is None:
         return None
-    t = spans.span_s(ctx["trace"].op_s, ctx["scope_map"], SPAN)
+    t = spans.span_s(ctx["trace"].op_s, att["scopes"], SPAN)
     return 100.0 * t / ctx["trace"].busy_s if t > 0 else None

@@ -30,7 +30,6 @@ import gc
 import importlib
 import statistics
 import time
-from pathlib import Path
 from typing import Callable, Dict, Optional
 
 import jax
@@ -143,12 +142,6 @@ def run(ctx, fault: Optional[Callable] = None) -> dict:
     from repro.train.train_step import TrainState, init_train_state
     from repro.train.trainer import Trainer
 
-    if ctx.trace:
-        # the scope map is read from the compiled step's metadata, and an
-        # executable loaded from the compile cache keeps the metadata of
-        # the program that wrote the entry: key the cache on it too
-        jax.config.update("jax_compilation_cache_include_metadata_in_key",
-                          True)
     c, t, traffic = ctx.config["model"], ctx.config["train"], ctx.traffic
     ref = importlib.import_module(f"bench.refs.{ctx.config['reference']}")
     kind = importlib.import_module(f"bench.traffic.kinds.{traffic['kind']}")
@@ -212,17 +205,6 @@ def run(ctx, fault: Optional[Callable] = None) -> dict:
                                                 p0))
     del p0
 
-    if ctx.trace:
-        # the step's instruction name -> op_name map, from its compiled
-        # text: bench/spans.py joins the trace's device ops to the
-        # program's spans through it
-        from bench import spans
-        t_map = time.perf_counter()
-        with mesh:
-            scopes = spans.scope_map(getattr(step, "jitted", step).lower(
-                tr.state, {"tokens": pool[0]}).compile().as_text())
-        ctx.note(f"[setup] scope map of {len(scopes)} instructions: "
-                 f"{time.perf_counter() - t_map:.2f} s")
     steps = TRACED_STEPS if ctx.trace else max(
         1, round(ctx.seconds / max(t_step, 1e-9)))
     start = int(tr.state.step)
@@ -269,7 +251,7 @@ def run(ctx, fault: Optional[Callable] = None) -> dict:
     ctx.note(f"[train] losses program {losses} reference {ref_losses}")
     ctx.note(f"[train] still leaves left out of update_gap: "
              f"{sorted(set(leaves) - set(moving))}")
-    out = {
+    return {
         "metrics": {"train_tokens_per_s": tokens / window_s,
                     "setup_s": ctx.setup_s(t0)},
         "checks": checks,
@@ -283,7 +265,3 @@ def run(ctx, fault: Optional[Callable] = None) -> dict:
                       "tokens": tokens, "window_s": window_s,
                       "elem_bytes": dtype.itemsize},
     }
-    if ctx.trace:
-        out["layer_ctx"].update(scope_map=scopes, trace_path=str(sorted(
-            Path(ctx.trace_dir).rglob("*.xplane.pb"))[-1]))
-    return out

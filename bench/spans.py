@@ -5,11 +5,13 @@ instruction traced inside it, and keeps it through ``jax.grad`` and
 remat: ``jit(f)/train/grad/transpose(jvp())/.../checkpoint/
 rematted_computation/routing/assign/sort`` is the backward pass's copy of
 an instruction of ``routing/assign``. The trace's ``XLA Ops`` events are
-named by the same instructions, but carry no scope. So a traced run
-compiles its step once more before the window (from the compile cache)
-and keeps the compiled text's instruction name -> ``op_name`` map (the
-*scope map*, ``scope_map``); here each device operation of the window is
-given the spans its instruction's ``op_name`` holds.
+named by the same instructions, but carry no scope. The profile holds
+the scope all the same: its ``/host:metadata`` plane keeps each compiled
+module that ran, as an ``HloProto`` with its metadata (``trace_modules``).
+From the module's text comes the instruction name -> ``op_name`` map
+(the *scope map*, ``scope_map``); here each device operation of the
+window is given the spans its instruction's ``op_name`` holds. Nothing
+is compiled for it and nothing is added to the run's set-up.
 
 A span is a ``<subsystem>/<phase>`` pair of the program's subsystems
 (``SUBSYSTEMS``). The innermost span of an operation is the last one in
@@ -22,9 +24,12 @@ Host spans are the trainer's ``train/*`` annotations on the host
 timeline; device-idle time in the window is split by the one that
 covers it.
 
-``attribution(ctx)`` computes all of it once per traced run from the
-metric readers' context (``layer_ctx`` keys ``scope_map`` and
-``trace_path``, set by the training mode) and adds its notes:
+The readers get the reduction (``ctx["trace"]``) but not the profile's
+path, so ``load`` finds the profile itself: the newest ``.xplane.pb``
+under the temporary directories ``bench/run.py`` traces into
+(``TRACE_DIRS``), kept only if its ``bench/window`` span is the one the
+reduction measured. ``attribution(ctx)`` computes all of it once per
+traced run and adds its notes:
 
 * device time by leaf span, with the unclaimed share and the share of
   operation time whose instruction the scope map holds;
@@ -32,13 +37,17 @@ metric readers' context (``layer_ctx`` keys ``scope_map`` and
   ``Reduced.kernel_s("routed_attention_fused")``;
 * device-idle time per step by trainer span.
 
-With a program that writes no such span (an older checkout) every
-quantity is 0 and the readers return ``None``.
+Without such a profile, or with one that holds no compiled module, it is
+``None``; with a program that writes no span (an older checkout) every
+quantity is 0. Either way the readers return ``None``, never 0.
 """
 from __future__ import annotations
 
 import re
-from typing import Dict, List, Optional, Sequence
+import tempfile
+import time
+from pathlib import Path
+from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 from bench.trace_reduce import WINDOW_SPAN, Op, covered, read_planes, union
 
@@ -56,11 +65,16 @@ OPERANDS = re.compile(r"\s[a-z][\w\-]*\(([^()]*)\)")
 REF = re.compile(r"%([\w.\-]+)")
 CALLED = re.compile(r"\b(calls|body|condition|to_apply)=%?([\w.\-]+)")
 KERNEL = "routed_attention_fused"
+TRACE_DIRS = "bench-trace-*"   # bench/run.py's tempfile.mkdtemp prefix
+METADATA_PLANE = "/host:metadata"
+HLO_STAT = "Hlo Proto"
+CANDIDATES = 3                 # profiles looked at, newest first
 
 
 def scope_map(hlo_text: str) -> Dict[str, str]:
     """Instruction name -> ``op_name`` ("" without one) of every
-    instruction of a compiled module's text (``Compiled.as_text()``).
+    instruction of a compiled module's text (``trace_modules``,
+    ``Compiled.as_text()``).
 
     Instructions that XLA's TPU passes build late (the index-sorted
     rewrite of a scatter, clones sunk into loop bodies, layout copies)
@@ -131,6 +145,125 @@ def scope_map(hlo_text: str) -> Dict[str, str]:
     return {n: resolve(n, frozenset()) for n in own}
 
 
+def _fields(buf: bytes, start: int = 0,
+            end: Optional[int] = None) -> Iterator[Tuple[int, int, object]]:
+    """(field number, wire type, value) of the protobuf message in
+    ``buf[start:end]``; a length-delimited value is its (start, end)."""
+    end = len(buf) if end is None else end
+    i = start
+    while i < end:
+        key, i = _varint(buf, i)
+        num, wire = key >> 3, key & 7
+        if wire == 0:
+            value, i = _varint(buf, i)
+        elif wire == 2:
+            n, i = _varint(buf, i)
+            value, i = (i, i + n), i + n
+        elif wire in (1, 5):
+            n = 8 if wire == 1 else 4
+            value, i = buf[i:i + n], i + n
+        else:
+            raise ValueError(f"protobuf wire type {wire} at byte {i}")
+        yield num, wire, value
+
+
+def _varint(buf: bytes, i: int) -> Tuple[int, int]:
+    out = shift = 0
+    while True:
+        b = buf[i]
+        i += 1
+        out |= (b & 0x7F) << shift
+        shift += 7
+        if b < 0x80:
+            return out, i
+
+
+def _message(buf: bytes, span: Tuple[int, int]) -> Dict[int, list]:
+    """The fields of the message at ``span``: number -> values."""
+    out: Dict[int, list] = {}
+    for num, _, value in _fields(buf, *span):
+        out.setdefault(num, []).append(value)
+    return out
+
+
+def _str(buf: bytes, span: Tuple[int, int]) -> str:
+    return bytes(buf[span[0]:span[1]]).decode(errors="replace")
+
+
+def trace_modules(path) -> List[str]:
+    """The text of every compiled module a profile holds, largest first,
+    with its ``op_name`` metadata. The profile (an ``XSpace``) keeps each
+    module that ran as an ``HloProto``, the bytes of a stat named
+    ``Hlo Proto`` on an event metadata of its ``/host:metadata`` plane."""
+    from jax._src.lib import _jax
+
+    buf = Path(path).read_bytes()
+    out = []
+    for plane in _message(buf, (0, len(buf))).get(1, []):   # planes
+        fields = _message(buf, plane)
+        if [_str(buf, n) for n in fields.get(2, [])] != [METADATA_PLANE]:
+            continue
+        # stat_metadata (5) and event_metadata (4) are maps, whose
+        # entries hold the key in field 1 and the value in field 2
+        hlo_stats = set()
+        for entry in fields.get(5, []):
+            for meta in _message(buf, entry).get(2, []):
+                m = _message(buf, meta)                 # XStatMetadata
+                if [_str(buf, n) for n in m.get(2, [])] == [HLO_STAT]:
+                    hlo_stats.update(m.get(1, []))
+        for entry in fields.get(4, []):
+            for meta in _message(buf, entry).get(2, []):
+                for stat in _message(buf, meta).get(5, []):
+                    st = _message(buf, stat)            # XStat
+                    if not hlo_stats & set(st.get(1, [])):
+                        continue
+                    for proto in st.get(6, []):         # bytes_value
+                        for module in _message(buf, proto).get(1, []):
+                            out.append(_jax.HloModule
+                                       .from_serialized_hlo_module_proto(
+                                           bytes(buf[module[0]:module[1]]))
+                                       .to_string())
+    return sorted(out, key=len, reverse=True)
+
+
+def trace_scopes(path) -> Dict[str, str]:
+    """The scope map of every module a profile holds; on a name that two
+    modules share, the larger module's instruction wins."""
+    out: Dict[str, str] = {}
+    for text in trace_modules(path):
+        for name, op in scope_map(text).items():
+            out.setdefault(name, op)
+    return out
+
+
+def trace_files() -> List[Path]:
+    """The profiles under the directories ``bench/run.py`` traces into,
+    newest first."""
+    return sorted(Path(tempfile.gettempdir()).glob(
+        f"{TRACE_DIRS}/**/*.xplane.pb"),
+        key=lambda p: p.stat().st_mtime, reverse=True)
+
+
+def window_s(host: Sequence[Op]) -> Optional[float]:
+    """The ``bench/window`` span's length, as ``reduce_ops`` takes it."""
+    wins = [h for h in host if h.name == WINDOW_SPAN]
+    return (wins[-1].end - wins[0].start) / 1e9 if wins else None
+
+
+def load(red) -> Optional[tuple]:
+    """(device ops by chip, host spans, scope map) of the profile that
+    the reduction ``red`` was made from; ``None`` if none is found."""
+    for path in trace_files()[:CANDIDATES]:
+        try:
+            devices, host = read_planes(path)
+            if window_s(host) != red.window_s:
+                continue
+            return devices, host, trace_scopes(path)
+        except (OSError, RuntimeError, ValueError, IndexError):
+            continue
+    return None
+
+
 def spans_of(op_name: str) -> List[str]:
     """The program spans an ``op_name`` holds, outermost first."""
     return SPAN.findall(op_name)
@@ -194,19 +327,30 @@ def idle_by_host_span(devices: Dict[int, List[Op]], host: Sequence[Op],
 
 def attribution(ctx: dict) -> Optional[dict]:
     """The run's span attribution, computed once per reader context;
-    ``None`` outside a traced training run that carries a scope map."""
-    if ctx.get("mode") != "train" or not ctx.get("scope_map") \
-            or not ctx.get("trace_path"):
+    ``None`` outside a traced training run whose profile holds the
+    compiled step."""
+    if ctx.get("mode") != "train" or "trace" not in ctx:
         return None
     if "_spans" in ctx:
         return ctx["_spans"]
-    red, scopes, steps = ctx["trace"], ctx["scope_map"], ctx["steps"]
+    ctx["_spans"] = None
+    t0 = time.perf_counter()
+    got = load(ctx["trace"])
+    if got is None or not got[2]:
+        ctx["notes"].append(
+            "[spans] no profile of this window with a compiled module "
+            f"under {tempfile.gettempdir()}/{TRACE_DIRS}: no span metric")
+        return None
+    devices, host, scopes = got
+    ctx["notes"].append(
+        f"[spans] profile and scope map of {len(scopes)} instructions "
+        f"read in {time.perf_counter() - t0:.2f} s, after the window")
+    red, steps = ctx["trace"], ctx["steps"]
     total = sum(red.op_s.values())
     mapped = sum(s for n, s in red.op_s.items() if n in scopes)
     by_leaf = device_by_leaf(red.op_s, scopes)
-    devices, host = read_planes(ctx["trace_path"])
     idle = idle_by_host_span(devices, host)
-    out = {"by_leaf": by_leaf,
+    out = {"scopes": scopes, "by_leaf": by_leaf,
            "mapped_share": mapped / total if total else 0.0,
            "idle": idle}
     ctx["_spans"] = out

@@ -1,7 +1,9 @@
-"""bench/spans.py: the scope map of a small routed train step compiled on
-the CPU puts the forward, remat and backward copies of the routing
-stages under their spans; the span readers on hand-made operations."""
+"""bench/spans.py: the scope map that a CPU profile of a small routed
+train step holds puts the forward, remat and backward copies of the
+routing stages under their spans; the profile of the window is found by
+its window; the span readers on hand-made operations."""
 import importlib.util
+import tempfile
 from pathlib import Path
 
 import jax
@@ -24,8 +26,9 @@ def reader(name):
 
 
 @pytest.fixture(scope="module")
-def step_scopes():
-    """Scope map of a 2-layer local+routing train step, full remat."""
+def step_profile(tmp_path_factory):
+    """(temporary directory, profile) of one 2-layer local+routing train
+    step with full remat, traced as ``bench/run.py`` traces its window."""
     from repro.configs.base import (ModelConfig, RoutingConfig, RunConfig,
                                     TrainConfig)
     from repro.train.train_step import init_train_state, make_train_step
@@ -41,9 +44,20 @@ def step_scopes():
         global_batch=2, seq_len=64, steps=1, remat="full"))
     state = init_train_state(run, jax.random.PRNGKey(0))
     batch = {"tokens": jnp.zeros((2, 65), jnp.int32)}
-    text = jax.jit(make_train_step(run)).lower(state, batch).compile() \
-        .as_text()
-    return spans.scope_map(text)
+    step = jax.jit(make_train_step(run))
+    jax.block_until_ready(step(state, batch))
+    tmp = tmp_path_factory.mktemp("tmp")
+    trace_dir = tempfile.mkdtemp(prefix="bench-trace-", dir=tmp)
+    jax.profiler.start_trace(trace_dir)
+    with jax.profiler.TraceAnnotation(tr.WINDOW_SPAN):
+        jax.block_until_ready(step(state, batch))
+    jax.profiler.stop_trace()
+    return tmp, sorted(Path(trace_dir).rglob("*.xplane.pb"))[-1]
+
+
+@pytest.fixture(scope="module")
+def step_scopes(step_profile):
+    return spans.trace_scopes(step_profile[1])
 
 
 def _copies(scopes, span):
@@ -100,6 +114,33 @@ ENTRY %main.9 (a: f32[4]) -> f32[4] {
 """
 
 
+def test_trace_modules_print_the_metadata(step_profile):
+    modules = spans.trace_modules(step_profile[1])
+    assert modules and "routing/assign" in modules[0]
+    assert "op_name=" in modules[0]
+
+
+@pytest.mark.parametrize("case", ["this_window", "other_window",
+                                  "no_profile"])
+def test_load_takes_the_profile_of_the_window(monkeypatch, step_profile,
+                                              tmp_path, case):
+    tmp, path = step_profile
+    monkeypatch.setattr(tempfile, "tempdir",
+                        str(tmp_path if case == "no_profile" else tmp))
+    devices, host = tr.read_planes(path)
+    win = [h for h in host if h.name == tr.WINDOW_SPAN]
+    if case == "other_window":
+        win = [Op(tr.WINDOW_SPAN, win[0].start, win[0].end + 1)]
+    red = tr.reduce_ops({0: [Op("x", win[0].start, win[0].end)]}, win)
+    got = spans.load(red)
+    if case != "this_window":
+        assert got is None
+        return
+    assert spans.window_s(got[1]) == red.window_s
+    assert any(spans.leaf(op) == "routing/scatter"
+               for op in got[2].values())
+
+
 def test_scope_map_fills_what_xla_left_without_metadata():
     m = spans.scope_map(HLO)
     # a fusion without metadata: its computation's root
@@ -147,11 +188,11 @@ HOST = [Op("bench/window", 0, 1000),
         Op("train", 500, 1000), Op("train/fetch", 900, 960)]
 
 
-def _ctx(monkeypatch, mode="train", scopes=SCOPES, host=HOST):
-    monkeypatch.setattr(spans, "read_planes",
-                        lambda path: ({0: OPS}, host))
+def _ctx(monkeypatch, mode="train", scopes=SCOPES, host=HOST,
+         profile=True):
+    monkeypatch.setattr(spans, "load", lambda red: (
+        ({0: OPS}, host, scopes) if profile else None))
     return {"mode": mode, "trace": tr.reduce_ops({0: OPS}, host),
-            "scope_map": scopes, "trace_path": "unused.xplane.pb",
             "steps": 2, "notes": []}
 
 
@@ -194,13 +235,16 @@ def test_leaf_claims_and_kernel_span(monkeypatch):
 @pytest.mark.parametrize("name", ["kmeans_assign_share.train",
                                   "routing_scatter_share.train",
                                   "trainer_exposed_ms.train"])
-@pytest.mark.parametrize("case", ["serve", "no_scope_map", "no_spans"])
+@pytest.mark.parametrize("case", ["serve", "no_scope_map", "no_spans",
+                                  "no_profile"])
 def test_readers_return_none_without_spans(monkeypatch, name, case):
     if case == "serve":
         ctx = _ctx(monkeypatch, mode="serve")
     elif case == "no_scope_map":
-        ctx = _ctx(monkeypatch)
-        del ctx["scope_map"], ctx["trace_path"]
+        # a profile that holds no compiled module
+        ctx = _ctx(monkeypatch, scopes={})
+    elif case == "no_profile":
+        ctx = _ctx(monkeypatch, profile=False)
     else:
         # a program that writes no span: nothing to read, and not 0
         ctx = _ctx(monkeypatch,

@@ -17,8 +17,8 @@ Registered pairs (variant, impl):
                      q/k/v intermediates in HBM (DESIGN.md §9); preferred
                      over routing/pallas on TPU (priority 20 vs 10). The
                      kernel's memory plan auto-switches past the VMEM
-                     residency budget to double-buffered per-row DMA
-                     paging, so there is no seq-length registration cliff
+                     residency budget to per-row DMA from HBM, so there
+                     is no seq-length registration cliff
   routing/pallas_fused_paged / _unpaged   forced memory plans of the same
                      kernel (priority 0 — explicit ``impl=`` only); the
                      unpaged one keeps the old ``max_seq_elems`` cap
@@ -554,9 +554,9 @@ registry.register(Backend(
 # no mesh at attend) runs the kernel in distributed training (§9).
 # No max_seq_elems cap: the kernel auto-switches its memory plan at the
 # VMEM residency budget (kernels.common.FUSED_RESIDENT_BYTES, bytes of
-# the resident planes) — whole-plane VMEM residency below it,
-# double-buffered per-row DMA paging above (VMEM bounded by the tile
-# sizes, not N), so paper-scale N=8k–32k stays fused forward and
+# the resident planes) — whole-plane VMEM residency below it, member
+# rows DMA'd from HBM above (VMEM bounded by the cluster size w, not N),
+# so paper-scale N=8k–32k in 32 clusters stays fused forward and
 # backward.
 registry.register(Backend(
     variant="routing", impl="pallas_fused",

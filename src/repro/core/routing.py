@@ -186,12 +186,14 @@ def routed_attention(q: jax.Array,
     if impl in _FUSED_IMPLS:
         # gather-free: q/k/v stay in sequence layout; the kernel pulls
         # member rows through per-cluster SMEM index blocks and the mask
-        # compares pre-gathered member positions. The paged
-        # suffix forces the kernel's memory plan; bare "pallas_fused"
-        # auto-switches on the VMEM residency budget.
+        # compares pre-gathered member positions. Shared QK passes the
+        # one index array (keys are the q rows). The paged suffix forces
+        # the kernel's memory plan; bare "pallas_fused" auto-switches on
+        # the VMEM residency budget.
         from repro.kernels import ops as kops
         og = kops.routed_attention_fused(
-            r_q, None if shared else k_attn, v, q_idx, k_idx,
+            r_q, None if shared else k_attn, v, q_idx,
+            None if shared else k_idx,
             positions.astype(jnp.int32), causal=cfg.causal,
             kvalid=pad_mask, interpret=interpret,
             paged=_FUSED_IMPLS[impl])

@@ -11,7 +11,9 @@
 * ``FUSED_RESIDENT_BYTES`` / ``fused_paged_default`` /
   ``fused_vmem_limit``: the shared rule for when the fused routing kernel
   keeps whole (N, dh) sequence planes resident in VMEM vs streams member
-  rows from HBM, and the VMEM limit each plan compiles with. The kernel
+  rows from HBM, and the VMEM limit each plan compiles with;
+  ``fused_cluster_bytes`` / ``FUSED_CLUSTER_BYTES``: what one cluster's
+  rows and blocks take of that limit, and the most they may. The kernel
   layer, the backend registry, and the benches all derive from these so
   the switch point cannot drift between them.
 """
@@ -31,15 +33,29 @@ NEG = -1e9
 # separate keys — both resident; N=16384 pages. v5e has 128 MiB of VMEM;
 # the compile, not this constant, is the final word (tests/test_tpu_compile).
 FUSED_RESIDENT_BYTES = 24 << 20
-# room for the tiles, accumulators and pipelined per-cluster blocks
-# around the planes (well under 1 MiB at bq = bk = 128, dh = 128)
-_TILE_HEADROOM_BYTES = 8 << 20
+# room around the planes for the per-cluster row buffers and pipelined
+# blocks (fused_cluster_bytes: 1.5 MiB at w = 256, dh = 128, shared QK)
+# and the temporaries of the kernel body's unrolled sub-tiles. At w = 1024
+# (N = 32768 in 32 clusters) the body needs more than 8 MiB beside the
+# 6-7 MiB of blocks to compile for v5e (tests/test_tpu_compile.py)
+_TILE_HEADROOM_BYTES = 16 << 20
+# the share of that room one cluster's buffers and blocks may take
+FUSED_CLUSTER_BYTES = 8 << 20
 
 
 def fused_resident_bytes(n: int, dh: int, planes: int) -> int:
     """VMEM bytes the resident plan's ``planes`` (N, dh) float32 planes
     take, double-buffered."""
     return planes * 2 * n * dh * 4
+
+
+def fused_cluster_bytes(w: int, dh: int, planes: int) -> int:
+    """VMEM bytes of the fused routing kernels' per-cluster buffers and
+    blocks, the backward's (the larger): two slots of w member rows a
+    plane, and the pipeline's double-buffered do block, three gradient
+    blocks, row stats and member positions."""
+    rows = w * dh * 4
+    return (planes * 2 + 2 * (1 + 3)) * rows + 2 * 4 * w * 4
 
 
 # seq_len·head_dim cap of the forced resident plan, for the registry:

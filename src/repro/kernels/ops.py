@@ -59,12 +59,14 @@ def routed_attention_fused(q, k, v, q_idx, k_idx, positions, causal=True,
                            kvalid=None, bq=128, bk=128, interpret=None,
                            paged=None):
     """Gather-free fused kernel: sequence-layout q/k/v (k=None reads keys
-    from the q buffer — shared-QK causal mode) + (B,H,k,w) membership as
-    per-cluster SMEM blocks. Returns per-cluster blocks (B,H,k,w,dh).
+    from the q buffer — shared-QK causal mode, where k_idx is not read) +
+    (B,H,k,w) membership as per-cluster SMEM blocks. Returns per-cluster
+    blocks (B,H,k,w,dh).
 
     ``paged=None`` auto-switches the memory plan on the VMEM residency
     budget (``FUSED_RESIDENT_BYTES``): whole-plane resident below it,
-    double-buffered per-row DMA paging above — no sequence-length cliff."""
+    member rows DMA'd from HBM above — no sequence-length cliff; the
+    cluster size w is bounded by ``FUSED_CLUSTER_BYTES``."""
     with span("kernels/routed_attention_fused"):
         return _routing.routed_attention_fused(
             q, k, v, q_idx, k_idx, positions, causal=causal, kvalid=kvalid,

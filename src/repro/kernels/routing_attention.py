@@ -8,15 +8,28 @@ whole sequence, four in shared-QK mode before the dedupe) and the kernel
 streams the cluster blocks with flash-style online softmax.
 
 ``routed_attention_fused`` — the *gather-free* kernel: q/k/v stay in
-sequence layout (B,H,N,dh) and every grid step pulls exactly the bq/bk
-member rows of its cluster tile with per-row ``make_async_copy`` DMAs into
-revolving double-buffered VMEM slots (tile ik+1's DMAs issue before tile
-ik's compute runs) — the page-table trick TPU paged attention uses, at row
-granularity. The cluster's (w,) membership indices arrive per grid step as
-a blocked SMEM input (1 KiB at w=256, whatever B, H or N), and the member
-positions for the causal mask as lane-dense int32 VMEM blocks pre-gathered
-in XLA (4 B/row). No gathered (B,H,k,w,dh) q/k/v tensor ever reaches HBM,
-and shared-QK causal mode reads keys from the q plane.
+sequence layout (B,H,N,dh). The grid is (B·H, k), one step a cluster.
+A step pulls its cluster's w member rows of q and v (and of k when keys
+are not shared) with one-row ``make_async_copy`` DMAs into (w, dh) VMEM
+buffers, once each — the page-table trick TPU paged attention uses, at
+row granularity. All of a buffer's copies signal one DMA semaphore, and
+one wait whose descriptor covers the whole buffer retires them (a DMA
+semaphore counts bytes). Each buffer has two slots: cluster c+1's copies
+are issued before cluster c's are waited on, so they overlap its compute;
+the cluster axis is sequential ("arbitrary") and each batch·head starts
+afresh. The cluster's (w,) membership indices, and the next cluster's,
+arrive as blocked SMEM inputs (1 KiB each at w=256, whatever B, H or N),
+the member positions for the causal mask as lane-dense int32 VMEM blocks
+pre-gathered in XLA (4 B/row). No gathered (B,H,k,w,dh) q/k/v tensor ever
+reaches HBM. In shared-QK causal mode the kernels take the one (q) index
+array and read keys from the q buffer.
+
+The compute loops over the held cluster's (bq, bk) sub-tiles in the
+order the gathered kernel's grid visits them, with the same online
+softmax and the same float32 matmuls, so the two kernels' forward
+outputs agree bit for bit at the same (bq, bk). A cluster's buffers and
+blocks are O(w·dh); a w whose cluster does not fit
+``FUSED_CLUSTER_BYTES`` (kernels/common.py) is refused at trace time.
 
 The fused kernel has two memory plans that differ only in where the row
 DMAs read from (``paged=None`` auto-switches on the byte budget in
@@ -25,33 +38,35 @@ kernels/common.py):
 * *resident* — the (N, dh) sequence plane of the current batch·head is
   the kernel's input block: one bulk DMA per plane, row DMAs VMEM->VMEM.
 * *paged* — q/k/v stay in HBM (``memory_space=ANY``) and the row DMAs
-  read HBM directly, so VMEM live bytes are O(bq·dh + 4·bk·dh) —
-  independent of N.
+  read HBM directly, so VMEM live bytes are O(w·dh) — independent of N.
 
-Same kernels, same tiles, same arithmetic: the two plans' forward outputs
-are bit-identical. Single-row DMAs need 32-bit rows (Mosaic tiles 16-bit
-dtypes two rows per sublane), so 16-bit planes are widened to float32 in
-XLA before the kernel; the kernel computes in float32 either way, its
-matmuls at full float32 precision (``_dot``).
+Same kernels, same sub-tiles, same arithmetic: the two plans' forward
+outputs are bit-identical. Single-row DMAs need 32-bit rows (Mosaic tiles
+16-bit dtypes two rows per sublane), so 16-bit planes are widened to
+float32 in XLA before the kernel; the kernel computes in float32 either
+way, its matmuls at full float32 precision (``_dot``).
 
 Both kernels are differentiable (``jax.custom_vjp``): the forward emits
 per-row lse stats (m + log l); the backward recomputes p = exp(s - lse)
-tile by tile — no (w x w) matrix is ever stored — and runs a dq kernel
-(KV-sequential grid) plus a dk/dv kernel (Q-sequential grid) over the same
-cluster-block structure. The fused backward produces per-cluster gradient
-blocks and scatter-adds them to sequence layout in XLA (duplicate
-memberships accumulate, exactly the transpose of the implicit gather).
+tile by tile — no (w x w) matrix is ever stored. The gathered backward
+runs a dq kernel (KV-sequential grid) plus a dk/dv kernel (Q-sequential
+grid). The fused backward is one kernel on the forward's per-cluster grid
+and fetch plan: each p/ds sub-tile feeds dq, dk and dv, and the kernel
+writes per-cluster gradient blocks that XLA scatter-adds to sequence
+layout (duplicate memberships accumulate, exactly the transpose of the
+implicit gather).
 
 Row stats (lse, dsum) and positions travel as (..., 1, w) arrays so that
-their blocks' last two dims, (1, b), tile on the chip.
+their blocks' last two dims tile on the chip.
 
-Grid: (B·H·k clusters, w/bq, w/bk) gathered; (B·H, k, w/bq, w/bk) fused,
-KV axis sequential; (m, l, acc) scratch in VMEM. MXU-aligned: bq = bk =
-128 default, dh in {64, 128, 256}.
+Grid: (B·H·k clusters, w/bq, w/bk) gathered, KV axis sequential, (m, l,
+acc) scratch in VMEM; (B·H, k) fused, sub-tiles unrolled in the body.
+MXU-aligned: bq = bk = 128 default, dh in {64, 128, 256}.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -59,7 +74,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import NEG as _NEG
-from repro.kernels.common import (default_interpret, float0_like,
+from repro.kernels.common import (FUSED_CLUSTER_BYTES, default_interpret,
+                                  float0_like, fused_cluster_bytes,
                                   fused_paged_default, fused_vmem_limit)
 from repro.obs.trace import span
 
@@ -327,335 +343,215 @@ def routed_attention_blocks(qg, kg, vg, pos_q, pos_k, causal=True,
 
 
 # ---------------------------------------------------------------------------
-# Fused gather-free kernel: sequence-layout q/k/v, member rows streamed by
-# per-row DMA through revolving double-buffered VMEM slots
+# Fused gather-free kernel: sequence-layout q/k/v, one grid step a cluster,
+# its member rows fetched once by one-row DMAs into double-buffered VMEM
 # ---------------------------------------------------------------------------
-def _dma_start_rows(src, plane, idx_ref, base, rows, dst, sem):
-    """Issue one-row async copies ``src[plane, idx[base+j]] -> dst[j]``
-    for j < rows, all signalling the same semaphore. Cluster membership
-    has no sequence locality, so rows — not contiguous chunks — are the
-    DMA unit; the cluster's SMEM index block drives the source addresses
-    (the same trick the paged decode kernel uses)."""
-    def body(j, _):
-        row = idx_ref[0, 0, 0, base + j]
-        pltpu.make_async_copy(src.at[plane, pl.ds(row, 1)],
-                              dst.at[pl.ds(j, 1)], sem).start()
+def _start_rows(src, plane, idx_ref, dst, sem):
+    """Issue one-row async copies ``src[plane, idx[j]] -> dst[j]`` for
+    every row j of ``dst``, all signalling ``sem``. Cluster membership has
+    no sequence locality, so rows — not contiguous chunks — are the DMA
+    unit; the cluster's SMEM index block drives the source addresses (the
+    same trick the paged decode kernel uses). Mosaic lowers a loop only
+    whole or rolled, so the body issues ``unroll`` copies by hand."""
+    w = dst.shape[0]
+    unroll = math.gcd(w, 8)
+
+    def body(i, _):
+        for u in range(unroll):
+            j = i * unroll + u
+            row = idx_ref[0, 0, 0, j]
+            pltpu.make_async_copy(src.at[plane, pl.ds(row, 1)],
+                                  dst.at[pl.ds(j, 1)], sem).start()
         return 0
-    jax.lax.fori_loop(0, rows, body, 0, unroll=False)
+    jax.lax.fori_loop(0, w // unroll, body, 0)
 
 
-def _dma_wait_rows(src, rows, dst, sem):
-    """Wait the ``rows`` one-row copies previously started into ``dst``
-    (the wait descriptor only needs the byte count, so src row 0 serves
-    for every j)."""
-    def body(j, _):
-        pltpu.make_async_copy(src.at[0, pl.ds(0, 1)],
-                              dst.at[pl.ds(j, 1)], sem).wait()
-        return 0
-    jax.lax.fori_loop(0, rows, body, 0, unroll=False)
+def _wait_rows(dst, sem):
+    """Retire every row copy into ``dst`` with one wait: a DMA semaphore
+    counts bytes, and this descriptor covers the whole buffer."""
+    pltpu.make_async_copy(dst, dst, sem).wait()
 
 
-def _unpack(refs, shared, n_tail):
-    """Split a fused kernel's refs into (q, k, v) sources and the rest;
-    shared-QK reads keys from the q plane."""
-    if shared:
-        q_src, v_src, *rest = refs
-        k_src = q_src
-    else:
-        q_src, k_src, v_src, *rest = refs
-    assert len(rest) == n_tail, (len(rest), n_tail)
-    return q_src, k_src, v_src, rest
+def _split_refs(refs, shared, n_in, n_out):
+    """A fused kernel's refs: membership index blocks (current and next
+    cluster, for q and, with separate keys, for k), member positions,
+    the (q, [k,] v) sources, ``n_in`` more inputs, ``n_out`` outputs,
+    then one (2, w, dh) row buffer and one semaphore pair a plane."""
+    planes = 2 if shared else 3
+    sizes = (planes - 1) * 2, 2, planes, n_in, n_out, planes, planes
+    parts, at = [], 0
+    for n in sizes:
+        parts.append(refs[at:at + n])
+        at += n
+    assert at == len(refs), (at, len(refs))
+    return parts
 
 
-def _fwd_kernel(qi_ref, ki_ref, pq_ref, pk_ref, *refs, shared, causal,
-                scale, bq, bk, resident):
-    q_src, k_src, v_src, rest = _unpack(refs, shared, 11)
-    (o_ref, lse_ref, qt_ref, kt_ref, vt_ref, m_ref, l_ref, acc_ref,
-     q_sem, k_sem, v_sem) = rest
+def _cluster_rows(idx, srcs, bufs, sems, shared, resident):
+    """Fetch the member rows of this grid step's cluster, and prefetch the
+    next one's. Slot c % 2 holds cluster c; the next cluster's copies are
+    issued before this one's are waited on, so they overlap its compute.
+    Each batch·head starts afresh at c = 0 (the resident plan's plane
+    block changes there). Returns the (q, k, v) row buffers of the slot;
+    shared-QK keys are the q buffer, and v follows the key index."""
+    qi, qn = idx[:2]
+    ki, kn = (qi, qn) if shared else idx[2:]
+    cur = (qi,) + (() if shared else (ki,)) + (ki,)
+    nxt = (qn,) + (() if shared else (kn,)) + (kn,)
     plane = 0 if resident else pl.program_id(0)
-    iq = pl.program_id(2)
-    ik = pl.program_id(3)
-    nk = pl.num_programs(3)
+    c = pl.program_id(1)
+    slot = c % 2
 
-    def start_kv(t, slot):
-        _dma_start_rows(k_src, plane, ki_ref, t * bk, bk, kt_ref.at[slot],
-                        k_sem.at[slot])
-        _dma_start_rows(v_src, plane, ki_ref, t * bk, bk, vt_ref.at[slot],
-                        v_sem.at[slot])
+    def start(index, s):
+        for i_ref, src, buf, sem in zip(index, srcs, bufs, sems):
+            _start_rows(src, plane, i_ref, buf.at[s], sem.at[s])
 
-    @pl.when(ik == 0)
-    def _prologue():
-        m_ref[...] = jnp.full_like(m_ref, _NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        _dma_start_rows(q_src, plane, qi_ref, iq * bq, bq, qt_ref, q_sem)
-        start_kv(0, 0)
-        _dma_wait_rows(q_src, bq, qt_ref, q_sem)
+    @pl.when(c == 0)
+    def _first():
+        start(cur, 0)
 
-    # double-buffer: tile ik+1's DMAs are in flight while tile ik computes
-    @pl.when(ik + 1 < nk)
+    @pl.when(c + 1 < pl.num_programs(1))
     def _prefetch():
-        start_kv(ik + 1, (ik + 1) % 2)
+        start(nxt, 1 - slot)
 
-    slot = ik % 2
-    _dma_wait_rows(k_src, bk, kt_ref.at[slot], k_sem.at[slot])
-    _dma_wait_rows(v_src, bk, vt_ref.at[slot], v_sem.at[slot])
-
-    q = qt_ref[...]
-    k = kt_ref[slot]
-    v = vt_ref[slot]
-    s = _dot(q, k, 1, 1) * scale
-    keep = _keep_mask(pq_ref[0, 0, 0], pk_ref[0, 0, 0], causal)
-    s = jnp.where(keep, s, _NEG)
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(-1))
-    p = jnp.where(keep, jnp.exp(s - m_new[:, None]), 0.0)
-    corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + p.sum(-1)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + \
-        _dot(p, v, 1, 0)
-    m_ref[...] = m_new
-
-    @pl.when(ik == nk - 1)
-    def _done():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0, 0] = m_ref[...] + jnp.log(l)
+    for buf, sem in zip(bufs, sems):
+        _wait_rows(buf.at[slot], sem.at[slot])
+    qb, vb = bufs[0].at[slot], bufs[-1].at[slot]
+    return qb, (qb if shared else bufs[1].at[slot]), vb
 
 
-def _dq_kernel(qi_ref, ki_ref, pq_ref, pk_ref, *refs, shared, causal,
-               scale, bq, bk, resident):
-    q_src, k_src, v_src, rest = _unpack(refs, shared, 11)
-    (do_ref, lse_ref, dsum_ref, dq_ref, qt_ref, kt_ref, vt_ref, dq_acc,
-     q_sem, k_sem, v_sem) = rest
-    plane = 0 if resident else pl.program_id(0)
-    iq = pl.program_id(2)
-    ik = pl.program_id(3)
-    nk = pl.num_programs(3)
-
-    def start_kv(t, slot):
-        _dma_start_rows(k_src, plane, ki_ref, t * bk, bk, kt_ref.at[slot],
-                        k_sem.at[slot])
-        _dma_start_rows(v_src, plane, ki_ref, t * bk, bk, vt_ref.at[slot],
-                        v_sem.at[slot])
-
-    @pl.when(ik == 0)
-    def _prologue():
-        dq_acc[...] = jnp.zeros_like(dq_acc)
-        _dma_start_rows(q_src, plane, qi_ref, iq * bq, bq, qt_ref, q_sem)
-        start_kv(0, 0)
-        _dma_wait_rows(q_src, bq, qt_ref, q_sem)
-
-    @pl.when(ik + 1 < nk)
-    def _prefetch():
-        start_kv(ik + 1, (ik + 1) % 2)
-
-    slot = ik % 2
-    _dma_wait_rows(k_src, bk, kt_ref.at[slot], k_sem.at[slot])
-    _dma_wait_rows(v_src, bk, vt_ref.at[slot], v_sem.at[slot])
-
-    q = qt_ref[...]
-    k = kt_ref[slot]
-    v = vt_ref[slot]
-    do = do_ref[0, 0].astype(jnp.float32)
-    keep = _keep_mask(pq_ref[0, 0, 0], pk_ref[0, 0, 0], causal)
-    s = _dot(q, k, 1, 1) * scale
-    p = jnp.where(keep, jnp.exp(s - lse_ref[0, 0, 0][:, None]), 0.0)
-    dp = _dot(do, v, 1, 1)
-    ds = p * (dp - dsum_ref[0, 0, 0][:, None]) * scale
-    dq_acc[...] += _dot(ds, k, 1, 0)
-
-    @pl.when(ik == nk - 1)
-    def _done():
-        dq_ref[0, 0] = dq_acc[...]
+def _fwd_kernel(*refs, shared, causal, scale, bq, bk, resident):
+    idx, (pq_ref, pk_ref), srcs, _, (o_ref, lse_ref), bufs, sems = \
+        _split_refs(refs, shared, 0, 2)
+    qb, kb, vb = _cluster_rows(idx, srcs, bufs, sems, shared, resident)
+    w, dh = qb.shape
+    # the gathered kernel's (iq, ik) grid as loops over the held cluster:
+    # same sub-tiles, same online-softmax order, same arithmetic
+    for iq in range(w // bq):
+        rq = pl.ds(iq * bq, bq)
+        q = qb[rq, :]
+        pq = pq_ref[0, 0, 0, rq]
+        m = jnp.full((bq,), _NEG, jnp.float32)
+        l = jnp.zeros((bq,), jnp.float32)
+        acc = jnp.zeros((bq, dh), jnp.float32)
+        for ik in range(w // bk):
+            rk = pl.ds(ik * bk, bk)
+            s = _dot(q, kb[rk, :], 1, 1) * scale
+            keep = _keep_mask(pq, pk_ref[0, 0, 0, rk], causal)
+            s = jnp.where(keep, s, _NEG)
+            m_new = jnp.maximum(m, s.max(-1))
+            p = jnp.where(keep, jnp.exp(s - m_new[:, None]), 0.0)
+            corr = jnp.exp(m - m_new)
+            l = l * corr + p.sum(-1)
+            acc = acc * corr[:, None] + _dot(p, vb[rk, :], 1, 0)
+            m = m_new
+        l = jnp.maximum(l, 1e-30)
+        o_ref[0, 0, rq, :] = (acc / l[:, None]).astype(o_ref.dtype)
+        lse_ref[0, 0, 0, rq] = m + jnp.log(l)
 
 
-def _dkv_kernel(qi_ref, ki_ref, pq_ref, pk_ref, *refs, shared, causal,
-                scale, bq, bk, resident):
-    q_src, k_src, v_src, rest = _unpack(refs, shared, 13)
-    (do_ref, lse_ref, dsum_ref, dk_ref, dv_ref, qt_ref, kt_ref, vt_ref,
-     dk_acc, dv_acc, q_sem, k_sem, v_sem) = rest
-    plane = 0 if resident else pl.program_id(0)
-    ik = pl.program_id(2)
-    iq = pl.program_id(3)
-    nq = pl.num_programs(3)
-
-    # swapped roles: the k/v tile is the single resident (it is revisited
-    # by every q sweep step), the q tiles revolve through double buffers
-    @pl.when(iq == 0)
-    def _prologue():
-        dk_acc[...] = jnp.zeros_like(dk_acc)
-        dv_acc[...] = jnp.zeros_like(dv_acc)
-        _dma_start_rows(k_src, plane, ki_ref, ik * bk, bk, kt_ref, k_sem)
-        _dma_start_rows(v_src, plane, ki_ref, ik * bk, bk, vt_ref, v_sem)
-        _dma_start_rows(q_src, plane, qi_ref, 0, bq, qt_ref.at[0],
-                        q_sem.at[0])
-        _dma_wait_rows(k_src, bk, kt_ref, k_sem)
-        _dma_wait_rows(v_src, bk, vt_ref, v_sem)
-
-    @pl.when(iq + 1 < nq)
-    def _prefetch():
-        _dma_start_rows(q_src, plane, qi_ref, (iq + 1) * bq, bq,
-                        qt_ref.at[(iq + 1) % 2], q_sem.at[(iq + 1) % 2])
-
-    slot = iq % 2
-    _dma_wait_rows(q_src, bq, qt_ref.at[slot], q_sem.at[slot])
-
-    q = qt_ref[slot]
-    k = kt_ref[...]
-    v = vt_ref[...]
-    do = do_ref[0, 0].astype(jnp.float32)
-    keep = _keep_mask(pq_ref[0, 0, 0], pk_ref[0, 0, 0], causal)
-    s = _dot(q, k, 1, 1) * scale
-    p = jnp.where(keep, jnp.exp(s - lse_ref[0, 0, 0][:, None]), 0.0)
-    dv_acc[...] += _dot(p, do, 0, 0)
-    dp = _dot(do, v, 1, 1)
-    ds = p * (dp - dsum_ref[0, 0, 0][:, None]) * scale
-    dk_acc[...] += _dot(ds, q, 0, 0)
-
-    @pl.when(iq == nq - 1)
-    def _done():
-        dk_ref[0, 0] = dk_acc[...]
-        dv_ref[0, 0] = dv_acc[...]
+def _bwd_kernel(*refs, shared, causal, scale, bq, bk, resident):
+    (idx, (pq_ref, pk_ref), srcs, (do_ref, lse_ref, dsum_ref),
+     (dq_ref, dk_ref, dv_ref), bufs, sems) = _split_refs(refs, shared, 3, 3)
+    qb, kb, vb = _cluster_rows(idx, srcs, bufs, sems, shared, resident)
+    w = qb.shape[0]
+    # one p/ds sub-tile feeds dq, dk and dv; dq sums over key sub-tiles
+    # and dk/dv over query sub-tiles, each in ascending order
+    for iq in range(w // bq):
+        rq = pl.ds(iq * bq, bq)
+        q = qb[rq, :]
+        do = do_ref[0, 0, rq, :].astype(jnp.float32)
+        pq = pq_ref[0, 0, 0, rq]
+        lse = lse_ref[0, 0, 0, rq]
+        dsum = dsum_ref[0, 0, 0, rq]
+        dq = None
+        for ik in range(w // bk):
+            rk = pl.ds(ik * bk, bk)
+            k = kb[rk, :]
+            keep = _keep_mask(pq, pk_ref[0, 0, 0, rk], causal)
+            s = _dot(q, k, 1, 1) * scale
+            p = jnp.where(keep, jnp.exp(s - lse[:, None]), 0.0)
+            dp = _dot(do, vb[rk, :], 1, 1)
+            ds = p * (dp - dsum[:, None]) * scale
+            dq_t = _dot(ds, k, 1, 0)
+            dq = dq_t if dq is None else dq + dq_t
+            dv_t = _dot(p, do, 0, 0)
+            dk_t = _dot(ds, q, 0, 0)
+            if iq == 0:
+                dv_ref[0, 0, rk, :] = dv_t
+                dk_ref[0, 0, rk, :] = dk_t
+            else:
+                dv_ref[0, 0, rk, :] += dv_t
+                dk_ref[0, 0, rk, :] += dk_t
+        dq_ref[0, 0, rq, :] = dq
 
 
-def _fused_in_specs(N, dh, w, bq, bk, shared, resident, swapped):
-    """in_specs shared by the three fused kernels: the cluster's index
-    blocks (SMEM), its member positions (VMEM rows), then the q [k] v
-    sources — the whole (N, dh) plane of the batch·head when resident,
-    untouched HBM (ANY) when paged."""
-    if swapped:                                   # grid (b, c, ik, iq)
-        tq = lambda b, c, ik, iq: (b, c, 0, iq)
-        tk = lambda b, c, ik, iq: (b, c, 0, ik)
-    else:                                         # grid (b, c, iq, ik)
-        tq = lambda b, c, iq, ik: (b, c, 0, iq)
-        tk = lambda b, c, iq, ik: (b, c, 0, ik)
-    idx = pl.BlockSpec((1, 1, 1, w), lambda b, c, i2, i3: (b, c, 0, 0),
-                       memory_space=pltpu.SMEM)
+def _fused_call(kernel, qf, kf, vf, qi, ki, pqg, pkg, extra, n_out, shared,
+                causal, bq, bk, resident, interpret, lse_out=False):
+    """One fused kernel over the (B·H, k) grid, a cluster a step. Inputs:
+    the membership index blocks of this cluster and the next (SMEM), the
+    member positions, the q [k] v sources — the (N, dh) plane of the
+    batch·head when resident, untouched HBM (ANY) when paged — then
+    ``extra`` per-cluster (w, dh) and (1, w) blocks. Outputs: ``n_out``
+    float32 (w, dh) blocks, then the (1, w) row stats if ``lse_out``."""
+    BH, N, dh = qf.shape
+    _, kc, _, w = qi.shape
+    planes = 2 if shared else 3
+    f32 = jnp.float32
+    cur = lambda b, c: (b, c, 0, 0)
+    nxt = lambda b, c: (b, jnp.minimum(c + 1, kc - 1), 0, 0)
+    idx = [pl.BlockSpec((1, 1, 1, w), at, memory_space=pltpu.SMEM)
+           for at in (cur, nxt)]
     if resident:
-        src = pl.BlockSpec((1, N, dh), lambda b, c, i2, i3: (b, 0, 0))
+        src = pl.BlockSpec((1, N, dh), lambda b, c: (b, 0, 0))
     else:
         src = pl.BlockSpec(memory_space=pl.ANY)
-    return ([idx, idx, pl.BlockSpec((1, 1, 1, bq), tq),
-             pl.BlockSpec((1, 1, 1, bk), tk)]
-            + [src] * (2 if shared else 3))
-
-
-def _row_blocks(bq, dh, swapped):
-    """(tile, row-stat) BlockSpecs of the per-cluster q-side arrays."""
-    if swapped:
-        at = lambda b, c, ik, iq: (b, c, iq, 0)
-        rat = lambda b, c, ik, iq: (b, c, 0, iq)
-    else:
-        at = lambda b, c, iq, ik: (b, c, iq, 0)
-        rat = lambda b, c, iq, ik: (b, c, 0, iq)
-    return (pl.BlockSpec((1, 1, bq, dh), at),
-            pl.BlockSpec((1, 1, 1, bq), rat))
-
-
-def _sems(q2, kv2):
-    dma = pltpu.SemaphoreType.DMA
-    return [dma((2,)) if q2 else dma] + [dma((2,)) if kv2 else dma] * 2
-
-
-def _fused_params(N, dh, shared, resident):
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "parallel",
-                             "arbitrary"),
-        vmem_limit_bytes=fused_vmem_limit(N, dh, 2 if shared else 3,
-                                          resident))
+    tile = pl.BlockSpec((1, 1, w, dh), cur)
+    row = pl.BlockSpec((1, 1, 1, w), cur)
+    indices = (qi, qi) + (() if shared else (ki, ki))
+    srcs = (qf,) + (() if shared else (kf,)) + (vf,)
+    out_shape = [jax.ShapeDtypeStruct((BH, kc, w, dh), f32)] * n_out
+    out_specs = [tile] * n_out
+    if lse_out:
+        out_shape.append(jax.ShapeDtypeStruct((BH, kc, 1, w), f32))
+        out_specs.append(row)
+    return pl.pallas_call(
+        functools.partial(kernel, shared=shared, causal=causal,
+                          scale=1.0 / (dh ** 0.5), bq=bq, bk=bk,
+                          resident=resident),
+        grid=(BH, kc),
+        in_specs=(idx * (len(indices) // 2) + [row, row]
+                  + [src] * planes
+                  + [row if x.shape[-2] == 1 else tile for x in extra]),
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=([pltpu.VMEM((2, w, dh), f32)] * planes
+                        + [pltpu.SemaphoreType.DMA((2,))] * planes),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=fused_vmem_limit(N, dh, planes, resident)),
+        interpret=interpret,
+    )(*indices, pqg, pkg, *srcs, *extra)
 
 
 def _fused_fwd_call(qf, kf, vf, qi, ki, pqg, pkg, shared, causal, bq, bk,
                     resident, interpret):
-    BH, N, dh = qf.shape
-    _, kc, _, w = qi.shape
-    f32 = jnp.float32
-    o_at, lse_at = _row_blocks(bq, dh, swapped=False)
-    srcs = (qf,) + (() if shared else (kf,)) + (vf,)
-    out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, shared=shared, causal=causal,
-                          scale=1.0 / (dh ** 0.5), bq=bq, bk=bk,
-                          resident=resident),
-        grid=(BH, kc, w // bq, w // bk),
-        in_specs=_fused_in_specs(N, dh, w, bq, bk, shared, resident,
-                                 swapped=False),
-        out_specs=[o_at, lse_at],
-        out_shape=[
-            jax.ShapeDtypeStruct((BH, kc, w, dh), f32),
-            jax.ShapeDtypeStruct((BH, kc, 1, w), f32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, dh), f32),
-            pltpu.VMEM((2, bk, dh), f32),
-            pltpu.VMEM((2, bk, dh), f32),
-            pltpu.VMEM((bq,), f32),
-            pltpu.VMEM((bq,), f32),
-            pltpu.VMEM((bq, dh), f32),
-        ] + _sems(q2=False, kv2=True),
-        compiler_params=_fused_params(N, dh, shared, resident),
-        interpret=interpret,
-    )(qi, ki, pqg, pkg, *srcs)
-    return out, lse
+    return _fused_call(_fwd_kernel, qf, kf, vf, qi, ki, pqg, pkg, (), 1,
+                       shared, causal, bq, bk, resident, interpret,
+                       lse_out=True)
 
 
 def _fused_bwd_call(qf, kf, vf, qi, ki, pqg, pkg, out, lse, do, shared,
                     causal, bq, bk, resident, interpret):
     BH, N, dh = qf.shape
-    _, kc, _, w = qi.shape
     f32 = jnp.float32
-    nq, nk = w // bq, w // bk
     # the softmax backward's row sums, in XLA: the routing stage's
     # attention work, outside the kernel's span
     with span("routing/attend"):
         dsum = (do * out).sum(-1)[:, :, None, :]
-    kern_kw = dict(shared=shared, causal=causal, scale=1.0 / (dh ** 0.5),
-                   bq=bq, bk=bk, resident=resident)
-    params = _fused_params(N, dh, shared, resident)
-    srcs = (qf,) + (() if shared else (kf,)) + (vf,)
-    operands = (qi, ki, pqg, pkg) + srcs + (do, lse, dsum)
-
-    q_at, r_at = _row_blocks(bq, dh, swapped=False)
-    dqg = pl.pallas_call(
-        functools.partial(_dq_kernel, **kern_kw),
-        grid=(BH, kc, nq, nk),
-        in_specs=_fused_in_specs(N, dh, w, bq, bk, shared, resident,
-                                 swapped=False) + [q_at, r_at, r_at],
-        out_specs=q_at,                                   # dqg blocks
-        out_shape=jax.ShapeDtypeStruct((BH, kc, w, dh), f32),
-        scratch_shapes=[
-            pltpu.VMEM((bq, dh), f32),
-            pltpu.VMEM((2, bk, dh), f32),
-            pltpu.VMEM((2, bk, dh), f32),
-            pltpu.VMEM((bq, dh), f32),
-        ] + _sems(q2=False, kv2=True),
-        compiler_params=params,
-        interpret=interpret,
-    )(*operands)
-
-    # swapped grid: key tile parallel over (b, c, ik), query sweep inner
-    q_at2, r_at2 = _row_blocks(bq, dh, swapped=True)
-    k_out = pl.BlockSpec((1, 1, bk, dh), lambda b, c, ik, iq: (b, c, ik, 0))
-    dkg, dvg = pl.pallas_call(
-        functools.partial(_dkv_kernel, **kern_kw),
-        grid=(BH, kc, nk, nq),
-        in_specs=_fused_in_specs(N, dh, w, bq, bk, shared, resident,
-                                 swapped=True) + [q_at2, r_at2, r_at2],
-        out_specs=[k_out, k_out],
-        out_shape=[
-            jax.ShapeDtypeStruct((BH, kc, w, dh), f32),
-            jax.ShapeDtypeStruct((BH, kc, w, dh), f32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((2, bq, dh), f32),
-            pltpu.VMEM((bk, dh), f32),
-            pltpu.VMEM((bk, dh), f32),
-            pltpu.VMEM((bk, dh), f32),
-            pltpu.VMEM((bk, dh), f32),
-        ] + _sems(q2=True, kv2=False),
-        compiler_params=params,
-        interpret=interpret,
-    )(*operands)
+    dqg, dkg, dvg = _fused_call(_bwd_kernel, qf, kf, vf, qi, ki, pqg, pkg,
+                                (do, lse, dsum), 3, shared, causal, bq, bk,
+                                resident, interpret)
 
     # scatter-add per-cluster gradient blocks back to sequence layout —
     # the exact transpose of the kernel's implicit gather; duplicate
@@ -705,19 +601,22 @@ def routed_attention_fused(q, k, v, q_idx, k_idx, positions, causal=True,
                            paged=None):
     """Gather-free routed attention on sequence-layout tensors.
 
-    q/v: (B,H,N,dh); k: like q, or None for shared-QK causal mode (keys
-    are read from the q plane).
+    q/v: (B,H,N,dh); k: like q, or None for shared-QK causal mode.
     q_idx/k_idx: (B,H,k,w) sorted membership indices into the sequence.
+    In shared mode the keys are the queries: the kernels take q_idx
+    alone and read keys from the q rows they fetched, so ``k_idx`` is
+    not read (pass None, or q_idx).
     positions: (B,N) int32 original positions (the causal mask compares
     these). kvalid: (B,N) bool, True = attendable key (padding False).
     Returns per-cluster outputs (B,H,k,w,dh); callers scatter them back.
 
     ``paged=None`` auto-selects the memory plan: whole-plane VMEM
     residency while the planes fit the byte budget of
-    ``fused_paged_default``, per-row DMA straight from HBM beyond it (VMEM
-    bounded by the tile sizes, not N). Pass True/False to force a plan.
-    Member positions are pre-gathered in XLA (int32, 4 B/row) — still no
-    gathered q/k/v tensor in HBM.
+    ``fused_paged_default``, row DMAs straight from HBM beyond it (VMEM
+    bounded by the cluster size w, not N). Pass True/False to force a
+    plan. A cluster whose rows do not fit ``FUSED_CLUSTER_BYTES`` of VMEM
+    is refused here (ValueError). Member positions are pre-gathered in
+    XLA (int32, 4 B/row) — still no gathered q/k/v tensor in HBM.
 
     Differentiable: flash-style custom VJP that recomputes p from saved
     lse stats and scatter-adds per-cluster dq/dk/dv to sequence layout.
@@ -728,12 +627,19 @@ def routed_attention_fused(q, k, v, q_idx, k_idx, positions, causal=True,
     bk = min(bk, w)
     assert w % bq == 0 and w % bk == 0, (w, bq, bk)
     shared = k is None
+    planes = 2 if shared else 3
+    need = fused_cluster_bytes(w, dh, planes)
+    if need > FUSED_CLUSTER_BYTES:
+        raise ValueError(
+            f"fused routing kernel: a cluster of w={w} rows (dh={dh}, "
+            f"{planes} planes) needs {need} B of VMEM, over "
+            f"FUSED_CLUSTER_BYTES={FUSED_CLUSTER_BYTES}; use more clusters")
     f32 = jnp.float32
     qf = q.reshape(B * H, N, dh).astype(f32)
     kf = qf if shared else k.reshape(B * H, N, dh).astype(f32)
     vf = v.reshape(B * H, N, dh).astype(f32)
     qi = q_idx.reshape(B * H, kc, 1, w).astype(jnp.int32)
-    ki = k_idx.reshape(B * H, kc, 1, w).astype(jnp.int32)
+    ki = qi if shared else k_idx.reshape(B * H, kc, 1, w).astype(jnp.int32)
     posq = positions.astype(jnp.int32)
     posk = (jnp.where(kvalid, posq, SENTINEL) if kvalid is not None
             else posq)
@@ -743,7 +649,7 @@ def routed_attention_fused(q, k, v, q_idx, k_idx, positions, causal=True,
         return jnp.take_along_axis(src, idx.reshape(B * H, kc * w),
                                    axis=1).reshape(B * H, kc, 1, w)
 
-    resident = not fused_paged_default(N, dh, 2 if shared else 3, paged)
+    resident = not fused_paged_default(N, dh, planes, paged)
     # XLA's gathers of the members' positions take the routing stage's
     # span, so the kernel's span holds the kernel's own time
     with span("routing/gather"):

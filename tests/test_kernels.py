@@ -226,13 +226,17 @@ def test_routed_blocks_kernel_grad_parity():
 # ---------------------------------------------------------------------------
 # Fused kernel: forward parity with the gathered kernel
 # ---------------------------------------------------------------------------
+@pytest.mark.parametrize("bq,bk", [(32, 32), (64, 32)])
 @pytest.mark.parametrize("shared,causal,valid", [
     (False, True, False), (False, False, True),
     (True, True, False), (True, True, True),
 ])
-def test_fused_forward_matches_gathered_kernel(shared, causal, valid):
-    """Bit-level forward parity: the fused kernel's in-VMEM row pulls see
-    exactly the tiles XLA would have gathered."""
+def test_fused_forward_matches_gathered_kernel(shared, causal, valid, bq,
+                                               bk):
+    """Bit-level forward parity at the same (bq, bk): the fused kernel
+    fetches each cluster's rows once and loops over the sub-tiles the
+    gathered kernel's grid visits, in the same order. Shared-QK mode
+    takes the one index array (k_idx None)."""
     B, H, N, dh, kc, w = 2, 2, 256, 64, 4, 64
     ks = jax.random.split(KEY, 6)
     q = jax.random.normal(ks[0], (B, H, N, dh))
@@ -258,10 +262,11 @@ def test_fused_forward_matches_gathered_kernel(shared, causal, valid):
     og = ops.routed_attention_blocks(gath(q, qi), gath(kk, ki),
                                      gath(v, ki), seqg(pos, qi),
                                      seqg(pos, ki), causal=causal,
-                                     valid_k=vk, bq=32, bk=32)
-    of = ops.routed_attention_fused(q, None if shared else k, v, qi, ki,
-                                    pos, causal=causal, kvalid=kvalid,
-                                    bq=32, bk=32)
+                                     valid_k=vk, bq=bq, bk=bk)
+    of = ops.routed_attention_fused(q, None if shared else k, v, qi,
+                                    None if shared else ki, pos,
+                                    causal=causal, kvalid=kvalid, bq=bq,
+                                    bk=bk)
     assert float(jnp.abs(og - of).max()) < 1e-6
 
 
@@ -299,30 +304,43 @@ def test_paged_fused_matches_unpaged_bitwise(shared, causal, valid):
     assert bool(jnp.array_equal(up, pg)), float(jnp.abs(up - pg).max())
 
 
-@pytest.mark.parametrize("w", [128, 256, 384])
+@pytest.mark.parametrize("w", [64, 256, 384])
 def test_paged_double_buffer_chunk_counts(w):
-    """Double-buffer epilogue/prologue correctness at 1, 2 and an odd
-    number of tiles per cluster window (nq = nk = w/128 in {1, 2, 3}) —
-    the degenerate single-tile case never issues a prefetch, the odd
-    case ends on the opposite buffer slot it started on. Forward must
-    stay bitwise; the three-kernel backward must match the unpaged VJP."""
-    B, H, N, dh, kc = 1, 2, 768, 32, 2
-    q, _, v, qi, ki, pos, _ = _fused_inputs(B, H, N, dh, kc, w,
-                                            shared=True, valid=False)
+    """One, two and an odd number of sub-tiles per cluster (bq = bk =
+    min(128, w): 1, 2, 3 for w = 64, 256, 384), over three clusters a
+    batch·head and two batch·heads: cluster c+1 is prefetched into the
+    slot c did not use, the third cluster lands back in slot 0, and each
+    batch·head starts afresh. Forward bitwise between the memory plans;
+    both plans' VJPs within GRAD_TOL of the XLA reference."""
+    from repro.core.routing import _block_attention
+    B, H, N, dh, kc = 1, 2, 768, 32, 3
+    q, _, v, qi, _, pos, _ = _fused_inputs(B, H, N, dh, kc, w,
+                                           shared=True, valid=False)
     wt = jax.random.normal(jax.random.PRNGKey(7), (B, H, kc, w, dh))
 
-    def loss(paged):
-        return lambda q, v: (ops.routed_attention_fused(
-            q, None, v, qi, ki, pos, causal=True, paged=paged) * wt).sum()
+    def gath(x):
+        return jnp.take_along_axis(x, qi.reshape(B, H, -1, 1),
+                                   axis=2).reshape(B, H, kc, w, -1)
 
-    up = ops.routed_attention_fused(q, None, v, qi, ki, pos, causal=True,
+    pq = gath(jnp.broadcast_to(pos[:, None, :, None], (B, H, N, 1)))[..., 0]
+
+    def loss(paged):
+        if paged is None:
+            return lambda q, v: (_block_attention(
+                gath(q), gath(q), gath(v), pq, pq, True, None, False)[0]
+                * wt).sum()
+        return lambda q, v: (ops.routed_attention_fused(
+            q, None, v, qi, None, pos, causal=True, paged=paged) * wt).sum()
+
+    up = ops.routed_attention_fused(q, None, v, qi, None, pos, causal=True,
                                     paged=False)
-    pg = ops.routed_attention_fused(q, None, v, qi, ki, pos, causal=True,
+    pg = ops.routed_attention_fused(q, None, v, qi, None, pos, causal=True,
                                     paged=True)
     assert bool(jnp.array_equal(up, pg))
-    g = jax.grad(loss(True), argnums=(0, 1))(q, v)
-    gr = jax.grad(loss(False), argnums=(0, 1))(q, v)
-    assert _grad_maxdiff(g, gr) < GRAD_TOL
+    gr = jax.grad(loss(None), argnums=(0, 1))(q, v)
+    for paged in (False, True):
+        g = jax.grad(loss(paged), argnums=(0, 1))(q, v)
+        assert _grad_maxdiff(g, gr) < GRAD_TOL, paged
 
 
 @pytest.mark.parametrize("case", ["causal_shared", "padded",
@@ -402,7 +420,7 @@ def _scratch_shapes(grid_spec):
 
 def test_paged_vmem_scratch_independent_of_seq_len(monkeypatch):
     """Structural VMEM bound: the paged kernels' scratch allocations
-    (tiles + accumulators + DMA semaphores) are functions of (bq, bk,
+    (the cluster's row buffers + DMA semaphores) are functions of (w,
     dh) only — identical between N and 4N — and the q/k/v operands stay
     in ANY memory space (no N-sized VMEM window in any BlockSpec)."""
     calls = []
@@ -423,7 +441,8 @@ def test_paged_vmem_scratch_independent_of_seq_len(monkeypatch):
         return got
 
     small, big = build(256), build(1024)
-    # forward (x2: once for the value path, once inside the VJP), dq, dkv
+    # forward (x2: once for the value path, once inside the VJP), and the
+    # one backward kernel
     assert len(small) == len(big) and len(big) >= 3
     for gs_s, gs_b in zip(small, big):
         assert _scratch_shapes(gs_s) == _scratch_shapes(gs_b)
@@ -458,6 +477,21 @@ def test_fused_auto_pages_past_residency_budget(monkeypatch):
     ra.routed_attention_fused(q, None, v, qi, ki, pos, causal=True,
                               interpret=True)
     assert calls, "paged=None did not route past the shrunk budget"
+
+
+def test_fused_refuses_cluster_past_vmem_budget():
+    """A cluster is held whole in VMEM, so its size w = N/k is bounded:
+    rt-enwik8's w = 256 and N = 32768 in 32 clusters (w = 1024, separate
+    keys) fit FUSED_CLUSTER_BYTES, w = 2048 is refused before tracing."""
+    import repro.kernels.routing_attention as ra
+    from repro.kernels import common
+    assert common.fused_cluster_bytes(256, 128, 2) == 1581056
+    assert common.fused_cluster_bytes(1024, 128, 3) <= \
+        common.FUSED_CLUSTER_BYTES
+    q, _, v, qi, _, pos, _ = _fused_inputs(1, 1, 2048, 128, 1, 2048,
+                                           shared=True, valid=False)
+    with pytest.raises(ValueError, match="FUSED_CLUSTER_BYTES"):
+        ra.routed_attention_fused(q, None, v, qi, None, pos, interpret=True)
 
 
 def test_interpret_default_derived_from_platform(monkeypatch):

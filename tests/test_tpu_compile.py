@@ -77,9 +77,11 @@ def test_local_kernel_compiles(one_chip, dtype, with_grad):
     (8192, "float32", True, False),      # train: resident planes
     (16384, "float32", True, True),      # past the byte budget: paged
     (8192, "bfloat16", False, False),    # serve prefill
+    (32768, "float32", True, True),      # w = 1024, the largest admitted
 ])
 def test_fused_routing_kernel_compiles(one_chip, n, dtype, with_grad,
                                        paged):
+    """One kernel a cluster forward, one for the whole backward."""
     from repro.kernels.routing_attention import routed_attention_fused
     # the auto plan (paged=None) is the one expected at this size
     assert common.fused_paged_default(n, DH, 2) is paged
@@ -92,10 +94,12 @@ def test_fused_routing_kernel_compiles(one_chip, n, dtype, with_grad,
                                       interpret=False)
 
     if with_grad:
-        _compile(lambda q, v, i, p: _grad(
+        text = _compile(lambda q, v, i, p: _grad(
             lambda q, v: fn(q, v, i, p), (0, 1))(q, v), x, x, idx, pos)
     else:
-        _compile(fn, x, x, idx, pos)
+        text = _compile(fn, x, x, idx, pos)
+    calls = text.count('custom_call_target="tpu_custom_call"')
+    assert calls == (2 if with_grad else 1), calls
 
 
 def test_paged_decode_kernel_compiles(one_chip):

@@ -27,40 +27,50 @@ import numpy as np
 NEG = -1e9
 
 # VMEM budget for the fused routing kernel's resident planes. The plan
-# holds each (N, dh) float32 plane of the current batch·head as a
-# pipelined input block, which the pipeline double-buffers. At rt-enwik8
-# widths (N=8192, dh=128) that is 16 MiB shared-QK (q, v) and 24 MiB with
-# separate keys — both resident; N=16384 pages. v5e has 128 MiB of VMEM;
-# the compile, not this constant, is the final word (tests/test_tpu_compile).
+# holds each (N, dh) float32 plane of the current batch·head, at
+# row_lanes(dh), as a pipelined input block, which the pipeline
+# double-buffers. At rt-enwik8 widths (N=8192, dh=128) that is 16 MiB
+# shared-QK (q, v) and 24 MiB with separate keys — both resident; so is
+# rt-imagenet64's N=12288, dh=64 (24 MiB at 128 lanes); N=16384 pages.
+# v5e has 128 MiB of VMEM; the compile, not this constant, is the final
+# word (tests/test_tpu_compile).
 FUSED_RESIDENT_BYTES = 24 << 20
 # room around the planes for the per-cluster row buffers and pipelined
 # blocks (fused_cluster_bytes: 1.5 MiB at w = 256, dh = 128, shared QK)
-# and the temporaries of the kernel body's unrolled sub-tiles. At w = 1024
-# (N = 32768 in 32 clusters) the body needs more than 8 MiB beside the
-# 6-7 MiB of blocks to compile for v5e (tests/test_tpu_compile.py)
-_TILE_HEADROOM_BYTES = 16 << 20
+# and the temporaries of the kernel body's unrolled sub-tiles. At
+# w = 1536, dh = 64 (rt-imagenet64: 144 sub-tile pairs) the paged plan
+# needs 22.5 MB of scoped VMEM to compile for v5e (tests/test_tpu_compile)
+_TILE_HEADROOM_BYTES = 24 << 20
 # the share of that room one cluster's buffers and blocks may take
-FUSED_CLUSTER_BYTES = 8 << 20
+FUSED_CLUSTER_BYTES = 12 << 20
+
+
+def row_lanes(dh: int) -> int:
+    """Lanes a float32 row of ``dh`` takes in VMEM: the 128-lane tile,
+    rounded up. The fused routing kernel's member rows and planes are
+    this wide (dh = 64 rows are zero-padded to 128)."""
+    return -(-dh // 128) * 128
 
 
 def fused_resident_bytes(n: int, dh: int, planes: int) -> int:
     """VMEM bytes the resident plan's ``planes`` (N, dh) float32 planes
-    take, double-buffered."""
-    return planes * 2 * n * dh * 4
+    take, double-buffered, at ``row_lanes(dh)``."""
+    return planes * 2 * n * row_lanes(dh) * 4
 
 
 def fused_cluster_bytes(w: int, dh: int, planes: int) -> int:
     """VMEM bytes of the fused routing kernels' per-cluster buffers and
     blocks, the backward's (the larger): two slots of w member rows a
     plane, and the pipeline's double-buffered do block, three gradient
-    blocks, row stats and member positions."""
-    rows = w * dh * 4
+    blocks, row stats and member positions; every (w, dh) row block at
+    ``row_lanes(dh)``."""
+    rows = w * row_lanes(dh) * 4
     return (planes * 2 + 2 * (1 + 3)) * rows + 2 * 4 * w * 4
 
 
 # seq_len·head_dim cap of the forced resident plan, for the registry:
 # the three-plane (separate keys) worst case
-FUSED_RESIDENT_ELEMS = FUSED_RESIDENT_BYTES // fused_resident_bytes(1, 1, 3)
+FUSED_RESIDENT_ELEMS = FUSED_RESIDENT_BYTES // (3 * 2 * 4)
 
 
 def fused_paged_default(n: int, dh: int, planes: int,

@@ -61,7 +61,10 @@ their blocks' last two dims tile on the chip.
 
 Grid: (B·H·k clusters, w/bq, w/bk) gathered, KV axis sequential, (m, l,
 acc) scratch in VMEM; (B·H, k) fused, sub-tiles unrolled in the body.
-MXU-aligned: bq = bk = 128 default, dh in {64, 128, 256}.
+MXU-aligned: bq = bk = 128 default. The fused kernel's planes and member
+rows are ``row_lanes(dh)`` wide (a one-row DMA moves whole 128-lane rows,
+so dh = 64 rows are zero-padded to 128 in XLA); each loaded tile is cut
+back to dh, and its outputs are dh wide.
 """
 from __future__ import annotations
 
@@ -76,7 +79,8 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.common import NEG as _NEG
 from repro.kernels.common import (FUSED_CLUSTER_BYTES, default_interpret,
                                   float0_like, fused_cluster_bytes,
-                                  fused_paged_default, fused_vmem_limit)
+                                  fused_paged_default, fused_vmem_limit,
+                                  row_lanes)
 from repro.obs.trace import span
 
 SENTINEL = 2 ** 30          # python int: usable inside the kernel body
@@ -420,30 +424,37 @@ def _cluster_rows(idx, srcs, bufs, sems, shared, resident):
     return qb, (qb if shared else bufs[1].at[slot]), vb
 
 
+def _rows(buf, r, dh):
+    """Rows ``r`` of a member-row buffer, cut to the head dim (a dh = 64
+    buffer row is 128 lanes wide, its last 64 zero)."""
+    x = buf[r, :]
+    return x if x.shape[-1] == dh else x[:, :dh]
+
+
 def _fwd_kernel(*refs, shared, causal, scale, bq, bk, resident):
     idx, (pq_ref, pk_ref), srcs, _, (o_ref, lse_ref), bufs, sems = \
         _split_refs(refs, shared, 0, 2)
     qb, kb, vb = _cluster_rows(idx, srcs, bufs, sems, shared, resident)
-    w, dh = qb.shape
+    w, dh = o_ref.shape[-2:]
     # the gathered kernel's (iq, ik) grid as loops over the held cluster:
     # same sub-tiles, same online-softmax order, same arithmetic
     for iq in range(w // bq):
         rq = pl.ds(iq * bq, bq)
-        q = qb[rq, :]
+        q = _rows(qb, rq, dh)
         pq = pq_ref[0, 0, 0, rq]
         m = jnp.full((bq,), _NEG, jnp.float32)
         l = jnp.zeros((bq,), jnp.float32)
         acc = jnp.zeros((bq, dh), jnp.float32)
         for ik in range(w // bk):
             rk = pl.ds(ik * bk, bk)
-            s = _dot(q, kb[rk, :], 1, 1) * scale
+            s = _dot(q, _rows(kb, rk, dh), 1, 1) * scale
             keep = _keep_mask(pq, pk_ref[0, 0, 0, rk], causal)
             s = jnp.where(keep, s, _NEG)
             m_new = jnp.maximum(m, s.max(-1))
             p = jnp.where(keep, jnp.exp(s - m_new[:, None]), 0.0)
             corr = jnp.exp(m - m_new)
             l = l * corr + p.sum(-1)
-            acc = acc * corr[:, None] + _dot(p, vb[rk, :], 1, 0)
+            acc = acc * corr[:, None] + _dot(p, _rows(vb, rk, dh), 1, 0)
             m = m_new
         l = jnp.maximum(l, 1e-30)
         o_ref[0, 0, rq, :] = (acc / l[:, None]).astype(o_ref.dtype)
@@ -454,12 +465,12 @@ def _bwd_kernel(*refs, shared, causal, scale, bq, bk, resident):
     (idx, (pq_ref, pk_ref), srcs, (do_ref, lse_ref, dsum_ref),
      (dq_ref, dk_ref, dv_ref), bufs, sems) = _split_refs(refs, shared, 3, 3)
     qb, kb, vb = _cluster_rows(idx, srcs, bufs, sems, shared, resident)
-    w = qb.shape[0]
+    w, dh = dq_ref.shape[-2:]
     # one p/ds sub-tile feeds dq, dk and dv; dq sums over key sub-tiles
     # and dk/dv over query sub-tiles, each in ascending order
     for iq in range(w // bq):
         rq = pl.ds(iq * bq, bq)
-        q = qb[rq, :]
+        q = _rows(qb, rq, dh)
         do = do_ref[0, 0, rq, :].astype(jnp.float32)
         pq = pq_ref[0, 0, 0, rq]
         lse = lse_ref[0, 0, 0, rq]
@@ -467,11 +478,11 @@ def _bwd_kernel(*refs, shared, causal, scale, bq, bk, resident):
         dq = None
         for ik in range(w // bk):
             rk = pl.ds(ik * bk, bk)
-            k = kb[rk, :]
+            k = _rows(kb, rk, dh)
             keep = _keep_mask(pq, pk_ref[0, 0, 0, rk], causal)
             s = _dot(q, k, 1, 1) * scale
             p = jnp.where(keep, jnp.exp(s - lse[:, None]), 0.0)
-            dp = _dot(do, vb[rk, :], 1, 1)
+            dp = _dot(do, _rows(vb, rk, dh), 1, 1)
             ds = p * (dp - dsum[:, None]) * scale
             dq_t = _dot(ds, k, 1, 0)
             dq = dq_t if dq is None else dq + dq_t
@@ -487,14 +498,15 @@ def _bwd_kernel(*refs, shared, causal, scale, bq, bk, resident):
 
 
 def _fused_call(kernel, qf, kf, vf, qi, ki, pqg, pkg, extra, n_out, shared,
-                causal, bq, bk, resident, interpret, lse_out=False):
+                causal, bq, bk, resident, interpret, dh, lse_out=False):
     """One fused kernel over the (B·H, k) grid, a cluster a step. Inputs:
     the membership index blocks of this cluster and the next (SMEM), the
-    member positions, the q [k] v sources — the (N, dh) plane of the
-    batch·head when resident, untouched HBM (ANY) when paged — then
-    ``extra`` per-cluster (w, dh) and (1, w) blocks. Outputs: ``n_out``
-    float32 (w, dh) blocks, then the (1, w) row stats if ``lse_out``."""
-    BH, N, dh = qf.shape
+    member positions, the q [k] v sources — (N, row_lanes(dh)) planes,
+    the batch·head's whole plane when resident, untouched HBM (ANY) when
+    paged — then ``extra`` per-cluster (w, dh) and (1, w) blocks.
+    Outputs: ``n_out`` float32 (w, dh) blocks, then the (1, w) row stats
+    if ``lse_out``."""
+    BH, N, lanes = qf.shape
     _, kc, _, w = qi.shape
     planes = 2 if shared else 3
     f32 = jnp.float32
@@ -503,7 +515,7 @@ def _fused_call(kernel, qf, kf, vf, qi, ki, pqg, pkg, extra, n_out, shared,
     idx = [pl.BlockSpec((1, 1, 1, w), at, memory_space=pltpu.SMEM)
            for at in (cur, nxt)]
     if resident:
-        src = pl.BlockSpec((1, N, dh), lambda b, c: (b, 0, 0))
+        src = pl.BlockSpec((1, N, lanes), lambda b, c: (b, 0, 0))
     else:
         src = pl.BlockSpec(memory_space=pl.ANY)
     tile = pl.BlockSpec((1, 1, w, dh), cur)
@@ -525,7 +537,7 @@ def _fused_call(kernel, qf, kf, vf, qi, ki, pqg, pkg, extra, n_out, shared,
                   + [row if x.shape[-2] == 1 else tile for x in extra]),
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=([pltpu.VMEM((2, w, dh), f32)] * planes
+        scratch_shapes=([pltpu.VMEM((2, w, lanes), f32)] * planes
                         + [pltpu.SemaphoreType.DMA((2,))] * planes),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
@@ -535,15 +547,16 @@ def _fused_call(kernel, qf, kf, vf, qi, ki, pqg, pkg, extra, n_out, shared,
 
 
 def _fused_fwd_call(qf, kf, vf, qi, ki, pqg, pkg, shared, causal, bq, bk,
-                    resident, interpret):
+                    resident, interpret, dh):
     return _fused_call(_fwd_kernel, qf, kf, vf, qi, ki, pqg, pkg, (), 1,
-                       shared, causal, bq, bk, resident, interpret,
+                       shared, causal, bq, bk, resident, interpret, dh,
                        lse_out=True)
 
 
 def _fused_bwd_call(qf, kf, vf, qi, ki, pqg, pkg, out, lse, do, shared,
                     causal, bq, bk, resident, interpret):
-    BH, N, dh = qf.shape
+    BH, N, _ = qf.shape
+    dh = do.shape[-1]
     f32 = jnp.float32
     # the softmax backward's row sums, in XLA: the routing stage's
     # attention work, outside the kernel's span
@@ -551,7 +564,7 @@ def _fused_bwd_call(qf, kf, vf, qi, ki, pqg, pkg, out, lse, do, shared,
         dsum = (do * out).sum(-1)[:, :, None, :]
     dqg, dkg, dvg = _fused_call(_bwd_kernel, qf, kf, vf, qi, ki, pqg, pkg,
                                 (do, lse, dsum), 3, shared, causal, bq, bk,
-                                resident, interpret)
+                                resident, interpret, dh)
 
     # scatter-add per-cluster gradient blocks back to sequence layout —
     # the exact transpose of the kernel's implicit gather; duplicate
@@ -570,19 +583,34 @@ def _fused_bwd_call(qf, kf, vf, qi, ki, pqg, pkg, out, lse, do, shared,
     return dq, dk, dv
 
 
+def _lane_planes(qf, kf, vf, shared):
+    """The (q, k, v) planes at ``row_lanes(dh)``: a one-row DMA moves a
+    whole 128-lane row, so narrower rows are zero-padded (in XLA, inside
+    the kernel's span); shared-QK keys stay the q plane."""
+    dh = qf.shape[-1]
+    pad = row_lanes(dh) - dh
+    if pad:
+        qf, vf = (jnp.pad(x, ((0, 0), (0, 0), (0, pad))) for x in (qf, vf))
+        kf = qf if shared else jnp.pad(kf, ((0, 0), (0, 0), (0, pad)))
+    return qf, kf, vf
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4, 5))
 def _routed_fused(shared, causal, bq, bk, resident, interpret, qf, kf, vf,
                   qi, ki, pqg, pkg):
-    out, _ = _fused_fwd_call(qf, kf, vf, qi, ki, pqg, pkg, shared, causal,
-                             bq, bk, resident, interpret)
+    out, _ = _fused_fwd_call(*_lane_planes(qf, kf, vf, shared), qi, ki, pqg,
+                             pkg, shared, causal, bq, bk, resident,
+                             interpret, qf.shape[-1])
     return out
 
 
 def _routed_fused_fwd(shared, causal, bq, bk, resident, interpret, qf, kf,
                       vf, qi, ki, pqg, pkg):
-    out, lse = _fused_fwd_call(qf, kf, vf, qi, ki, pqg, pkg, shared,
-                               causal, bq, bk, resident, interpret)
-    return out, (qf, kf, vf, qi, ki, pqg, pkg, out, lse)
+    qp, kp, vp = _lane_planes(qf, kf, vf, shared)
+    out, lse = _fused_fwd_call(qp, kp, vp, qi, ki, pqg, pkg, shared,
+                               causal, bq, bk, resident, interpret,
+                               qf.shape[-1])
+    return out, (qp, kp, vp, qi, ki, pqg, pkg, out, lse)
 
 
 def _routed_fused_bwd(shared, causal, bq, bk, resident, interpret, res, do):

@@ -44,6 +44,9 @@ def test_flash_attention_sweep(dtype, B, H, Hkv, N, dh, bq, bk, causal):
     (2, 4, 2, 256, 64, 64, True),
     (1, 2, 1, 128, 32, 32, False),
     (2, 2, 2, 256, 128, 128, True),
+    # windows past the query sub-tile (sub_tile(1024, 64) = 256 rows)
+    (1, 2, 1, 2048, 64, 1024, True),
+    (1, 2, 2, 2048, 64, 1024, False),
 ])
 def test_local_attention_sweep(dtype, B, H, Hkv, N, dh, w, causal):
     ks = jax.random.split(KEY, 3)
@@ -133,6 +136,29 @@ def test_local_attention_grad_parity(causal):
     assert _grad_maxdiff(g, gr) < GRAD_TOL
 
 
+@pytest.mark.parametrize("causal", [True, False])
+def test_local_attention_sub_tiled_grad_parity(causal):
+    """A window larger than its query sub-tile: the forward and dq run
+    four 256-row query sub-tiles of each 1024-row block against its 2048
+    keys, dk/dv four 256-row key sub-tiles against the whole query
+    blocks that attend them."""
+    from repro.kernels.local_attention import sub_tile
+    B, H, Hkv, N, dh, w = 1, 2, 1, 2048, 64, 1024
+    assert sub_tile(w, dh) == 256 and sub_tile(256, 128) == 256
+    ks = jax.random.split(KEY, 4)
+    q = jax.random.normal(ks[0], (B, H, N, dh))
+    k = jax.random.normal(ks[1], (B, Hkv, N, dh))
+    v = jax.random.normal(ks[2], (B, Hkv, N, dh))
+    wt = jax.random.normal(ks[3], (B, H, N, dh))
+    f = lambda q, k, v: (ops.local_attention(q, k, v, window=w,
+                                             causal=causal) * wt).sum()
+    fr = lambda q, k, v: (ref.local_attention_ref(q, k, v, window=w,
+                                                  causal=causal) * wt).sum()
+    g = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(fr, argnums=(0, 1, 2))(q, k, v)
+    assert _grad_maxdiff(g, gr) < GRAD_TOL
+
+
 def _routing_case(case):
     """(cfg, k_or_None, pad_mask) for a named routing parity case."""
     from repro.configs.base import RoutingConfig
@@ -151,13 +177,16 @@ def _routing_case(case):
     }[case]
 
 
-@pytest.mark.parametrize("impl", ["pallas", "pallas_fused"])
+@pytest.mark.parametrize("impl", ["pallas", "pallas_fused",
+                                  "pallas_fused_paged"])
 @pytest.mark.parametrize("case", ["causal_shared", "causal_shared_padded",
                                   "noncausal_separate", "noncausal_padded",
                                   "segmented"])
 def test_routing_grad_parity(impl, case):
-    """Kernel VJPs (gathered and fused) vs jax.grad of the XLA reference
-    through the full routing module, on every mask/sharing regime."""
+    """Kernel outputs and VJPs (gathered, and fused in both memory plans)
+    vs the XLA reference through the full routing module, on every
+    mask/sharing regime, at dh = 64 (the fused kernel's rows padded to
+    128 lanes)."""
     from repro.core.kmeans import init_kmeans
     from repro.core.routing import routed_attention
     B, H, N, dh = 2, 4, 256, 64
@@ -172,12 +201,15 @@ def test_routing_grad_parity(impl, case):
         def f(q, k, v):
             out = routed_attention(q, k, v, st, cfg, pad_mask=pm,
                                    update_state=False, impl=impl).out
-            return (out * wt).sum()
+            return (out * wt).sum(), out
         return f
 
     args = (0, 2) if k is None else (0, 1, 2)
-    g = jax.grad(loss(impl), argnums=args)(q, k, v)
-    gr = jax.grad(loss("xla"), argnums=args)(q, k, v)
+    (_, o), g = jax.value_and_grad(loss(impl), argnums=args,
+                                   has_aux=True)(q, k, v)
+    (_, o_r), gr = jax.value_and_grad(loss("xla"), argnums=args,
+                                      has_aux=True)(q, k, v)
+    assert float(jnp.abs(o - o_r).max()) < TOL["float32"]
     assert _grad_maxdiff(g, gr) < GRAD_TOL
 
 

@@ -102,6 +102,46 @@ def test_fused_routing_kernel_compiles(one_chip, n, dtype, with_grad,
     assert calls == (2 if with_grad else 1), calls
 
 
+# rt-imagenet64 (configs/paper.py): 8 local + 8 routing heads of dh 64,
+# window 2048, 8 clusters of 1536 over sequence 12288
+IMG_N, IMG_DH, IMG_KC, IMG_WINDOW, IMG_HEADS = 12288, 64, 8, 2048, 8
+
+
+def test_local_kernel_compiles_past_its_sub_tile(one_chip):
+    """w = 2048: 128-row query sub-tiles against 4096 keys (a one-shot
+    (w x 2w) float32 score tile would be 32 MiB), with the gradient."""
+    from repro.kernels.local_attention import (local_attention_kernel,
+                                               sub_tile)
+    assert sub_tile(IMG_WINDOW, IMG_DH) == 128
+    x = _shape(one_chip, (1, IMG_HEADS, IMG_N, IMG_DH), "float32")
+    fn = lambda q, k, v: local_attention_kernel(q, k, v, IMG_WINDOW,
+                                                interpret=False)
+    text = _compile(_grad(fn, (0, 1, 2)), x, x, x)
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
+@pytest.mark.parametrize("paged", [None, True])
+def test_fused_routing_kernel_compiles_at_dh64(one_chip, paged):
+    """dh = 64 rows padded to 128 lanes. The auto plan (None) at
+    N = 12288 keeps the two (N, 128) planes resident (24 MiB, the
+    budget); the paged plan compiles too, and a cluster of 1536 rows fits
+    FUSED_CLUSTER_BYTES."""
+    from repro.kernels.routing_attention import routed_attention_fused
+    assert common.fused_paged_default(IMG_N, IMG_DH, 2) is False
+    assert common.fused_cluster_bytes(IMG_N // IMG_KC, IMG_DH, 2) <= \
+        common.FUSED_CLUSTER_BYTES
+    x = _shape(one_chip, (1, IMG_HEADS, IMG_N, IMG_DH), "float32")
+    idx = _shape(one_chip, (1, IMG_HEADS, IMG_KC, IMG_N // IMG_KC), "int32")
+    pos = _shape(one_chip, (1, IMG_N), "int32")
+
+    def fn(q, v, i, p):
+        return _grad(lambda q, v: routed_attention_fused(
+            q, None, v, i, i, p, interpret=False, paged=paged), (0, 1))(q, v)
+
+    text = _compile(fn, x, x, idx, pos)
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+
+
 def test_paged_decode_kernel_compiles(one_chip):
     from repro.kernels.routing_decode import paged_routing_decode
     B, cap, bf = 8, 256, "bfloat16"

@@ -243,10 +243,14 @@ def routed_attention(q: jax.Array,
         # routing-health telemetry (repro.obs, DESIGN.md §10): reuses the
         # scores/membership computed above; the static `if` keeps the
         # stats-off HLO byte-identical to a build without the flag
+        from repro.kernels.routing_attention import computed_tile_share
         from repro.obs.routing_stats import compute_routing_stats
         stats = compute_routing_stats(
             r_q, k_attn, state.mu, new_state.mu, scores_q, q_idx, k_idx,
-            positions, pad_mask, cfg.causal, probes=cfg.stats_probes)
+            positions, pad_mask, cfg.causal,
+            computed_tile_share(positions, q_idx, k_idx, cfg.causal,
+                                kvalid=pad_mask),
+            probes=cfg.stats_probes)
     return RoutingOutput(out=out, state=new_state,
                          attn=attn if return_attn else None,
                          q_idx=q_idx if return_attn else None,

@@ -26,9 +26,23 @@ array and read keys from the q buffer.
 
 The compute loops over the held cluster's (bq, bk) sub-tiles in the
 order the gathered kernel's grid visits them, with the same online
-softmax and the same float32 matmuls, so the two kernels' forward
-outputs agree bit for bit at the same (bq, bk). A cluster's buffers and
-blocks are O(w·dh); a w whose cluster does not fit
+softmax and the same float32 matmuls. In causal mode it skips the pairs
+past the causal band when none of them holds an attendable (query, key)
+pair: query sub-tile iq then visits key sub-tiles [0, ``band_tiles``[iq])
+alone, the T(T+1)/2 of T² pairs that sorted members at increasing
+positions can need. Whether a cluster takes the band is read from its
+members' positions (``band_clusters``, in XLA beside the position
+gathers: each query sub-tile's latest position against each later key
+sub-tile's earliest; padded keys sit at SENTINEL), so a cluster whose
+members repeat across a sub-tile edge or whose positions fall visits
+every pair, as does non-causal mode. A skipped pair is one the mask
+covers entirely: it would add p = 0 with corr = 1 to (m, l, acc), and
+ds = 0 to dq, dk and dv, so skipping it changes no sum, and the two
+kernels' forward outputs agree bit for bit at the same (bq, bk). The
+band is a static loop bound under one branch a cluster, not a branch a
+pair: on a v5e, a branch a pair with the softmax state in VMEM scratch
+made the forward slower than visiting every pair (PERF.md §6). A
+cluster's buffers and blocks are O(w·dh); a w whose cluster does not fit
 ``FUSED_CLUSTER_BYTES`` (kernels/common.py) is refused at trace time.
 
 The fused kernel has two memory plans that differ only in where the row
@@ -73,6 +87,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -95,6 +110,30 @@ def _dot(a, b, ca, cb):
     rounding noise."""
     return jax.lax.dot_general(a, b, (((ca,), (cb,)), ((), ())),
                                precision=jax.lax.Precision.HIGHEST)
+
+
+def band_tiles(w, bq, bk):
+    """The causal band: query sub-tile iq's keys that sorted members at
+    increasing positions can attend lie in key sub-tiles [0, hi[iq]),
+    those that start at or before the query sub-tile's last row."""
+    return [((iq + 1) * bq - 1) // bk + 1 for iq in range(w // bq)]
+
+
+def band_clusters(pq, pk, causal, bq, bk):
+    """Per cluster of (..., w) member positions (padded keys at
+    SENTINEL): whether no sub-tile pair past the causal band holds a
+    (query, key) pair ``_keep_mask`` keeps — each query sub-tile's
+    latest position comes before every later key sub-tile's earliest.
+    Always False in non-causal mode, where every pair is visited."""
+    lead, w = pq.shape[:-1], pq.shape[-1]
+    if not causal:
+        return jnp.zeros(lead, bool)
+    q_last = pq.reshape(*lead, w // bq, bq).max(-1)
+    k_first = pk.reshape(*lead, w // bk, bk).min(-1)
+    past = (np.arange(w // bk)[None, :]
+            >= np.asarray(band_tiles(w, bq, bk))[:, None])
+    live = q_last[..., :, None] >= k_first[..., None, :]
+    return ~jnp.any(live & past, axis=(-2, -1))
 
 
 def _keep_mask(pq, pk, causal):
@@ -378,11 +417,12 @@ def _wait_rows(dst, sem):
 
 def _split_refs(refs, shared, n_in, n_out):
     """A fused kernel's refs: membership index blocks (current and next
-    cluster, for q and, with separate keys, for k), member positions,
-    the (q, [k,] v) sources, ``n_in`` more inputs, ``n_out`` outputs,
-    then one (2, w, dh) row buffer and one semaphore pair a plane."""
+    cluster, for q and, with separate keys, for k), member positions and
+    the cluster's band flag, the (q, [k,] v) sources, ``n_in`` more
+    inputs, ``n_out`` outputs, then one (2, w, dh) row buffer and one
+    semaphore pair a plane."""
     planes = 2 if shared else 3
-    sizes = (planes - 1) * 2, 2, planes, n_in, n_out, planes, planes
+    sizes = (planes - 1) * 2, 3, planes, n_in, n_out, planes, planes
     parts, at = [], 0
     for n in sizes:
         parts.append(refs[at:at + n])
@@ -431,81 +471,116 @@ def _rows(buf, r, dh):
     return x if x.shape[-1] == dh else x[:, :dh]
 
 
+def _by_band(band_ref, causal, w, bq, bk, body):
+    """Run ``body(hi)``, where query sub-tile iq visits key sub-tiles
+    [0, hi[iq]): the causal band when the cluster's flag says no pair
+    past it is attendable, else every pair. One branch a cluster; each
+    side's loops are unrolled whole."""
+    every = [w // bk] * (w // bq)
+    band = band_tiles(w, bq, bk)
+    if not causal or band == every:
+        body(every)
+        return
+    take = band_ref[0, 0, 0, 0] != 0
+
+    @pl.when(take)
+    def _band():
+        body(band)
+
+    @pl.when(jnp.logical_not(take))
+    def _every():
+        body(every)
+
+
 def _fwd_kernel(*refs, shared, causal, scale, bq, bk, resident):
-    idx, (pq_ref, pk_ref), srcs, _, (o_ref, lse_ref), bufs, sems = \
-        _split_refs(refs, shared, 0, 2)
+    idx, (pq_ref, pk_ref, band_ref), srcs, _, (o_ref, lse_ref), bufs, \
+        sems = _split_refs(refs, shared, 0, 2)
     qb, kb, vb = _cluster_rows(idx, srcs, bufs, sems, shared, resident)
     w, dh = o_ref.shape[-2:]
+
     # the gathered kernel's (iq, ik) grid as loops over the held cluster:
-    # same sub-tiles, same online-softmax order, same arithmetic
-    for iq in range(w // bq):
-        rq = pl.ds(iq * bq, bq)
-        q = _rows(qb, rq, dh)
-        pq = pq_ref[0, 0, 0, rq]
-        m = jnp.full((bq,), _NEG, jnp.float32)
-        l = jnp.zeros((bq,), jnp.float32)
-        acc = jnp.zeros((bq, dh), jnp.float32)
-        for ik in range(w // bk):
-            rk = pl.ds(ik * bk, bk)
-            s = _dot(q, _rows(kb, rk, dh), 1, 1) * scale
-            keep = _keep_mask(pq, pk_ref[0, 0, 0, rk], causal)
-            s = jnp.where(keep, s, _NEG)
-            m_new = jnp.maximum(m, s.max(-1))
-            p = jnp.where(keep, jnp.exp(s - m_new[:, None]), 0.0)
-            corr = jnp.exp(m - m_new)
-            l = l * corr + p.sum(-1)
-            acc = acc * corr[:, None] + _dot(p, _rows(vb, rk, dh), 1, 0)
-            m = m_new
-        l = jnp.maximum(l, 1e-30)
-        o_ref[0, 0, rq, :] = (acc / l[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0, 0, rq] = m + jnp.log(l)
+    # same sub-tiles, same online-softmax order, same arithmetic; a pair
+    # past hi[iq] would leave (m, l, acc) as they are
+    def attend(hi):
+        for iq in range(w // bq):
+            rq = pl.ds(iq * bq, bq)
+            q = _rows(qb, rq, dh)
+            pq = pq_ref[0, 0, 0, rq]
+            m = jnp.full((bq,), _NEG, jnp.float32)
+            l = jnp.zeros((bq,), jnp.float32)
+            acc = jnp.zeros((bq, dh), jnp.float32)
+            for ik in range(hi[iq]):
+                rk = pl.ds(ik * bk, bk)
+                s = _dot(q, _rows(kb, rk, dh), 1, 1) * scale
+                keep = _keep_mask(pq, pk_ref[0, 0, 0, rk], causal)
+                s = jnp.where(keep, s, _NEG)
+                m_new = jnp.maximum(m, s.max(-1))
+                p = jnp.where(keep, jnp.exp(s - m_new[:, None]), 0.0)
+                corr = jnp.exp(m - m_new)
+                l = l * corr + p.sum(-1)
+                acc = acc * corr[:, None] + _dot(p, _rows(vb, rk, dh), 1, 0)
+                m = m_new
+            l = jnp.maximum(l, 1e-30)
+            o_ref[0, 0, rq, :] = (acc / l[:, None]).astype(o_ref.dtype)
+            lse_ref[0, 0, 0, rq] = m + jnp.log(l)
+
+    _by_band(band_ref, causal, w, bq, bk, attend)
 
 
 def _bwd_kernel(*refs, shared, causal, scale, bq, bk, resident):
-    (idx, (pq_ref, pk_ref), srcs, (do_ref, lse_ref, dsum_ref),
+    (idx, (pq_ref, pk_ref, band_ref), srcs, (do_ref, lse_ref, dsum_ref),
      (dq_ref, dk_ref, dv_ref), bufs, sems) = _split_refs(refs, shared, 3, 3)
     qb, kb, vb = _cluster_rows(idx, srcs, bufs, sems, shared, resident)
     w, dh = dq_ref.shape[-2:]
+
     # one p/ds sub-tile feeds dq, dk and dv; dq sums over key sub-tiles
-    # and dk/dv over query sub-tiles, each in ascending order
-    for iq in range(w // bq):
-        rq = pl.ds(iq * bq, bq)
-        q = _rows(qb, rq, dh)
-        do = do_ref[0, 0, rq, :].astype(jnp.float32)
-        pq = pq_ref[0, 0, 0, rq]
-        lse = lse_ref[0, 0, 0, rq]
-        dsum = dsum_ref[0, 0, 0, rq]
-        dq = None
-        for ik in range(w // bk):
-            rk = pl.ds(ik * bk, bk)
-            k = _rows(kb, rk, dh)
-            keep = _keep_mask(pq, pk_ref[0, 0, 0, rk], causal)
-            s = _dot(q, k, 1, 1) * scale
-            p = jnp.where(keep, jnp.exp(s - lse[:, None]), 0.0)
-            dp = _dot(do, _rows(vb, rk, dh), 1, 1)
-            ds = p * (dp - dsum[:, None]) * scale
-            dq_t = _dot(ds, k, 1, 0)
-            dq = dq_t if dq is None else dq + dq_t
-            dv_t = _dot(p, do, 0, 0)
-            dk_t = _dot(ds, q, 0, 0)
-            if iq == 0:
-                dv_ref[0, 0, rk, :] = dv_t
-                dk_ref[0, 0, rk, :] = dk_t
-            else:
-                dv_ref[0, 0, rk, :] += dv_t
-                dk_ref[0, 0, rk, :] += dk_t
-        dq_ref[0, 0, rq, :] = dq
+    # and dk/dv over query sub-tiles, each in ascending order. A pair past
+    # hi[iq] has p = ds = 0; every key sub-tile is in the last query
+    # sub-tile's range, so each dk/dv block is written
+    def grads(hi):
+        written = set()
+        for iq in range(w // bq):
+            rq = pl.ds(iq * bq, bq)
+            q = _rows(qb, rq, dh)
+            do = do_ref[0, 0, rq, :].astype(jnp.float32)
+            pq = pq_ref[0, 0, 0, rq]
+            lse = lse_ref[0, 0, 0, rq]
+            dsum = dsum_ref[0, 0, 0, rq]
+            dq = None
+            for ik in range(hi[iq]):
+                rk = pl.ds(ik * bk, bk)
+                k = _rows(kb, rk, dh)
+                keep = _keep_mask(pq, pk_ref[0, 0, 0, rk], causal)
+                s = _dot(q, k, 1, 1) * scale
+                p = jnp.where(keep, jnp.exp(s - lse[:, None]), 0.0)
+                dp = _dot(do, _rows(vb, rk, dh), 1, 1)
+                ds = p * (dp - dsum[:, None]) * scale
+                dq_t = _dot(ds, k, 1, 0)
+                dq = dq_t if dq is None else dq + dq_t
+                dv_t = _dot(p, do, 0, 0)
+                dk_t = _dot(ds, q, 0, 0)
+                if ik in written:
+                    dv_ref[0, 0, rk, :] += dv_t
+                    dk_ref[0, 0, rk, :] += dk_t
+                else:
+                    dv_ref[0, 0, rk, :] = dv_t
+                    dk_ref[0, 0, rk, :] = dk_t
+                    written.add(ik)
+            dq_ref[0, 0, rq, :] = dq
+
+    _by_band(band_ref, causal, w, bq, bk, grads)
 
 
-def _fused_call(kernel, qf, kf, vf, qi, ki, pqg, pkg, extra, n_out, shared,
-                causal, bq, bk, resident, interpret, dh, lse_out=False):
+def _fused_call(kernel, qf, kf, vf, qi, ki, pqg, pkg, band, extra, n_out,
+                shared, causal, bq, bk, resident, interpret, dh,
+                lse_out=False):
     """One fused kernel over the (B·H, k) grid, a cluster a step. Inputs:
     the membership index blocks of this cluster and the next (SMEM), the
-    member positions, the q [k] v sources — (N, row_lanes(dh)) planes,
-    the batch·head's whole plane when resident, untouched HBM (ANY) when
-    paged — then ``extra`` per-cluster (w, dh) and (1, w) blocks.
-    Outputs: ``n_out`` float32 (w, dh) blocks, then the (1, w) row stats
-    if ``lse_out``."""
+    member positions, the cluster's band flag (SMEM), the q [k] v
+    sources — (N, row_lanes(dh)) planes, the batch·head's whole plane
+    when resident, untouched HBM (ANY) when paged — then ``extra``
+    per-cluster (w, dh) and (1, w) blocks. Outputs: ``n_out`` float32
+    (w, dh) blocks, then the (1, w) row stats if ``lse_out``."""
     BH, N, lanes = qf.shape
     _, kc, _, w = qi.shape
     planes = 2 if shared else 3
@@ -520,6 +595,7 @@ def _fused_call(kernel, qf, kf, vf, qi, ki, pqg, pkg, extra, n_out, shared,
         src = pl.BlockSpec(memory_space=pl.ANY)
     tile = pl.BlockSpec((1, 1, w, dh), cur)
     row = pl.BlockSpec((1, 1, 1, w), cur)
+    flag = pl.BlockSpec((1, 1, 1, 1), cur, memory_space=pltpu.SMEM)
     indices = (qi, qi) + (() if shared else (ki, ki))
     srcs = (qf,) + (() if shared else (kf,)) + (vf,)
     out_shape = [jax.ShapeDtypeStruct((BH, kc, w, dh), f32)] * n_out
@@ -532,7 +608,7 @@ def _fused_call(kernel, qf, kf, vf, qi, ki, pqg, pkg, extra, n_out, shared,
                           scale=1.0 / (dh ** 0.5), bq=bq, bk=bk,
                           resident=resident),
         grid=(BH, kc),
-        in_specs=(idx * (len(indices) // 2) + [row, row]
+        in_specs=(idx * (len(indices) // 2) + [row, row, flag]
                   + [src] * planes
                   + [row if x.shape[-2] == 1 else tile for x in extra]),
         out_specs=out_specs,
@@ -543,18 +619,18 @@ def _fused_call(kernel, qf, kf, vf, qi, ki, pqg, pkg, extra, n_out, shared,
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=fused_vmem_limit(N, dh, planes, resident)),
         interpret=interpret,
-    )(*indices, pqg, pkg, *srcs, *extra)
+    )(*indices, pqg, pkg, band, *srcs, *extra)
 
 
-def _fused_fwd_call(qf, kf, vf, qi, ki, pqg, pkg, shared, causal, bq, bk,
-                    resident, interpret, dh):
-    return _fused_call(_fwd_kernel, qf, kf, vf, qi, ki, pqg, pkg, (), 1,
-                       shared, causal, bq, bk, resident, interpret, dh,
+def _fused_fwd_call(qf, kf, vf, qi, ki, pqg, pkg, band, shared, causal, bq,
+                    bk, resident, interpret, dh):
+    return _fused_call(_fwd_kernel, qf, kf, vf, qi, ki, pqg, pkg, band, (),
+                       1, shared, causal, bq, bk, resident, interpret, dh,
                        lse_out=True)
 
 
-def _fused_bwd_call(qf, kf, vf, qi, ki, pqg, pkg, out, lse, do, shared,
-                    causal, bq, bk, resident, interpret):
+def _fused_bwd_call(qf, kf, vf, qi, ki, pqg, pkg, band, out, lse, do,
+                    shared, causal, bq, bk, resident, interpret):
     BH, N, _ = qf.shape
     dh = do.shape[-1]
     f32 = jnp.float32
@@ -563,8 +639,8 @@ def _fused_bwd_call(qf, kf, vf, qi, ki, pqg, pkg, out, lse, do, shared,
     with span("routing/attend"):
         dsum = (do * out).sum(-1)[:, :, None, :]
     dqg, dkg, dvg = _fused_call(_bwd_kernel, qf, kf, vf, qi, ki, pqg, pkg,
-                                (do, lse, dsum), 3, shared, causal, bq, bk,
-                                resident, interpret, dh)
+                                band, (do, lse, dsum), 3, shared, causal, bq,
+                                bk, resident, interpret, dh)
 
     # scatter-add per-cluster gradient blocks back to sequence layout —
     # the exact transpose of the kernel's implicit gather; duplicate
@@ -597,31 +673,67 @@ def _lane_planes(qf, kf, vf, shared):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4, 5))
 def _routed_fused(shared, causal, bq, bk, resident, interpret, qf, kf, vf,
-                  qi, ki, pqg, pkg):
+                  qi, ki, pqg, pkg, band):
     out, _ = _fused_fwd_call(*_lane_planes(qf, kf, vf, shared), qi, ki, pqg,
-                             pkg, shared, causal, bq, bk, resident,
+                             pkg, band, shared, causal, bq, bk, resident,
                              interpret, qf.shape[-1])
     return out
 
 
 def _routed_fused_fwd(shared, causal, bq, bk, resident, interpret, qf, kf,
-                      vf, qi, ki, pqg, pkg):
+                      vf, qi, ki, pqg, pkg, band):
     qp, kp, vp = _lane_planes(qf, kf, vf, shared)
-    out, lse = _fused_fwd_call(qp, kp, vp, qi, ki, pqg, pkg, shared,
+    out, lse = _fused_fwd_call(qp, kp, vp, qi, ki, pqg, pkg, band, shared,
                                causal, bq, bk, resident, interpret,
                                qf.shape[-1])
-    return out, (qp, kp, vp, qi, ki, pqg, pkg, out, lse)
+    return out, (qp, kp, vp, qi, ki, pqg, pkg, band, out, lse)
 
 
 def _routed_fused_bwd(shared, causal, bq, bk, resident, interpret, res, do):
-    qf, kf, vf, qi, ki, pqg, pkg, out, lse = res
-    dq, dk, dv = _fused_bwd_call(qf, kf, vf, qi, ki, pqg, pkg, out, lse, do,
-                                 shared, causal, bq, bk, resident, interpret)
+    qf, kf, vf, qi, ki, pqg, pkg, band, out, lse = res
+    dq, dk, dv = _fused_bwd_call(qf, kf, vf, qi, ki, pqg, pkg, band, out,
+                                 lse, do, shared, causal, bq, bk, resident,
+                                 interpret)
     return (dq, dk, dv, float0_like(qi), float0_like(ki),
-            float0_like(pqg), float0_like(pkg))
+            float0_like(pqg), float0_like(pkg), float0_like(band))
 
 
 _routed_fused.defvjp(_routed_fused_fwd, _routed_fused_bwd)
+
+
+def _member_positions(positions, kvalid, qi, ki):
+    """The (B, N) positions of the (B·H, k, 1, w) query and key members,
+    int32; keys that ``kvalid`` marks as padding read SENTINEL."""
+    B, N = positions.shape
+    BH, kc, _, w = qi.shape
+    posq = positions.astype(jnp.int32)
+    posk = (jnp.where(kvalid, posq, SENTINEL) if kvalid is not None
+            else posq)
+
+    def member_pos(pos, idx):
+        src = jnp.broadcast_to(pos[:, None, :], (B, BH // B, N))
+        return jnp.take_along_axis(src.reshape(BH, N),
+                                   idx.reshape(BH, kc * w),
+                                   axis=1).reshape(BH, kc, 1, w)
+
+    return member_pos(posq, qi), member_pos(posk, ki)
+
+
+def computed_tile_share(positions, q_idx, k_idx, causal, kvalid=None,
+                        bq=128, bk=128):
+    """(H,) share of the (bq, bk) sub-tile pairs that
+    ``routed_attention_fused`` computes for these memberships, mean over
+    batch and clusters: the band's where ``band_clusters`` holds, else 1.
+    Arguments as there (k_idx None: shared keys)."""
+    B, H, kc, w = q_idx.shape
+    bq, bk = min(bq, w), min(bk, w)
+    qi = q_idx.reshape(B * H, kc, 1, w).astype(jnp.int32)
+    ki = qi if k_idx is None else k_idx.reshape(B * H, kc, 1, w).astype(
+        jnp.int32)
+    band = band_clusters(*_member_positions(positions, kvalid, qi, ki),
+                         causal, bq, bk)
+    share = sum(band_tiles(w, bq, bk)) / ((w // bq) * (w // bk))
+    return jnp.where(band, share, 1.0).reshape(B, H, kc).mean((0, 2))
 
 
 def routed_attention_fused(q, k, v, q_idx, k_idx, positions, causal=True,
@@ -668,21 +780,15 @@ def routed_attention_fused(q, k, v, q_idx, k_idx, positions, causal=True,
     vf = v.reshape(B * H, N, dh).astype(f32)
     qi = q_idx.reshape(B * H, kc, 1, w).astype(jnp.int32)
     ki = qi if shared else k_idx.reshape(B * H, kc, 1, w).astype(jnp.int32)
-    posq = positions.astype(jnp.int32)
-    posk = (jnp.where(kvalid, posq, SENTINEL) if kvalid is not None
-            else posq)
-
-    def member_pos(pos, idx):
-        src = jnp.broadcast_to(pos[:, None, :], (B, H, N)).reshape(B * H, N)
-        return jnp.take_along_axis(src, idx.reshape(B * H, kc * w),
-                                   axis=1).reshape(B * H, kc, 1, w)
-
     resident = not fused_paged_default(N, dh, planes, paged)
-    # XLA's gathers of the members' positions take the routing stage's
-    # span, so the kernel's span holds the kernel's own time
+    # XLA's gathers of the members' positions, and the clusters' band
+    # flags read from them, take the routing stage's span, so the
+    # kernel's span holds the kernel's own time
     with span("routing/gather"):
-        pqg, pkg = member_pos(posq, qi), member_pos(posk, ki)
+        pqg, pkg = _member_positions(positions, kvalid, qi, ki)
+        band = band_clusters(pqg, pkg, causal, bq, bk).astype(
+            jnp.int32)[..., None]
     out = _routed_fused(shared, bool(causal), int(bq), int(bk), resident,
                         default_interpret(interpret), qf, kf, vf, qi, ki,
-                        pqg, pkg)
+                        pqg, pkg, band)
     return out.reshape(B, H, kc, w, dh).astype(q.dtype)

@@ -25,6 +25,11 @@ Per routing layer (leaves shaped over that layer's routing heads H):
                      queries, the fraction of full-softmax attention
                      mass (same normalized q/k, same causal/pad masks)
                      that falls on keys the routed pattern can reach
+  live_tiles (H,)    share of the fused routing kernels' (bq, bk)
+                     sub-tile pairs computed for this membership (batch
+                     mean): T(T+1)/2 of T² where a cluster's members
+                     keep to the causal band, 1 where they do not or
+                     attention is not causal (computed by core.routing)
 
 Everything is fp32 and stop_gradient'ed: stats must never change grads.
 This module imports jax + stdlib only (obs stays below repro.core in the
@@ -44,7 +49,8 @@ import jax.numpy as jnp
 _BIG_NEG = -1e9
 _EPS = 1e-12
 
-SCALAR_FIELDS = ("entropy", "dead", "drift", "mismatch", "recall")
+SCALAR_FIELDS = ("entropy", "dead", "drift", "mismatch", "recall",
+                 "live_tiles")
 
 
 class RoutingStats(NamedTuple):
@@ -54,6 +60,7 @@ class RoutingStats(NamedTuple):
     drift: jax.Array        # (H,)
     mismatch: jax.Array     # (H,)
     recall: jax.Array       # (H,)
+    live_tiles: jax.Array   # (H,)
 
 
 def _probe_idx(n: int, probes: int):
@@ -69,13 +76,15 @@ def compute_routing_stats(r_q: jax.Array, k_attn: jax.Array,
                           scores_q: jax.Array, q_idx: jax.Array,
                           k_idx: jax.Array, positions: jax.Array,
                           pad_mask: Optional[jax.Array], causal: bool,
+                          live_tiles: jax.Array,
                           probes: int = 8) -> RoutingStats:
     """All inputs are the routing layer's own intermediates:
 
     r_q/k_attn (B,H,N,dh) normalized routing vectors / attention keys,
     mu_prev/mu_new (H,k,dh) centroids before/after the EMA update,
     scores_q (B,H,N,k) centroid affinities, q_idx/k_idx (B,H,k,w)
-    balanced memberships, positions (B,N), pad_mask (B,N) or None.
+    balanced memberships, positions (B,N), pad_mask (B,N) or None,
+    live_tiles (H,) the fused kernels' computed sub-tile share.
     """
     B, H, N, dh = r_q.shape
     kc = scores_q.shape[-1]
@@ -134,7 +143,8 @@ def compute_routing_stats(r_q: jax.Array, k_attn: jax.Array,
         dead=dead.mean(0),
         drift=drift,
         mismatch=mismatch,
-        recall=recall))
+        recall=recall,
+        live_tiles=live_tiles.astype(f32)))
 
 
 # ---------------------------------------------------------------------------

@@ -302,6 +302,159 @@ def test_fused_forward_matches_gathered_kernel(shared, causal, valid, bq,
     assert float(jnp.abs(og - of).max()) < 1e-6
 
 
+def _unique_members(key, B, H, N, kc):
+    """(B,H,k,N/k) sorted memberships that partition the sequence, as
+    balanced top-k gives them."""
+    perms = [jax.random.permutation(k, N)
+             for k in jax.random.split(key, B * H)]
+    return jnp.sort(jnp.stack(perms).reshape(B, H, kc, N // kc), axis=-1)
+
+
+def _spy_lse(monkeypatch, ra, name, rec):
+    """Record the row stats (the second output) of ``ra.<name>`` each time
+    the compiled program runs."""
+    orig = getattr(ra, name)
+    jax.clear_caches()      # no earlier trace may skip the spy
+
+    def spy(*a, **kw):
+        out = orig(*a, **kw)
+        jax.debug.callback(lambda x: rec.__setitem__(name, x), out[1])
+        return out
+    monkeypatch.setattr(ra, name, spy)
+
+
+@pytest.mark.parametrize("members,causal,valid", [
+    ("unique", True, False), ("unique", True, True),
+    ("repeated", True, False), ("unique", False, True),
+])
+def test_fused_forward_and_lse_bitwise_at_twelve_tiles(monkeypatch, members,
+                                                       causal, valid):
+    """w/bq = 12, the rt-imagenet64 cell's sub-tiles a cluster side (w 1536
+    at 128): with unique sorted members at increasing positions the
+    causal kernel visits the band alone (78 of 144 pairs), with repeated
+    members every pair; either way output and lse equal the gathered
+    kernel's bit for bit."""
+    import repro.kernels.routing_attention as ra
+    B, H, N, dh, kc, w, b = 1, 2, 768, 32, 4, 192, 16
+    ks = jax.random.split(KEY, 6)
+    q = jax.random.normal(ks[0], (B, H, N, dh))
+    v = jax.random.normal(ks[2], (B, H, N, dh))
+    qi = (_unique_members(ks[3], B, H, N, kc) if members == "unique" else
+          jnp.sort(jax.random.randint(ks[3], (B, H, kc, w), 0, N), axis=-1))
+    pos = jnp.broadcast_to(jnp.arange(N, dtype=jnp.int32), (B, N))
+    kvalid = jax.random.bernoulli(ks[5], 0.9, (B, N)) if valid else None
+
+    def rows(x):
+        return jnp.take_along_axis(x, qi.reshape(B, H, -1, 1),
+                                   axis=2).reshape(B, H, kc, w, dh)
+
+    def seqg(x):
+        return jnp.take_along_axis(jnp.broadcast_to(x[:, None], (B, H, N)),
+                                   qi.reshape(B, H, -1),
+                                   axis=2).reshape(B, H, kc, w)
+
+    pq = seqg(pos)
+    band = ra.band_clusters(pq, pq, causal, b, b)
+    assert bool(band.all()) == (causal and members == "unique")
+    rec = {}
+    _spy_lse(monkeypatch, ra, "_g_fwd_call", rec)
+    _spy_lse(monkeypatch, ra, "_fused_fwd_call", rec)
+    og = ops.routed_attention_blocks(
+        rows(q), rows(q), rows(v), pq, pq, causal=causal,
+        valid_k=None if kvalid is None else seqg(kvalid), bq=b, bk=b)
+    of = ops.routed_attention_fused(q, None if causal else q, v, qi,
+                                    None if causal else qi, pos,
+                                    causal=causal, kvalid=kvalid, bq=b, bk=b)
+    jax.block_until_ready((og, of))
+    assert bool(jnp.array_equal(og, of)), float(jnp.abs(og - of).max())
+    lse_g = rec["_g_fwd_call"].reshape(-1)
+    lse_f = rec["_fused_fwd_call"].reshape(-1)
+    assert bool(jnp.array_equal(lse_g, lse_f))
+
+
+def _band_case(case, B, H, N, kc):
+    """(q_idx, k_idx or None, positions, kvalid, causal) of a named input
+    regime for the fused kernel's band choice."""
+    w = N // kc
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    arange = jnp.broadcast_to(jnp.arange(N, dtype=jnp.int32), (B, N))
+    members = _unique_members(ks[0], B, H, N, kc)
+    contiguous = jnp.broadcast_to(jnp.arange(N).reshape(kc, w), (B, H, kc, w))
+    if case == "band":
+        return members, None, arange, None, True
+    if case == "doc_resets":          # positions restart every 48 tokens
+        return members, None, arange % 48, None, True
+    if case == "padded_key_tile":     # cluster 1's first key sub-tile
+        kvalid = jnp.broadcast_to((jnp.arange(N) < w)
+                                  | (jnp.arange(N) >= w + 16), (B, N))
+        return contiguous, contiguous, arange, kvalid, True
+    if case == "dead_query_tile":     # keys all after cluster 0's queries
+        return contiguous, jnp.roll(contiguous, -1, axis=2), arange, None, \
+            True
+    if case == "noncausal_padded_tile":
+        kvalid = jnp.broadcast_to(jnp.arange(N) >= 16, (B, N))
+        return contiguous, contiguous, arange, kvalid, False
+    raise ValueError(case)
+
+
+@pytest.mark.parametrize("case", ["band", "doc_resets", "padded_key_tile",
+                                  "dead_query_tile", "noncausal_padded_tile"])
+def test_fused_grad_parity_across_band_choices(case):
+    """Fused output and VJP vs the XLA reference on clusters that take the
+    causal band and on clusters that must visit every pair: positions
+    that restart, a wholly padded key sub-tile, a query sub-tile with no
+    attendable key, non-causal padding."""
+    import repro.kernels.routing_attention as ra
+    from repro.core.routing import _block_attention
+    B, H, N, dh, kc, b = 1, 2, 256, 32, 4, 16
+    w = N // kc
+    qi, ki, pos, kvalid, causal = _band_case(case, B, H, N, kc)
+    shared = ki is None
+    ks = jax.random.split(KEY, 4)
+    q = jax.random.normal(ks[0], (B, H, N, dh))
+    k = None if shared else jax.random.normal(ks[1], (B, H, N, dh))
+    v = jax.random.normal(ks[2], (B, H, N, dh))
+    wt = jax.random.normal(ks[3], (B, H, kc, w, dh))
+    kidx = qi if shared else ki
+
+    def rows(x, idx):
+        return jnp.take_along_axis(x, idx.reshape(B, H, -1, 1),
+                                   axis=2).reshape(B, H, kc, w, dh)
+
+    def seqg(x, idx):
+        return jnp.take_along_axis(jnp.broadcast_to(x[:, None], (B, H, N)),
+                                   idx.reshape(B, H, -1),
+                                   axis=2).reshape(B, H, kc, w)
+
+    pq, pk = seqg(pos, qi), seqg(pos, kidx)
+    vk = None if kvalid is None else seqg(kvalid, kidx)
+    band = ra.band_clusters(pq, jnp.where(vk, pk, ra.SENTINEL)
+                            if vk is not None else pk, causal, b, b)
+    every, some = bool(band.all()), bool(band.any())
+    assert {"band": every, "doc_resets": not every,
+            "padded_key_tile": every, "dead_query_tile": some and not every,
+            "noncausal_padded_tile": not some}[case], band
+
+    def fused(q, k, v):
+        return (ops.routed_attention_fused(q, k, v, qi, ki, pos,
+                                           causal=causal, kvalid=kvalid,
+                                           bq=b, bk=b) * wt).sum()
+
+    def xla(q, k, v):
+        kk = q if k is None else k
+        og, _ = _block_attention(rows(q, qi), rows(kk, kidx),
+                                 rows(v, kidx), pq, pk, causal, vk, False)
+        return (og * wt).sum()
+
+    args = (0, 2) if shared else (0, 1, 2)
+    f, g = jax.value_and_grad(fused, argnums=args)(q, k, v)
+    fr, gr = jax.value_and_grad(xla, argnums=args)(q, k, v)
+    assert abs(float(f - fr)) < 1e-3 * max(1.0, abs(float(fr)))
+    assert _grad_maxdiff(g, gr) < GRAD_TOL
+    if case == "dead_query_tile":     # no attendable key: zero gradient
+        assert float(jnp.abs(g[0][:, :, :w]).max()) == 0.0
+
+
 # ---------------------------------------------------------------------------
 # Paged fused kernel: double-buffered sequence-plane DMA (the VMEM pager)
 # ---------------------------------------------------------------------------

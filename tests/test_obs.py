@@ -220,10 +220,61 @@ def test_summarize_folds_tree():
     stats = routed_attention(q, None, v, st, cfg, update_state=False).stats
     summ = summarize([{"0": stats}, {}])
     assert set(summ) == {f"routing/{f}" for f in
-                         ("entropy", "dead", "drift", "mismatch", "recall")}
+                         ("entropy", "dead", "drift", "mismatch", "recall",
+                          "live_tiles")}
     assert float(summ["routing/entropy"]) == pytest.approx(
         float(jnp.mean(stats.entropy)), rel=1e-6)
     assert summarize([{}, {}]) == {}
+
+
+@pytest.mark.parametrize("T", [2, 12])
+def test_live_tiles_reads_the_causal_band(T):
+    """Unique sorted members at increasing positions keep to the causal
+    band: T(T+1)/2 of T² sub-tile pairs (78/144 at T = 12, rt-imagenet64's
+    w 1536; 3/4 at T = 2, rt-enwik8's w 256); non-causal, unpadded, all."""
+    from repro.kernels.routing_attention import computed_tile_share
+    B, H, kc, b = 2, 3, 4, 16
+    w = T * b
+    N = kc * w
+    perms = [jax.random.permutation(k, N)
+             for k in jax.random.split(jax.random.PRNGKey(T), B * H)]
+    idx = jnp.sort(jnp.stack(perms).reshape(B, H, kc, w), axis=-1)
+    pos = jnp.broadcast_to(jnp.arange(N, dtype=jnp.int32), (B, N))
+    band = computed_tile_share(pos, idx, None, True, bq=b, bk=b)
+    np.testing.assert_allclose(band, T * (T + 1) / (2 * T * T), rtol=1e-6)
+    full = computed_tile_share(pos, idx, idx, False, bq=b, bk=b)
+    np.testing.assert_array_equal(full, 1.0)
+    # positions that restart mid-cluster reach past the band: every pair
+    restart = computed_tile_share(pos % (w // 2), idx, None, True, bq=b,
+                                  bk=b)
+    assert np.all(np.asarray(restart) > T * (T + 1) / (2 * T * T))
+
+
+def test_live_tiles_leaf_only_with_stats_on():
+    """Through routed_attention at the kernel's own sub-tiles (128): w 256
+    reads 3/4 causal and 1 non-causal; with stats off there is no stats
+    leaf and the program is the default's."""
+    B, H, N, kc = 1, 2, 512, 2
+    q, v, st = _routing_inputs(B=B, H=H, N=N, kc=kc)
+    on = routed_attention(q, None, v, st,
+                          RoutingConfig(num_clusters=kc, stats=True),
+                          update_state=False).stats
+    np.testing.assert_allclose(on.live_tiles, 0.75, rtol=1e-6)
+    assert "routing/live_tiles" in summarize([{"0": on}])
+    k = jax.random.normal(jax.random.PRNGKey(9), q.shape)
+    nc = routed_attention(q, k, v, st,
+                          RoutingConfig(num_clusters=kc, stats=True,
+                                        causal=False, share_qk=False),
+                          update_state=False).stats
+    np.testing.assert_array_equal(nc.live_tiles, 1.0)
+    off = RoutingConfig(num_clusters=kc, stats=False)
+    assert routed_attention(q, None, v, st, off,
+                            update_state=False).stats is None
+
+    def lower(cfg):
+        return jax.jit(lambda q, v: routed_attention(
+            q, None, v, st, cfg, update_state=False)).lower(q, v).as_text()
+    assert lower(off) == lower(RoutingConfig(num_clusters=kc))
 
 
 def test_pages_health_reads_rlen():

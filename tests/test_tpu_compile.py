@@ -142,6 +142,24 @@ def test_fused_routing_kernel_compiles_at_dh64(one_chip, paged):
     assert text.count('custom_call_target="tpu_custom_call"') == 2
 
 
+@pytest.mark.parametrize("paged", [None, True])
+def test_fused_routing_forward_compiles_at_dh64(one_chip, paged):
+    """The forward alone at the rt-imagenet64 shape (12 sub-tiles a
+    cluster side): both unrolled bodies, the causal band and every pair,
+    and the per-cluster band flag in SMEM fit the chip's budgets."""
+    from repro.kernels.routing_attention import routed_attention_fused
+    x = _shape(one_chip, (1, IMG_HEADS, IMG_N, IMG_DH), "float32")
+    idx = _shape(one_chip, (1, IMG_HEADS, IMG_KC, IMG_N // IMG_KC), "int32")
+    pos = _shape(one_chip, (1, IMG_N), "int32")
+
+    def fn(q, v, i, p):
+        return routed_attention_fused(q, None, v, i, None, p,
+                                      interpret=False, paged=paged)
+
+    text = _compile(fn, x, x, idx, pos)
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
 def test_paged_decode_kernel_compiles(one_chip):
     from repro.kernels.routing_decode import paged_routing_decode
     B, cap, bf = 8, 256, "bfloat16"

@@ -253,7 +253,7 @@ def markdown_table(rows: Dict[str, Dict], mesh: str = "pod") -> str:
 
 # ---------------------------------------------------------------------------
 # Attention-op roofline: routing (paged fused) vs flash, the O(n^1.5)
-# crossover (§Roofline, op level — feeds BENCH_routing.json)
+# crossover (§Roofline, op level — feeds the routing sweep's record)
 # ---------------------------------------------------------------------------
 ATTN_SEQ_LENS = (1024, 4096, 8192, 16384, 32768)
 

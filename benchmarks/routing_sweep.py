@@ -1,34 +1,29 @@
-"""--routing-sweep: gathered vs gather-free fused routing kernel.
+"""--routing-sweep: the XLA routing reference vs the fused routing kernel.
 
 One row per (sequence length, impl) through the full ``routed_attention``
 module (shared-QK causal, k = sqrt-ish clusters of window 256), measuring
 tok/s of the jitted call and peak memory (XLA ``memory_analysis`` temp +
-output bytes). Impls cover both memory plans of the fused kernel —
-``pallas_fused_paged`` (double-buffered per-row DMA, no VMEM residency
-cliff) and ``pallas_fused_unpaged`` (whole-plane resident) — next to the
-auto-switching ``pallas_fused``, the gathered ``pallas`` kernel and the
-``xla`` reference. Every row carries the device kind and whether the
-kernel ran in interpret mode, so hardware and CI numbers are never
+output bytes). Impls: the ``xla`` reference, which gathers (B,H,k,w,dh)
+blocks, and the gather-free ``pallas_fused`` kernel, which chooses its
+own memory plan from N·dh. Every row carries the device kind and whether
+the kernel ran in interpret mode, so hardware and CI numbers are never
 conflated in the trend line.
 
-The same record is written to ``BENCH_routing.json`` at the repo root —
-the perf-trajectory baseline for the routing hot-spot — together with
-the analytic routing-vs-flash roofline (benchmarks/roofline.py
-``attention_roofline``), whose predicted O(n^1.5)-vs-O(n^2) crossover
-carries the at-scale speed story that CPU wall-clock cannot.
+The record is written to ``BENCH_routing.json`` at the repo root (a CI
+artifact, not committed) together with the analytic routing-vs-flash
+roofline (benchmarks/roofline.py ``attention_roofline``), whose
+predicted O(n^1.5)-vs-O(n^2) crossover carries the at-scale speed story
+that CPU wall-clock cannot.
 
-``check=True`` gates the sweep: every impl's output must match the xla
-reference (always), and on real TPU hardware the paged fused rows must
-not be slower than the gathered kernel (tok/s ordering is only asserted
-when the platform is ``tpu``; see the interpret-mode caveat below).
+``check=True`` gates the sweep: the kernel's output must match the xla
+reference at every N.
 
-Interpret-mode caveat (CPU CI, this container): the Pallas rows execute
-the kernel bodies via the interpreter, where the fused kernel's in-VMEM
-row pulls cost more wall-clock than XLA's vectorized HBM gather — tok/s
-*inverts* relative to hardware. The HBM story is in ``peak_mb``: the
-fused rows drop the gathered (B,H,k,w,dh) q/k/v copies from the compiled
-buffer plan at every N. On TPU (interpret off) the same drop is the
-bandwidth win; record hardware numbers by re-running this sweep there.
+Interpret-mode caveat (CPU CI): the kernel rows execute the kernel body
+via the interpreter, where its in-VMEM row pulls cost more wall-clock
+than XLA's vectorized HBM gather — tok/s *inverts* relative to
+hardware, and ``peak_mb`` counts the interpreter's buffers, not the
+chip's. Neither is a device number; the on-chip benchmark (``bench/``)
+measures the kernel.
 """
 from __future__ import annotations
 
@@ -49,8 +44,7 @@ Row = Tuple[str, float, str]
 B, H, DH = 1, 2, 64
 WINDOW = 256
 SEQ_LENS = (1024, 4096, 8192)
-IMPLS = ("xla", "pallas", "pallas_fused", "pallas_fused_paged",
-         "pallas_fused_unpaged")
+IMPLS = ("xla", "pallas_fused")
 CHECK_TOL = 2e-4
 JSON_PATH = Path(__file__).resolve().parents[1] / "BENCH_routing.json"
 
@@ -84,10 +78,9 @@ def routing_sweep_rows(iters: int = 3, seq_lens=SEQ_LENS,
         "interpret": interpret,
         "note": ("interpret-mode wall-clock (CPU): fused in-kernel row "
                  "pulls are interpreter-slow, so tok/s inverts vs "
-                 "hardware; the fused win is the gathered-copy drop in "
-                 "peak_mb (and HBM bandwidth on TPU) — the at-scale "
-                 "speed story is the analytic crossover under "
-                 "'roofline'"),
+                 "hardware, and peak_mb counts the interpreter's "
+                 "buffers — the at-scale speed story is the analytic "
+                 "crossover under 'roofline'"),
         "checked": bool(check),
         "points": [],
         # analytic routing-vs-flash model + predicted O(n^1.5) crossover
@@ -132,18 +125,12 @@ def routing_sweep_rows(iters: int = 3, seq_lens=SEQ_LENS,
                                     "tok_s": round(tok_s),
                                     "peak_bytes": peak,
                                     "maxdiff_vs_xla": maxdiff}
-        g = point["impls"]["pallas"]
+        x = point["impls"]["xla"]
         f = point["impls"]["pallas_fused"]
-        p = point["impls"]["pallas_fused_paged"]
-        point["fused_speedup_tok_s"] = round(f["tok_s"] / g["tok_s"], 3)
-        point["paged_speedup_tok_s"] = round(p["tok_s"] / g["tok_s"], 3)
+        point["fused_speedup_tok_s"] = round(f["tok_s"] / x["tok_s"], 3)
         point["fused_peak_ratio"] = (
-            round(f["peak_bytes"] / g["peak_bytes"], 3)
-            if g["peak_bytes"] else None)
-        if check and platform == "tpu" and p["tok_s"] < g["tok_s"]:
-            raise SystemExit(
-                f"routing sweep perf check failed on tpu: N={N} paged "
-                f"fused {p['tok_s']} tok/s < gathered {g['tok_s']} tok/s")
+            round(f["peak_bytes"] / x["peak_bytes"], 3)
+            if x["peak_bytes"] else None)
         record["points"].append(point)
     return rows, record
 

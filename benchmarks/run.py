@@ -11,15 +11,13 @@ bits/dim, ...). TPU-projected numbers live in the roofline table
 show up in the same report tables; ``--backend-sweep-only`` skips the
 paper tables (fast per-push trend line).
 
-``--routing-sweep`` appends the gathered-vs-fused routing kernel rows
-across N in {1k, 4k, 8k} x {xla, pallas, pallas_fused, and both forced
-fused memory plans} (tok/s + memory_analysis peak + device kind +
-interpret flag) and rewrites ``BENCH_routing.json`` at the repo root —
-the routing hot-spot's perf trajectory, including the analytic
-routing-vs-flash roofline crossover; ``--routing-sweep-only`` runs just
-that (the push-time CI bench job). ``--routing-check`` additionally
-gates the sweep: output parity vs the xla reference always, and
-paged-fused >= gathered tok/s when running on real TPU hardware.
+``--routing-sweep`` appends the routing rows across N in {1k, 4k, 8k} x
+{xla reference, pallas_fused kernel} (tok/s + memory_analysis peak +
+device kind + interpret flag) and writes ``BENCH_routing.json`` at the
+repo root (a CI artifact), including the analytic routing-vs-flash
+roofline crossover; ``--routing-sweep-only`` runs just that (the
+push-time CI bench job). ``--routing-check`` additionally gates the
+sweep on the kernel's output parity with the xla reference.
 
 ``--obs-sweep`` appends routing-health telemetry rows (occupancy entropy
 vs log k, dead clusters, balanced-vs-nearest mismatch, sampled attention

@@ -116,8 +116,8 @@ def attend(spec: AttentionSpec, q, k, v, *, state=None, positions=None,
         return AttnOutput(out=out, state=state, cache=new_cache)
     backend = resolve(spec, padded=pad_mask is not None,
                       positioned=positions is not None,
-                      needs_grad=needs_grad, seq_len=q.shape[2],
-                      mesh=mesh, impl=impl, platform=plat)
+                      needs_grad=needs_grad, mesh=mesh, impl=impl,
+                      platform=plat)
     res = backend.apply(spec, q, k, v, state=state,
                         positions=positions, pad_mask=pad_mask,
                         update_state=update_state,

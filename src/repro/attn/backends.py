@@ -10,41 +10,31 @@ Registered pairs (variant, impl):
   local/pallas       blocked local kernel (kernels.local_attention)
   routing/xla        Algorithm-1 reference (core.routing),
                      cluster-paged decode
-  routing/pallas     gathered-block attention on the Pallas kernel
-                     (core.routing impl="pallas")
   routing/pallas_fused   gather-free fused kernel: sequence-layout q/k/v,
-                     membership via scalar prefetch — no (B,H,k,w,dh)
-                     q/k/v intermediates in HBM (DESIGN.md §9); preferred
-                     over routing/pallas on TPU (priority 20 vs 10). The
-                     kernel's memory plan auto-switches past the VMEM
-                     residency budget to per-row DMA from HBM, so there
-                     is no seq-length registration cliff
-  routing/pallas_fused_paged / _unpaged   forced memory plans of the same
-                     kernel (priority 0 — explicit ``impl=`` only); the
-                     unpaged one keeps the old ``max_seq_elems`` cap
-                     because whole-plane residency genuinely overflows
-                     VMEM past it
+                     membership via per-cluster SMEM blocks — no
+                     (B,H,k,w,dh) q/k/v intermediates in HBM (DESIGN.md
+                     §9). The kernel chooses its memory plan from N·dh
+                     (whole planes resident in VMEM, or member rows
+                     DMA'd from HBM), so it serves every sequence length
   routing/pallas_paged   fused apply + the paged-decode kernel
                      (kernels.routing_decode): single-token decode DMAs
                      only the selected cluster page into VMEM via
                      scalar-prefetched page tables — decode is gather-
                      free too, and resolves here on TPU (priority 20)
   local+routing/xla      paper head split, both halves reference
-  local+routing/pallas   local half on the Pallas window kernel, routing
-                     blocks on the gathered Pallas kernel
   local+routing/pallas_fused  both halves Pallas: window kernel + fused
-                     routing (plus the forced _paged/_unpaged variants)
+                     routing
   local+routing/pallas_paged  fused apply; decode = ring-local reference
                      + paged routing kernel
 
 Every Pallas backend is differentiable (the kernels carry flash-style
-custom VJPs), so ``impl="pallas"``/``"pallas_fused"`` are legal on the
-train path. Decode: the routing variants resolve to ``pallas_paged`` on
-TPU — token- and cache-trajectory bit-parity with the xla cluster-paged
-reference (the kernel shares the reference's routing + cache-write code
-and mirrors its attention op sequence; per-step outputs agree to float
-ulps, see kernels.routing_decode); full/local decode stays on the xla
-append/ring references (already gather-free).
+custom VJPs), so each is legal on the train path. Decode: the routing
+variants resolve to ``pallas_paged`` on TPU — token- and
+cache-trajectory bit-parity with the xla cluster-paged reference (the
+kernel shares the reference's routing + cache-write code and mirrors its
+attention op sequence; per-step outputs agree to float ulps, see
+kernels.routing_decode); full/local decode stays on the xla append/ring
+references (already gather-free).
 
 Rope is applied *here*, per variant: full/local heads are roped, routing
 heads are not (their routing vectors and shared-QK attention keys are
@@ -74,7 +64,6 @@ from repro.core.attention import full_attention
 from repro.core.kmeans import KMeansState, normalize_routing
 from repro.core.local import local_attention
 from repro.core.routing import routed_attention
-from repro.kernels.common import FUSED_RESIDENT_ELEMS
 from repro.models import layers as L
 from repro.obs.trace import span
 
@@ -192,19 +181,19 @@ def _make_routing_apply(kernel_impl: str):
     return apply
 
 
-def _make_mixed_apply(kernel_impl: str, local_kernel: bool = False):
+def _make_mixed_apply(kernel_impl: str):
     """Composite apply for the local+routing head split.
 
-    ``local_kernel=True`` (every Pallas-family registration) runs the
-    local half on the Pallas window kernel — which carries its own
-    flash-style custom VJP, so the composite gradient is kernel-backed
-    end to end instead of mixing a fused routing grad with the XLA-
-    reference local grad. The window kernel's affine BlockSpec pipeline
-    already double-buffers its (w, dh) tiles, so its VMEM footprint is
-    bounded by the window, never by N — it needs no manual paging. The
-    reference serves the cases the kernel does not express (pad_mask,
-    N not a multiple of the window)."""
+    The kernel impl (``pallas_fused``) runs the local half on the Pallas
+    window kernel — which carries its own flash-style custom VJP, so the
+    composite gradient is kernel-backed end to end instead of mixing a
+    fused routing grad with the XLA-reference local grad. The window
+    kernel's affine BlockSpec pipeline already double-buffers its (w, dh)
+    tiles, so its VMEM footprint is bounded by the window, never by N —
+    it needs no manual paging. The reference serves the cases the kernel
+    does not express (pad_mask, N not a multiple of the window)."""
     routing_apply = _make_routing_apply(kernel_impl)
+    local_kernel = kernel_impl != "xla"
 
     def apply(spec, q, k, v, *, state=None, positions=None, pad_mask=None,
               update_state=True, interpret=None):
@@ -540,51 +529,22 @@ registry.register(Backend(
     caps=Capabilities(supports_decode=True, supports_mesh=True,
                       supports_pad_mask=True, supports_grad=True)))
 
-registry.register(Backend(
-    variant="routing", impl="pallas", apply=_make_routing_apply("pallas"),
-    priority=10,
-    caps=Capabilities(supports_decode=False, supports_mesh=False,
-                      supports_pad_mask=True, supports_grad=True,
-                      needs_tpu=True)))
-
-# gather-free fused kernel: highest priority, so TPU auto-selection takes
-# it over the gathered pallas path; supports_grad via its custom VJP.
-# supports_mesh=False like every Pallas backend: a GSPMD mesh call falls
-# back to the reference; the shard_map train path (per-device programs,
-# no mesh at attend) runs the kernel in distributed training (§9).
-# No max_seq_elems cap: the kernel auto-switches its memory plan at the
-# VMEM residency budget (kernels.common.FUSED_RESIDENT_BYTES, bytes of
-# the resident planes) — whole-plane VMEM residency below it, member
-# rows DMA'd from HBM above (VMEM bounded by the cluster size w, not N),
-# so paper-scale N=8k–32k in 32 clusters stays fused forward and
-# backward.
+# gather-free fused kernel: priority 20, so TPU auto-selection takes it
+# over the reference; supports_grad via its custom VJP. supports_mesh=
+# False like every Pallas backend: a GSPMD mesh call falls back to the
+# reference; the shard_map train path (per-device programs, no mesh at
+# attend) runs the kernel in distributed training (§9). No sequence cap:
+# the kernel switches its memory plan at the VMEM residency budget
+# (kernels.common.fused_paged_default) — whole-plane VMEM residency
+# below it, member rows DMA'd from HBM above (VMEM bounded by the
+# cluster size w, not N), so paper-scale N=8k–32k in 32 clusters stays
+# fused forward and backward.
 registry.register(Backend(
     variant="routing", impl="pallas_fused",
     apply=_make_routing_apply("pallas_fused"), priority=20,
     caps=Capabilities(supports_decode=False, supports_mesh=False,
                       supports_pad_mask=True, supports_grad=True,
                       needs_tpu=True)))
-
-# forced memory plans of the fused kernel, priority 0: never auto-chosen
-# (tie with xla resolves to the earlier registration), reachable with an
-# explicit impl= — the parity matrix and benches exercise both plans this
-# way. Only the unpaged one still carries the residency cap: whole-plane
-# VMEM residency genuinely overflows past it, and resolve() now names
-# the fallback in the forced-impl error instead of stranding the caller.
-registry.register(Backend(
-    variant="routing", impl="pallas_fused_paged",
-    apply=_make_routing_apply("pallas_fused_paged"), priority=0,
-    caps=Capabilities(supports_decode=False, supports_mesh=False,
-                      supports_pad_mask=True, supports_grad=True,
-                      needs_tpu=True)))
-
-registry.register(Backend(
-    variant="routing", impl="pallas_fused_unpaged",
-    apply=_make_routing_apply("pallas_fused_unpaged"), priority=0,
-    caps=Capabilities(supports_decode=False, supports_mesh=False,
-                      supports_pad_mask=True, supports_grad=True,
-                      needs_tpu=True,
-                      max_seq_elems=FUSED_RESIDENT_ELEMS)))
 
 registry.register(Backend(
     variant="local+routing", impl="xla", apply=_make_mixed_apply("xla"),
@@ -593,36 +553,11 @@ registry.register(Backend(
                       supports_pad_mask=True, supports_grad=True)))
 
 registry.register(Backend(
-    variant="local+routing", impl="pallas",
-    apply=_make_mixed_apply("pallas", local_kernel=True), priority=10,
-    caps=Capabilities(supports_decode=False, supports_mesh=False,
-                      supports_pad_mask=True, supports_grad=True,
-                      needs_tpu=True)))
-
-registry.register(Backend(
     variant="local+routing", impl="pallas_fused",
-    apply=_make_mixed_apply("pallas_fused", local_kernel=True),
-    priority=20,
+    apply=_make_mixed_apply("pallas_fused"), priority=20,
     caps=Capabilities(supports_decode=False, supports_mesh=False,
                       supports_pad_mask=True, supports_grad=True,
                       needs_tpu=True)))
-
-registry.register(Backend(
-    variant="local+routing", impl="pallas_fused_paged",
-    apply=_make_mixed_apply("pallas_fused_paged", local_kernel=True),
-    priority=0,
-    caps=Capabilities(supports_decode=False, supports_mesh=False,
-                      supports_pad_mask=True, supports_grad=True,
-                      needs_tpu=True)))
-
-registry.register(Backend(
-    variant="local+routing", impl="pallas_fused_unpaged",
-    apply=_make_mixed_apply("pallas_fused_unpaged", local_kernel=True),
-    priority=0,
-    caps=Capabilities(supports_decode=False, supports_mesh=False,
-                      supports_pad_mask=True, supports_grad=True,
-                      needs_tpu=True,
-                      max_seq_elems=FUSED_RESIDENT_ELEMS)))
 
 # paged decode: fused apply plus the paged-decode kernel, so the serving
 # hot path is Pallas too. Registered AFTER pallas_fused at the same
@@ -642,7 +577,7 @@ registry.register(Backend(
 
 registry.register(Backend(
     variant="local+routing", impl="pallas_paged",
-    apply=_make_mixed_apply("pallas_fused", local_kernel=True),
+    apply=_make_mixed_apply("pallas_fused"),
     decode=_mixed_decode_paged, layout=MIXED_LAYOUT, priority=20,
     caps=Capabilities(supports_decode=True, supports_mesh=False,
                       supports_pad_mask=True, supports_grad=True,

@@ -8,28 +8,24 @@ page structure), and a ``Capabilities`` record the resolver filters on.
 
 Resolution order (``resolve``): among the backends registered for the
 spec's variant, drop those whose capabilities don't cover the call
-(decode needed, pad_mask present, mesh > 1 device, sequence too long,
-TPU-only backend off-TPU), then take the highest ``priority``. Pallas
-kernels register with priority 10 and ``needs_tpu=True``: auto-selection
+(decode needed, pad_mask present, mesh > 1 device, no VJP for a
+differentiated call, TPU-only backend off-TPU), then take the highest
+``priority`` (the first registered on a tie). This is the one place the
+program chooses a kernel; the fused routing kernel then chooses its own
+memory plan from N·dh (kernels/common.py). Pallas kernels register with
+``needs_tpu=True`` and a priority above the references': auto-selection
 prefers them on TPU and never picks them elsewhere, while an explicit
-``impl="pallas"`` still runs anywhere via interpret mode (that is what
-the CPU kernel-parity CI lane exercises). Every *other* capability
-mismatch on an explicit ``impl=`` override is a loud
-``BackendResolutionError`` — a forced backend silently computing the
-wrong thing (ignoring padding, lacking a decode path) is the failure
-mode this registry exists to kill; the error also names the backend
-auto-selection would have used, so the caller knows the escape hatch.
-
-Auto-selection that skips a higher-priority backend *purely on sequence
-capacity* (``max_seq`` / ``max_seq_elems``) is not silent either: each
-occurrence increments the obs ``attn/fallback`` counter and the first
-occurrence per (excluded, chosen) pair emits a RuntimeWarning — a call
-landing on a slower path at scale leaves a signal.
+``impl=`` still runs one anywhere via interpret mode (that is what the
+CPU kernel-parity CI lane exercises). Every *other* capability mismatch
+on an explicit ``impl=`` override is a loud ``BackendResolutionError`` —
+a forced backend silently computing the wrong thing (ignoring padding,
+lacking a decode path) is the failure mode this registry exists to
+kill; the error also names the backend auto-selection would have used,
+so the caller knows the escape hatch.
 """
 from __future__ import annotations
 
-import warnings
-from dataclasses import dataclass, field, replace
+from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Mapping, Optional, Tuple
 
 from repro.attn.spec import AttentionSpec
@@ -112,10 +108,6 @@ class Capabilities:
     announce it — their outputs are wrapped in a guard whose backward
     raises this registry's error instead of an opaque Pallas trace
     failure (see ``attn.attend``).
-    ``max_seq_elems``: cap on seq_len · head_dim — for kernels whose
-    working set scales with the (N, dh) plane (the fused routing kernel
-    keeps q/k/v sequence planes VMEM-resident), where a seq-only cap
-    would be wrong for wide heads.
     """
 
     supports_decode: bool = False
@@ -125,14 +117,6 @@ class Capabilities:
     supports_logit_scale: bool = False
     supports_grad: bool = False
     needs_tpu: bool = False
-    max_seq: Optional[int] = None
-    max_seq_elems: Optional[int] = None
-    # DEPRECATED (one-release shim): the stringly-typed layout tag.
-    # The typed ``Backend.layout`` (a CacheLayout) is authoritative;
-    # ``register`` mirrors ``layout.name`` into this field so external
-    # readers of the old string keep working for one release. Do not
-    # read it in new code — use ``backend.layout``.
-    cache_layout: str = ""
 
 
 @dataclass(frozen=True)
@@ -205,26 +189,6 @@ def register(backend: Backend) -> Backend:
             or backend.layout.fill is None):
         raise ValueError(f"{backend.name}: supports_decode without a "
                          f"declared CacheLayout (layout.init/layout.fill)")
-    if backend.layout is not None:
-        # one-release shim: mirror the typed layout's name into the
-        # deprecated caps.cache_layout string so external readers of the
-        # old field keep seeing the right value. A backend that sets the
-        # string itself must agree with its typed layout.
-        if (backend.caps.cache_layout
-                and backend.caps.cache_layout != backend.layout.name):
-            raise ValueError(
-                f"{backend.name}: deprecated caps.cache_layout "
-                f"{backend.caps.cache_layout!r} contradicts the typed "
-                f"layout {backend.layout.name!r}")
-        if backend.caps.cache_layout != backend.layout.name:
-            object.__setattr__(
-                backend, "caps",
-                replace(backend.caps, cache_layout=backend.layout.name))
-    elif backend.caps.cache_layout:
-        warnings.warn(
-            f"{backend.name}: caps.cache_layout is a deprecated string "
-            f"tag; declare a typed CacheLayout via Backend(layout=...)",
-            DeprecationWarning, stacklevel=2)
     _REGISTRY[backend.key] = backend
     return backend
 
@@ -303,58 +267,9 @@ def pageable_cache_leaves() -> Dict[str, str]:
     return out
 
 
-def _capacity_gaps(b: Backend, *, seq_len: Optional[int],
-                   head_dim: int) -> List[str]:
-    """Sequence-capacity gaps only (max_seq / max_seq_elems) — the class
-    of exclusion that silently degrades an otherwise-eligible backend at
-    scale, which auto-selection reports through obs (see resolve)."""
-    gaps = []
-    if (seq_len is not None and b.caps.max_seq is not None
-            and seq_len > b.caps.max_seq):
-        gaps.append(f"seq_len {seq_len} exceeds max_seq {b.caps.max_seq}")
-    if (seq_len is not None and b.caps.max_seq_elems is not None
-            and seq_len * head_dim > b.caps.max_seq_elems):
-        gaps.append(
-            f"seq_len x head_dim {seq_len}x{head_dim} exceeds "
-            f"max_seq_elems {b.caps.max_seq_elems} (the backend's "
-            f"resident-plane budget)")
-    return gaps
-
-
-_FALLBACK_WARNED: set = set()
-
-
-def _note_capacity_fallback(excluded: List[Backend], chosen: Backend,
-                            gap_kw) -> None:
-    """A strictly-higher-priority backend lost to ``chosen`` purely on
-    sequence capacity: count it (obs ``attn/fallback``) and warn once per
-    (excluded, chosen) pair — the N=8k-silently-lands-on-the-gathered-
-    path failure mode gets a signal instead of a mystery slowdown."""
-    from repro.obs import default_registry
-    for b in excluded:
-        cap = _capacity_gaps(b, seq_len=gap_kw["seq_len"],
-                             head_dim=gap_kw["head_dim"])
-        other = [g for g in _gaps(b, forced=False, **gap_kw)
-                 if g not in cap]
-        if not cap or other:
-            continue   # excluded for a non-capacity reason too — normal
-        default_registry().counter("attn/fallback").inc()
-        key = (b.name, chosen.name)
-        if key not in _FALLBACK_WARNED:
-            _FALLBACK_WARNED.add(key)
-            warnings.warn(
-                f"attn auto-selection fell back from {b.name} "
-                f"(priority {b.priority}) to {chosen.name} "
-                f"(priority {chosen.priority}): {'; '.join(cap)}. "
-                f"Further fallbacks of this pair are counted on the obs "
-                f"'attn/fallback' counter without re-warning.",
-                RuntimeWarning, stacklevel=3)
-
-
 def _gaps(b: Backend, *, decode: bool, padded: bool,
           positioned: bool, scaled: bool, needs_grad: bool,
-          seq_len: Optional[int], head_dim: int, mesh_devices: int,
-          platform: str, forced: bool) -> List[str]:
+          mesh_devices: int, platform: str, forced: bool) -> List[str]:
     """Capability gaps of ``b`` for this call. ``needs_tpu`` only counts
     against auto-selection (forced backends fall back to interpret)."""
     gaps = []
@@ -376,7 +291,6 @@ def _gaps(b: Backend, *, decode: bool, padded: bool,
     if mesh_devices > 1 and not b.caps.supports_mesh:
         gaps.append(f"call runs on a {mesh_devices}-device mesh but "
                     f"supports_mesh=False")
-    gaps += _capacity_gaps(b, seq_len=seq_len, head_dim=head_dim)
     if not forced and b.caps.needs_tpu and platform != "tpu":
         gaps.append(f"needs_tpu on platform {platform!r}")
     return gaps
@@ -395,13 +309,15 @@ def resolve(spec: AttentionSpec, *, decode: bool = False,
     ``needs_grad``: the caller will differentiate through the result
     (train paths announce this) — non-differentiable backends are
     excluded / refused.
+    ``seq_len`` is accepted and ignored: no backend is capped by
+    sequence length (bench/tests/test_imagenet64_cell.py still passes
+    it).
     """
     mesh_devices = getattr(mesh, "size", 1) if mesh is not None else 1
     gap_kw = dict(decode=decode, padded=padded, positioned=positioned,
                   needs_grad=needs_grad,
-                  scaled=spec.logit_scale is not None, seq_len=seq_len,
-                  head_dim=spec.head_dim, mesh_devices=mesh_devices,
-                  platform=platform)
+                  scaled=spec.logit_scale is not None,
+                  mesh_devices=mesh_devices, platform=platform)
     if impl is not None:
         b = get(spec.variant, impl)
         gaps = _gaps(b, forced=True, **gap_kw)
@@ -411,8 +327,7 @@ def resolve(spec: AttentionSpec, *, decode: bool = False,
             try:
                 alt = resolve(spec, decode=decode, padded=padded,
                               positioned=positioned, needs_grad=needs_grad,
-                              seq_len=seq_len, mesh=mesh, impl=None,
-                              platform=platform)
+                              mesh=mesh, impl=None, platform=platform)
             except BackendResolutionError:
                 alt = None
             if alt is not None:
@@ -433,8 +348,4 @@ def resolve(spec: AttentionSpec, *, decode: bool = False,
         raise BackendResolutionError(
             f"no registered backend for variant {spec.variant!r} covers "
             f"this call ({detail})")
-    chosen = max(ok, key=lambda b: b.priority)
-    skipped = [b for b in cands if b.priority > chosen.priority]
-    if skipped:
-        _note_capacity_fallback(skipped, chosen, gap_kw)
-    return chosen
+    return max(ok, key=lambda b: b.priority)

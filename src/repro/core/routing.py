@@ -15,14 +15,13 @@ Pipeline (per head):
 Complexity: O(nkd) for step 2 + O(k w^2 d) = O(n^2 d / k) for step 4;
 k = sqrt(n) gives the paper's O(n^1.5 d).
 
-The O(k w^2 d) attention (step 4) is the compute hot-spot and has two
-Pallas TPU kernels (`repro.kernels.routing_attention`); this module is the
-pure-JAX reference and the default on CPU. `impl="pallas"` runs the
-*gathered* kernel (XLA materializes the (B,H,k,w,dh) blocks, the kernel
-streams them); `impl="pallas_fused"` runs the *gather-free* kernel: q/k/v
-stay in sequence layout, the membership indices ride in via scalar
-prefetch, and steps 4's gathers never touch HBM (DESIGN.md §9). Both
-kernel paths are differentiable (custom flash-style VJPs).
+The O(k w^2 d) attention (step 4) is the compute hot-spot. `impl="xla"`
+(the default, and the reference) gathers (B,H,k,w,dh) blocks and attends
+them in XLA; `impl="pallas_fused"` runs the Pallas TPU kernel
+(`repro.kernels.routing_attention`): q/k/v stay in sequence layout, the
+membership indices ride in as per-cluster SMEM blocks, and step 4's
+gathers never touch HBM (DESIGN.md §9). The kernel chooses its own
+memory plan from N·dh and is differentiable (a custom flash-style VJP).
 """
 from __future__ import annotations
 
@@ -37,12 +36,7 @@ from repro.core.kmeans import (KMeansState, cluster_scores, ema_update,
 from repro.obs.trace import span
 
 _BIG_NEG = -1e9
-
-# fused-kernel impl names -> the `paged` argument of the kernel entry
-# point (None = auto-switch on the VMEM residency budget)
-_FUSED_IMPLS = {"pallas_fused": None,
-                "pallas_fused_paged": True,
-                "pallas_fused_unpaged": False}
+_IMPLS = ("xla", "pallas_fused")
 
 
 class RoutingOutput(NamedTuple):
@@ -119,15 +113,16 @@ def routed_attention(q: jax.Array,
         blocks order-correct.
     pad_mask: (B, N) bool, True = real token. Padding is excluded from
         top-k selection, attention, and centroid updates (paper Section 4.1).
-    impl: "xla" reference | "pallas" gathered kernel | "pallas_fused"
-        gather-free kernel (sequence-layout q/k/v, SMEM membership
-        blocks — no (B,H,k,w,dh) q/k/v intermediates in HBM; the
-        memory plan auto-switches to double-buffered VMEM paging past the
-        residency budget) | "pallas_fused_paged" / "pallas_fused_unpaged"
-        force that plan.
-    interpret: Pallas interpret mode for the kernel impls; None derives
+    impl: "xla" reference | "pallas_fused" gather-free kernel
+        (sequence-layout q/k/v, SMEM membership blocks — no (B,H,k,w,dh)
+        q/k/v intermediates in HBM; the kernel's memory plan switches to
+        double-buffered row DMA from HBM past its residency budget).
+    interpret: Pallas interpret mode for the kernel impl; None derives
         from the platform (compiled on TPU, interpret elsewhere).
     """
+    if impl not in _IMPLS:
+        raise ValueError(f"routed_attention: impl {impl!r} is not one of "
+                         f"{_IMPLS}")
     B, H, N, dh = q.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(N, dtype=jnp.int32), (B, N))
@@ -183,20 +178,17 @@ def routed_attention(q: jax.Array,
             scores_k = cluster_scores(r_k, state.mu)
             k_idx = balanced_topk(scores_k, w, pad_mask)
 
-    if impl in _FUSED_IMPLS:
+    if impl == "pallas_fused":
         # gather-free: q/k/v stay in sequence layout; the kernel pulls
         # member rows through per-cluster SMEM index blocks and the mask
         # compares pre-gathered member positions. Shared QK passes the
-        # one index array (keys are the q rows). The paged suffix forces
-        # the kernel's memory plan; bare "pallas_fused" auto-switches on
-        # the VMEM residency budget.
+        # one index array (keys are the q rows).
         from repro.kernels import ops as kops
         og = kops.routed_attention_fused(
             r_q, None if shared else k_attn, v, q_idx,
             None if shared else k_idx,
             positions.astype(jnp.int32), causal=cfg.causal,
-            kvalid=pad_mask, interpret=interpret,
-            paged=_FUSED_IMPLS[impl])
+            kvalid=pad_mask, interpret=interpret)
         attn = None
     else:
         with span("routing/gather"):
@@ -219,17 +211,9 @@ def routed_attention(q: jax.Array,
                 valid_k = jnp.take_along_axis(
                     vm, k_idx.reshape(B, H, -1), axis=2).reshape(pos_k.shape)
 
-        if impl == "pallas":
-            from repro.kernels import ops as kops
-            og = kops.routed_attention_blocks(
-                qg, kg, vg, pos_q, pos_k, causal=cfg.causal,
-                valid_k=valid_k, interpret=interpret)
-            attn = None
-        else:
-            with span("routing/attend"):
-                og, attn = _block_attention(qg, kg, vg, pos_q, pos_k,
-                                            cfg.causal, valid_k,
-                                            return_attn)
+        with span("routing/attend"):
+            og, attn = _block_attention(qg, kg, vg, pos_q, pos_k,
+                                        cfg.causal, valid_k, return_attn)
 
     with span("routing/scatter"):
         out = _scatter_rows(og, q_idx, N, cfg.scatter_mode)

@@ -13,9 +13,8 @@
   keeps whole (N, dh) sequence planes resident in VMEM vs streams member
   rows from HBM, and the VMEM limit each plan compiles with;
   ``fused_cluster_bytes`` / ``FUSED_CLUSTER_BYTES``: what one cluster's
-  rows and blocks take of that limit, and the most they may. The kernel
-  layer, the backend registry, and the benches all derive from these so
-  the switch point cannot drift between them.
+  rows and blocks take of that limit, and the most they may. The plan
+  is chosen here alone, from N·dh; no caller selects it.
 """
 from __future__ import annotations
 
@@ -68,16 +67,12 @@ def fused_cluster_bytes(w: int, dh: int, planes: int) -> int:
     return (planes * 2 + 2 * (1 + 3)) * rows + 2 * 4 * w * 4
 
 
-# seq_len·head_dim cap of the forced resident plan, for the registry:
-# the three-plane (separate keys) worst case
-FUSED_RESIDENT_ELEMS = FUSED_RESIDENT_BYTES // (3 * 2 * 4)
-
-
 def fused_paged_default(n: int, dh: int, planes: int,
                         paged: Optional[bool] = None) -> bool:
     """Resolve a ``paged`` argument for the fused routing kernel: None
-    pages exactly when the resident planes would exceed
-    ``FUSED_RESIDENT_BYTES``; an explicit bool wins."""
+    (the program's only value) pages exactly when the resident planes
+    would exceed ``FUSED_RESIDENT_BYTES``; an explicit bool (a test
+    seam) wins."""
     if paged is None:
         return fused_resident_bytes(n, dh, planes) > FUSED_RESIDENT_BYTES
     return bool(paged)

@@ -44,16 +44,6 @@ def local_attention(q, k, v, window, causal=True, interpret=None):
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "bq", "bk",
-                                             "interpret"))
-def routed_attention_blocks(qg, kg, vg, pos_q, pos_k, causal=True,
-                            valid_k=None, bq=128, bk=128, interpret=None):
-    with span("kernels/routed_attention_blocks"):
-        return _routing.routed_attention_blocks(
-            qg, kg, vg, pos_q, pos_k, causal=causal, valid_k=valid_k,
-            bq=bq, bk=bk, interpret=interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("causal", "bq", "bk",
                                              "interpret", "paged"))
 def routed_attention_fused(q, k, v, q_idx, k_idx, positions, causal=True,
                            kvalid=None, bq=128, bk=128, interpret=None,
@@ -66,7 +56,8 @@ def routed_attention_fused(q, k, v, q_idx, k_idx, positions, causal=True,
     ``paged=None`` auto-switches the memory plan on the VMEM residency
     budget (``FUSED_RESIDENT_BYTES``): whole-plane resident below it,
     member rows DMA'd from HBM above — no sequence-length cliff; the
-    cluster size w is bounded by ``FUSED_CLUSTER_BYTES``."""
+    cluster size w is bounded by ``FUSED_CLUSTER_BYTES``. True/False
+    force a plan (a test seam: the program passes None)."""
     with span("kernels/routed_attention_fused"):
         return _routing.routed_attention_fused(
             q, k, v, q_idx, k_idx, positions, causal=causal, kvalid=kvalid,

@@ -1,4 +1,5 @@
-"""Pure-jnp oracles for every Pallas kernel (the ground truth in tests).
+"""Pure-jnp oracles for the flash and local Pallas kernels (the ground
+truth in tests; the routing kernel's is ``core.routing._block_attention``).
 
 These are *definitions*, deliberately naive: O(n^2) materialized logits with
 fp32 softmax. The framework's XLA paths (core/attention.py etc.) are
@@ -49,21 +50,3 @@ def local_attention_ref(q, k, v, window, causal=True):
     o = jnp.einsum("bhgnm,bhmd->bhgnd", p.astype(v.dtype), v)
     return o.reshape(B, H, N, dh)
 
-
-def routed_attention_blocks_ref(qg, kg, vg, pos_q, pos_k, causal=True,
-                                valid_k=None):
-    """Intra-cluster attention on gathered blocks.
-
-    qg/kg/vg: (B,H,k,w,dh); pos_q/pos_k: (B,H,k,w) int32.
-    The causal mask compares *original sequence positions*.
-    """
-    dh = qg.shape[-1]
-    s = jnp.einsum("bhkwd,bhkud->bhkwu", qg, kg).astype(jnp.float32)
-    s = s / jnp.sqrt(dh)
-    if causal:
-        s = jnp.where(pos_q[..., :, None] >= pos_k[..., None, :], s,
-                      _BIG_NEG)
-    if valid_k is not None:
-        s = jnp.where(valid_k[..., None, :], s, _BIG_NEG)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhkwu,bhkud->bhkwd", p.astype(vg.dtype), vg)

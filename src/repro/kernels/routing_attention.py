@@ -1,19 +1,14 @@
-"""Routed (intra-cluster) attention — Pallas TPU kernels. THE paper hot-spot.
+"""Routed (intra-cluster) attention — the Pallas TPU kernel. THE paper
+hot-spot.
 
-Two kernels implement stage 2 of the TPU adaptation (DESIGN.md §3, §9):
-
-``routed_attention_blocks`` — the original *gathered* kernel: XLA
-materializes (B,H,k,w,dh) copies of q/k/v (three HBM round-trips of the
-whole sequence, four in shared-QK mode before the dedupe) and the kernel
-streams the cluster blocks with flash-style online softmax.
-
-``routed_attention_fused`` — the *gather-free* kernel: q/k/v stay in
-sequence layout (B,H,N,dh). The grid is (B·H, k), one step a cluster.
-A step pulls its cluster's w member rows of q and v (and of k when keys
-are not shared) with one-row ``make_async_copy`` DMAs into (w, dh) VMEM
-buffers, once each — the page-table trick TPU paged attention uses, at
-row granularity. All of a buffer's copies signal one DMA semaphore, and
-one wait whose descriptor covers the whole buffer retires them (a DMA
+``routed_attention_fused`` implements stage 2 of the TPU adaptation
+(DESIGN.md §3, §9) without gathering: q/k/v stay in sequence layout
+(B,H,N,dh). The grid is (B·H, k), one step a cluster. A step pulls its
+cluster's w member rows of q and v (and of k when keys are not shared)
+with one-row ``make_async_copy`` DMAs into (w, dh) VMEM buffers, once
+each — the page-table trick TPU paged attention uses, at row
+granularity. All of a buffer's copies signal one DMA semaphore, and one
+wait whose descriptor covers the whole buffer retires them (a DMA
 semaphore counts bytes). Each buffer has two slots: cluster c+1's copies
 are issued before cluster c's are waited on, so they overlap its compute;
 the cluster axis is sequential ("arbitrary") and each batch·head starts
@@ -24,30 +19,30 @@ pre-gathered in XLA (4 B/row). No gathered (B,H,k,w,dh) q/k/v tensor ever
 reaches HBM. In shared-QK causal mode the kernels take the one (q) index
 array and read keys from the q buffer.
 
-The compute loops over the held cluster's (bq, bk) sub-tiles in the
-order the gathered kernel's grid visits them, with the same online
-softmax and the same float32 matmuls. In causal mode it skips the pairs
-past the causal band when none of them holds an attendable (query, key)
-pair: query sub-tile iq then visits key sub-tiles [0, ``band_tiles``[iq])
-alone, the T(T+1)/2 of T² pairs that sorted members at increasing
-positions can need. Whether a cluster takes the band is read from its
-members' positions (``band_clusters``, in XLA beside the position
-gathers: each query sub-tile's latest position against each later key
-sub-tile's earliest; padded keys sit at SENTINEL), so a cluster whose
-members repeat across a sub-tile edge or whose positions fall visits
-every pair, as does non-causal mode. A skipped pair is one the mask
-covers entirely: it would add p = 0 with corr = 1 to (m, l, acc), and
-ds = 0 to dq, dk and dv, so skipping it changes no sum, and the two
-kernels' forward outputs agree bit for bit at the same (bq, bk). The
+The compute loops over the held cluster's (bq, bk) sub-tiles, query
+sub-tile by query sub-tile, key sub-tiles in ascending order, with
+flash-style online softmax and float32 matmuls. In causal mode it skips
+the pairs past the causal band when none of them holds an attendable
+(query, key) pair: query sub-tile iq then visits key sub-tiles
+[0, ``band_tiles``[iq]) alone, the T(T+1)/2 of T² pairs that sorted
+members at increasing positions can need. Whether a cluster takes the
+band is read from its members' positions (``band_clusters``, in XLA
+beside the position gathers: each query sub-tile's latest position
+against each later key sub-tile's earliest; padded keys sit at
+SENTINEL), so a cluster whose members repeat across a sub-tile edge or
+whose positions fall visits every pair, as does non-causal mode. A
+skipped pair is one the mask covers entirely: it would add p = 0 with
+corr = 1 to (m, l, acc), and ds = 0 to dq, dk and dv, so skipping it
+changes no sum, and the band and every-pair sides agree bit for bit. The
 band is a static loop bound under one branch a cluster, not a branch a
 pair: on a v5e, a branch a pair with the softmax state in VMEM scratch
 made the forward slower than visiting every pair (PERF.md §6). A
 cluster's buffers and blocks are O(w·dh); a w whose cluster does not fit
 ``FUSED_CLUSTER_BYTES`` (kernels/common.py) is refused at trace time.
 
-The fused kernel has two memory plans that differ only in where the row
-DMAs read from (``paged=None`` auto-switches on the byte budget in
-kernels/common.py):
+The kernel has two memory plans that differ only in where the row DMAs
+read from, chosen from N·dh by ``fused_paged_default`` (kernels/
+common.py):
 
 * *resident* — the (N, dh) sequence plane of the current batch·head is
   the kernel's input block: one bulk DMA per plane, row DMAs VMEM->VMEM.
@@ -60,25 +55,19 @@ outputs are bit-identical. Single-row DMAs need 32-bit rows (Mosaic tiles
 float32 in XLA before the kernel; the kernel computes in float32 either
 way, its matmuls at full float32 precision (``_dot``).
 
-Both kernels are differentiable (``jax.custom_vjp``): the forward emits
+The kernel is differentiable (``jax.custom_vjp``): the forward emits
 per-row lse stats (m + log l); the backward recomputes p = exp(s - lse)
-tile by tile — no (w x w) matrix is ever stored. The gathered backward
-runs a dq kernel (KV-sequential grid) plus a dk/dv kernel (Q-sequential
-grid). The fused backward is one kernel on the forward's per-cluster grid
-and fetch plan: each p/ds sub-tile feeds dq, dk and dv, and the kernel
-writes per-cluster gradient blocks that XLA scatter-adds to sequence
-layout (duplicate memberships accumulate, exactly the transpose of the
-implicit gather).
+tile by tile — no (w x w) matrix is ever stored. The backward is one
+kernel on the forward's per-cluster grid and fetch plan: each p/ds
+sub-tile feeds dq, dk and dv, and the kernel writes per-cluster gradient
+blocks that XLA scatter-adds to sequence layout (duplicate memberships
+accumulate, exactly the transpose of the implicit gather).
 
 Row stats (lse, dsum) and positions travel as (..., 1, w) arrays so that
-their blocks' last two dims tile on the chip.
-
-Grid: (B·H·k clusters, w/bq, w/bk) gathered, KV axis sequential, (m, l,
-acc) scratch in VMEM; (B·H, k) fused, sub-tiles unrolled in the body.
-MXU-aligned: bq = bk = 128 default. The fused kernel's planes and member
-rows are ``row_lanes(dh)`` wide (a one-row DMA moves whole 128-lane rows,
-so dh = 64 rows are zero-padded to 128 in XLA); each loaded tile is cut
-back to dh, and its outputs are dh wide.
+their blocks' last two dims tile on the chip. MXU-aligned: bq = bk = 128
+default. The planes and member rows are ``row_lanes(dh)`` wide (a one-row
+DMA moves whole 128-lane rows, so dh = 64 rows are zero-padded to 128 in
+XLA); each loaded tile is cut back to dh, and its outputs are dh wide.
 """
 from __future__ import annotations
 
@@ -144,245 +133,6 @@ def _keep_mask(pq, pk, causal):
         return pq[:, None] >= pk[None, :]
     return jnp.broadcast_to((pk < SENTINEL)[None, :],
                             (pq.shape[0], pk.shape[0]))
-
-
-# ---------------------------------------------------------------------------
-# Gathered kernel (blocks already materialized by XLA)
-# ---------------------------------------------------------------------------
-def _kernel(q_ref, k_ref, v_ref, pq_ref, pk_ref, o_ref, lse_ref,
-            m_ref, l_ref, acc_ref, *, causal, scale):
-    ik = pl.program_id(2)
-    nk = pl.num_programs(2)
-
-    @pl.when(ik == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    q = q_ref[0].astype(jnp.float32)                  # (bq, dh)
-    k = k_ref[0].astype(jnp.float32)                  # (bk, dh)
-    v = v_ref[0].astype(jnp.float32)
-    pq = pq_ref[0, 0]                                 # (bq,) int32
-    pk = pk_ref[0, 0]                                 # (bk,) int32
-    s = _dot(q, k, 1, 1) * scale
-    keep = _keep_mask(pq, pk, causal)
-    s = jnp.where(keep, s, _NEG)
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(-1))
-    p = jnp.where(keep, jnp.exp(s - m_new[:, None]), 0.0)
-    corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + p.sum(-1)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + \
-        _dot(p, v, 1, 0)
-    m_ref[...] = m_new
-
-    @pl.when(ik == nk - 1)
-    def _done():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_ref[...] + jnp.log(l)
-
-
-def _g_dq_kernel(q_ref, k_ref, v_ref, pq_ref, pk_ref, do_ref, lse_ref,
-                 dsum_ref, dq_ref, dq_acc, *, causal, scale):
-    ik = pl.program_id(2)
-    nk = pl.num_programs(2)
-
-    @pl.when(ik == 0)
-    def _init():
-        dq_acc[...] = jnp.zeros_like(dq_acc)
-
-    q = q_ref[0].astype(jnp.float32)
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
-    keep = _keep_mask(pq_ref[0, 0], pk_ref[0, 0], causal)
-    s = _dot(q, k, 1, 1) * scale
-    p = jnp.where(keep, jnp.exp(s - lse_ref[0, 0][:, None]), 0.0)
-    dp = _dot(do, v, 1, 1)
-    ds = p * (dp - dsum_ref[0, 0][:, None]) * scale
-    dq_acc[...] += _dot(ds, k, 1, 0)
-
-    @pl.when(ik == nk - 1)
-    def _done():
-        dq_ref[0] = dq_acc[...]
-
-
-def _g_dkv_kernel(q_ref, k_ref, v_ref, pq_ref, pk_ref, do_ref, lse_ref,
-                  dsum_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, causal,
-                  scale):
-    iq = pl.program_id(2)
-    nq = pl.num_programs(2)
-
-    @pl.when(iq == 0)
-    def _init():
-        dk_acc[...] = jnp.zeros_like(dk_acc)
-        dv_acc[...] = jnp.zeros_like(dv_acc)
-
-    q = q_ref[0].astype(jnp.float32)
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
-    keep = _keep_mask(pq_ref[0, 0], pk_ref[0, 0], causal)
-    s = _dot(q, k, 1, 1) * scale
-    p = jnp.where(keep, jnp.exp(s - lse_ref[0, 0][:, None]), 0.0)
-    dv_acc[...] += _dot(p, do, 0, 0)
-    dp = _dot(do, v, 1, 1)
-    ds = p * (dp - dsum_ref[0, 0][:, None]) * scale
-    dk_acc[...] += _dot(ds, q, 0, 0)
-
-    @pl.when(iq == nq - 1)
-    def _done():
-        dk_ref[0] = dk_acc[...]
-        dv_ref[0] = dv_acc[...]
-
-
-def _g_fwd_call(qf, kf, vf, pqf, pkf, causal, bq, bk, interpret):
-    n, w, dh = qf.shape
-    grid = (n, w // bq, w // bk)
-    out, lse = pl.pallas_call(
-        functools.partial(_kernel, causal=causal, scale=1.0 / (dh ** 0.5)),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bq, dh), lambda c, iq, ik: (c, iq, 0)),
-            pl.BlockSpec((1, bk, dh), lambda c, iq, ik: (c, ik, 0)),
-            pl.BlockSpec((1, bk, dh), lambda c, iq, ik: (c, ik, 0)),
-            pl.BlockSpec((1, 1, bq), lambda c, iq, ik: (c, 0, iq)),
-            pl.BlockSpec((1, 1, bk), lambda c, iq, ik: (c, 0, ik)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bq, dh), lambda c, iq, ik: (c, iq, 0)),
-            pl.BlockSpec((1, 1, bq), lambda c, iq, ik: (c, 0, iq)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, w, dh), qf.dtype),
-            jax.ShapeDtypeStruct((n, 1, w), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq, dh), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(qf, kf, vf, pqf, pkf)
-    return out, lse
-
-
-def _g_bwd_call(qf, kf, vf, pqf, pkf, out, lse, do, causal, bq, bk,
-                interpret):
-    n, w, dh = qf.shape
-    dsum = (do.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)
-    dsum = dsum.reshape(n, 1, w)
-    scale = 1.0 / (dh ** 0.5)
-    params = pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
-    q_at = lambda c, iq, ik: (c, iq, 0)
-    k_at = lambda c, iq, ik: (c, ik, 0)
-    rq_at = lambda c, iq, ik: (c, 0, iq)
-    rk_at = lambda c, iq, ik: (c, 0, ik)
-    dq = pl.pallas_call(
-        functools.partial(_g_dq_kernel, causal=causal, scale=scale),
-        grid=(n, w // bq, w // bk),
-        in_specs=[
-            pl.BlockSpec((1, bq, dh), q_at),
-            pl.BlockSpec((1, bk, dh), k_at),
-            pl.BlockSpec((1, bk, dh), k_at),
-            pl.BlockSpec((1, 1, bq), rq_at),
-            pl.BlockSpec((1, 1, bk), rk_at),
-            pl.BlockSpec((1, bq, dh), q_at),
-            pl.BlockSpec((1, 1, bq), rq_at),
-            pl.BlockSpec((1, 1, bq), rq_at),
-        ],
-        out_specs=pl.BlockSpec((1, bq, dh), q_at),
-        out_shape=jax.ShapeDtypeStruct((n, w, dh), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bq, dh), jnp.float32)],
-        compiler_params=params,
-        interpret=interpret,
-    )(qf, kf, vf, pqf, pkf, do, lse, dsum)
-
-    # swapped grid: key tile parallel, query sweep sequential
-    q_at2 = lambda c, ik, iq: (c, iq, 0)
-    k_at2 = lambda c, ik, iq: (c, ik, 0)
-    rq_at2 = lambda c, ik, iq: (c, 0, iq)
-    rk_at2 = lambda c, ik, iq: (c, 0, ik)
-    dk, dv = pl.pallas_call(
-        functools.partial(_g_dkv_kernel, causal=causal, scale=scale),
-        grid=(n, w // bk, w // bq),
-        in_specs=[
-            pl.BlockSpec((1, bq, dh), q_at2),
-            pl.BlockSpec((1, bk, dh), k_at2),
-            pl.BlockSpec((1, bk, dh), k_at2),
-            pl.BlockSpec((1, 1, bq), rq_at2),
-            pl.BlockSpec((1, 1, bk), rk_at2),
-            pl.BlockSpec((1, bq, dh), q_at2),
-            pl.BlockSpec((1, 1, bq), rq_at2),
-            pl.BlockSpec((1, 1, bq), rq_at2),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bk, dh), k_at2),
-            pl.BlockSpec((1, bk, dh), k_at2),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, w, dh), jnp.float32),
-            jax.ShapeDtypeStruct((n, w, dh), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bk, dh), jnp.float32),
-            pltpu.VMEM((bk, dh), jnp.float32),
-        ],
-        compiler_params=params,
-        interpret=interpret,
-    )(qf, kf, vf, pqf, pkf, do, lse, dsum)
-    return (dq.astype(qf.dtype), dk.astype(kf.dtype), dv.astype(vf.dtype))
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
-def _routed_gathered(causal, bq, bk, interpret, qf, kf, vf, pqf, pkf):
-    out, _ = _g_fwd_call(qf, kf, vf, pqf, pkf, causal, bq, bk, interpret)
-    return out
-
-
-def _routed_gathered_fwd(causal, bq, bk, interpret, qf, kf, vf, pqf, pkf):
-    out, lse = _g_fwd_call(qf, kf, vf, pqf, pkf, causal, bq, bk, interpret)
-    return out, (qf, kf, vf, pqf, pkf, out, lse)
-
-
-def _routed_gathered_bwd(causal, bq, bk, interpret, res, do):
-    qf, kf, vf, pqf, pkf, out, lse = res
-    dq, dk, dv = _g_bwd_call(qf, kf, vf, pqf, pkf, out, lse, do, causal,
-                             bq, bk, interpret)
-    return dq, dk, dv, float0_like(pqf), float0_like(pkf)
-
-
-_routed_gathered.defvjp(_routed_gathered_fwd, _routed_gathered_bwd)
-
-
-def routed_attention_blocks(qg, kg, vg, pos_q, pos_k, causal=True,
-                            valid_k=None, bq=128, bk=128,
-                            interpret=None):
-    """qg/kg/vg: (B,H,k,w,dh); pos_q/pos_k: (B,H,k,w) -> (B,H,k,w,dh).
-
-    Differentiable (custom flash-style VJP). ``interpret=None`` derives
-    from the platform (compiled on TPU, interpret elsewhere)."""
-    B, H, kc, w, dh = qg.shape
-    bq = min(bq, w)
-    bk = min(bk, w)
-    assert w % bq == 0 and w % bk == 0, (w, bq, bk)
-    n = B * H * kc
-    qf = qg.reshape(n, w, dh)
-    kf = kg.reshape(n, w, dh)
-    vf = vg.reshape(n, w, dh)
-    pqf = pos_q.reshape(n, 1, w).astype(jnp.int32)
-    pkf = pos_k.reshape(n, 1, w).astype(jnp.int32)
-    if valid_k is not None:
-        pkf = jnp.where(valid_k.reshape(n, 1, w), pkf, SENTINEL)
-    out = _routed_gathered(bool(causal), int(bq), int(bk),
-                           default_interpret(interpret), qf, kf, vf, pqf,
-                           pkf)
-    return out.reshape(B, H, kc, w, dh)
 
 
 # ---------------------------------------------------------------------------
@@ -498,9 +248,9 @@ def _fwd_kernel(*refs, shared, causal, scale, bq, bk, resident):
     qb, kb, vb = _cluster_rows(idx, srcs, bufs, sems, shared, resident)
     w, dh = o_ref.shape[-2:]
 
-    # the gathered kernel's (iq, ik) grid as loops over the held cluster:
-    # same sub-tiles, same online-softmax order, same arithmetic; a pair
-    # past hi[iq] would leave (m, l, acc) as they are
+    # online softmax over the held cluster's (iq, ik) sub-tiles, key
+    # sub-tiles in ascending order; a pair past hi[iq] would leave
+    # (m, l, acc) as they are
     def attend(hi):
         for iq in range(w // bq):
             rq = pl.ds(iq * bq, bq)
@@ -750,13 +500,14 @@ def routed_attention_fused(q, k, v, q_idx, k_idx, positions, causal=True,
     these). kvalid: (B,N) bool, True = attendable key (padding False).
     Returns per-cluster outputs (B,H,k,w,dh); callers scatter them back.
 
-    ``paged=None`` auto-selects the memory plan: whole-plane VMEM
-    residency while the planes fit the byte budget of
-    ``fused_paged_default``, row DMAs straight from HBM beyond it (VMEM
-    bounded by the cluster size w, not N). Pass True/False to force a
-    plan. A cluster whose rows do not fit ``FUSED_CLUSTER_BYTES`` of VMEM
-    is refused here (ValueError). Member positions are pre-gathered in
-    XLA (int32, 4 B/row) — still no gathered q/k/v tensor in HBM.
+    ``paged=None`` (every caller in the program) chooses the memory
+    plan from N·dh: whole-plane VMEM residency while the planes fit the
+    byte budget of ``fused_paged_default``, row DMAs straight from HBM
+    beyond it (VMEM bounded by the cluster size w, not N). Tests pass
+    True/False to run one plan at any size. A cluster whose rows do not
+    fit ``FUSED_CLUSTER_BYTES`` of VMEM is refused here (ValueError).
+    Member positions are pre-gathered in XLA (int32, 4 B/row) — still no
+    gathered q/k/v tensor in HBM.
 
     Differentiable: flash-style custom VJP that recomputes p from saved
     lse stats and scatter-adds per-cluster dq/dk/dv to sequence layout.

@@ -7,6 +7,7 @@ import sys
 
 import jax
 import jax.numpy as jnp
+import pytest
 
 _SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
@@ -43,3 +44,16 @@ def tree_abssum(t) -> float:
     d = jax.tree.map(lambda a: float(jnp.abs(jnp.asarray(a, jnp.float32)
                                              ).sum()), t)
     return jax.tree_util.tree_reduce(lambda a, b: a + b, d, 0.0)
+
+
+@pytest.fixture
+def paged_plan(monkeypatch):
+    """Run the fused routing kernel on its paged memory plan at every size:
+    the residency budget drops to 0 bytes. The kernel's jit wrapper keys
+    its trace cache on shapes, not on the budget, so traces are cleared on
+    entry and on exit."""
+    from repro.kernels import common
+    jax.clear_caches()
+    monkeypatch.setattr(common, "FUSED_RESIDENT_BYTES", 0)
+    yield
+    jax.clear_caches()

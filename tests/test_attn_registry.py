@@ -13,7 +13,6 @@ import numpy as np
 import pytest
 
 from repro import attn as A
-from repro.attn import registry
 from repro.attn.registry import Backend, Capabilities
 from repro.configs.base import ModelConfig, RoutingConfig
 from repro.core.kmeans import init_kmeans
@@ -71,13 +70,28 @@ def _case_kwargs(case, n=N):
 NON_REFERENCE = [b for b in A.registered() if b.impl != "xla"]
 
 
+def _matrix():
+    """Every non-reference backend on its own; the routing backends (all
+    of them run the fused kernel's apply) again on the kernel's paged
+    memory plan, which these small shapes never reach by themselves."""
+    name = lambda b: b.name.replace("/", ":")
+    return ([pytest.param(b, False, id=name(b)) for b in NON_REFERENCE]
+            + [pytest.param(b, True, id=name(b) + ":paged")
+               for b in NON_REFERENCE
+               if b.variant in ("routing", "local+routing")])
+
+
+MATRIX = _matrix()
+
+
 @pytest.mark.parametrize("case", ["causal", "gqa", "padded"])
-@pytest.mark.parametrize("backend", NON_REFERENCE,
-                         ids=lambda b: b.name.replace("/", ":"))
-def test_backend_matches_reference(backend, case):
+@pytest.mark.parametrize("backend,paged", MATRIX)
+def test_backend_matches_reference(backend, paged, case, request):
     """Matrix: every non-reference backend vs the xla reference on the
     same spec/inputs. Backends whose capabilities exclude a case must
     refuse it loudly instead of computing something else."""
+    if paged:
+        request.getfixturevalue("paged_plan")
     spec = _spec(backend.variant, gqa=(case == "gqa"))
     q, k, v, mu = _inputs(spec)
     kwargs = _case_kwargs(case)
@@ -93,15 +107,16 @@ def test_backend_matches_reference(backend, case):
     assert _maxdiff(out.out, ref.out) < TOL
 
 
-@pytest.mark.parametrize("backend", NON_REFERENCE,
-                         ids=lambda b: b.name.replace("/", ":"))
-def test_backend_grad_matches_reference(backend):
+@pytest.mark.parametrize("backend,paged", MATRIX)
+def test_backend_grad_matches_reference(backend, paged, request):
     """Grad leg of the matrix: every supports_grad backend's jax.grad
     must match the reference's — a kernel registered with a wrong (or
     missing) VJP cannot land. supports_grad defaults to False in the
     registry precisely so this leg is the only way to claim it."""
     if not backend.caps.supports_grad:
         pytest.skip(f"{backend.name} declares supports_grad=False")
+    if paged:
+        request.getfixturevalue("paged_plan")
     spec = _spec(backend.variant)
     q, k, v, mu = _inputs(spec)
 
@@ -156,8 +171,6 @@ def test_decode_cache_coherent(variant):
             continue
         ran.append(b.impl)
         assert b.layout.name in ("pages", "ring+pages")
-        # deprecation shim: the old string field mirrors the typed layout
-        assert b.caps.cache_layout == b.layout.name
         cache = A.init_decode_cache(spec, 2, 32, jnp.float32, impl=b.impl)
         for t in range(32):
             pos = jnp.full((2,), t, jnp.int32)
@@ -195,7 +208,7 @@ def test_explicit_positions_excluded_from_index_masking_kernels():
     # positions-aware backends still take them (routing gathers pos_q/k)
     r = _spec("routing")
     q2, k2, v2, mu = _inputs(r)
-    A.attend(r, q2, k2, v2, state=mu, positions=pos, impl="pallas")
+    A.attend(r, q2, k2, v2, state=mu, positions=pos, impl="pallas_fused")
 
 
 def test_logit_scale_excluded_from_baked_scale_backends():
@@ -240,25 +253,18 @@ def test_unknown_impl_lists_registered():
     q, k, v, _ = _inputs(spec)
     with pytest.raises(A.BackendResolutionError, match="pallas"):
         A.attend(spec, q, k, v, impl="cuda")
+    # the routing variants register one kernel impl: "pallas" is unknown
+    r = _spec("routing")
+    q, k, v, mu = _inputs(r)
+    with pytest.raises(A.BackendResolutionError,
+                       match=r"\['pallas_fused', 'pallas_paged', 'xla'\]"):
+        A.attend(r, q, k, v, state=mu, impl="pallas")
 
 
 def test_unknown_variant_rejected_at_spec():
     with pytest.raises(ValueError, match="variant"):
         A.AttentionSpec(variant="strided", num_heads=4, num_kv_heads=4,
                         head_dim=32)
-
-
-def test_max_seq_capability_enforced():
-    A.registry.register(Backend(
-        variant="full", impl="_test_short", apply=lambda *a, **kw: None,
-        caps=Capabilities(max_seq=64)))
-    try:
-        spec = _spec("full")
-        q, k, v, _ = _inputs(spec)          # N=256 > 64
-        with pytest.raises(A.BackendResolutionError, match="max_seq"):
-            A.attend(spec, q, k, v, impl="_test_short")
-    finally:
-        A.unregister("full", "_test_short")
 
 
 def test_auto_resolution_prefers_pallas_on_tpu_only():
@@ -269,90 +275,30 @@ def test_auto_resolution_prefers_pallas_on_tpu_only():
     assert A.resolve(spec, platform="tpu", padded=True).impl == "xla"
 
 
-def test_fused_routing_preferred_on_tpu():
-    """Auto-resolution takes the gather-free fused kernel over the
-    gathered pallas path on TPU (priority 20 vs 10), including under
-    needs_grad (it has a VJP). Decode resolves to the paged-decode
-    kernel (routing/pallas_paged) on TPU — the fused backend still
-    declares no decode path; pallas_paged registers after it at the same
-    priority, so the tie breaks toward fused for apply and toward the
-    paged kernel for decode (parity: tests/test_routing_decode.py)."""
-    for variant in ("routing", "local+routing"):
-        spec = _spec(variant)
-        assert A.resolve(spec, platform="tpu").impl == "pallas_fused"
-        assert A.resolve(spec, platform="tpu",
-                         needs_grad=True).impl == "pallas_fused"
-        assert A.resolve(spec, platform="cpu").impl == "xla"
-        assert A.decode_backend(spec, platform="tpu").impl == "pallas_paged"
-        assert A.decode_backend(spec, platform="cpu").impl == "xla"
-        # no VMEM-residency cliff anymore: the fused kernel auto-switches
-        # to the double-buffered paged memory plan past the residency
-        # budget, so auto-selection stays fused at every sequence length
-        assert A.resolve(spec, platform="tpu",
-                         seq_len=16384).impl == "pallas_fused"
-        assert A.resolve(spec, platform="tpu",
-                         seq_len=65536).impl == "pallas_fused"
-    wide = A.AttentionSpec(variant="routing", num_heads=4, num_kv_heads=4,
-                           head_dim=256, routing=ROUTING)
-    assert A.resolve(wide, platform="tpu",
-                     seq_len=8192).impl == "pallas_fused"
-    # ... but the *forced* unpaged plan keeps the cap, and refusing it
-    # names the auto-selected escape hatch
-    spec = _spec("routing")
-    with pytest.raises(A.BackendResolutionError,
-                       match=r"max_seq_elems.*\n.*pallas_fused"):
-        A.resolve(spec, platform="tpu", seq_len=65536,
-                  impl="pallas_fused_unpaged")
+# call kind -> (resolve kwargs, the impl auto-selection must pick)
+_AUTO_CALLS = {
+    "apply": (dict(platform="tpu"), "pallas_fused"),
+    "padded_apply": (dict(platform="tpu", padded=True), "pallas_fused"),
+    "needs_grad": (dict(platform="tpu", needs_grad=True), "pallas_fused"),
+    "decode": (dict(platform="tpu", decode=True), "pallas_paged"),
+    "off_tpu_apply": (dict(platform="cpu"), "xla"),
+    "mesh_apply": (dict(platform="tpu",
+                        mesh=jax.sharding.AbstractMesh((4,), ("data",))),
+                   "xla"),
+}
 
 
-def test_paged_fused_impls_in_parity_matrix():
-    """Both forced memory plans of the fused kernel are registered for
-    both routing variants — and therefore auto-picked-up by the
-    NON_REFERENCE parity matrix above (forward, padded, and grad legs
-    run against each without hand-listing them here)."""
-    names = {b.name for b in NON_REFERENCE}
-    for variant in ("routing", "local+routing"):
-        assert f"{variant}/pallas_fused_paged" in names
-        assert f"{variant}/pallas_fused_unpaged" in names
-        # forced-plan backends are escape hatches, not contenders:
-        # auto-selection must keep landing on the auto-switching impl
-        assert A.resolve(_spec(variant), platform="tpu").impl == \
-            "pallas_fused"
-
-
-def test_capacity_fallback_counts_and_warns_once():
-    """Auto-selection that skips a higher-priority backend purely on
-    sequence capacity (max_seq/max_seq_elems) increments the obs
-    'attn/fallback' counter every time and warns once per (excluded,
-    chosen) pair — the N=8k-silently-lands-on-a-slower-path failure
-    mode has a signal."""
-    import warnings
-    from repro.obs import default_registry
-    spec = _spec("full")
-    registry.register(Backend(
-        variant="full", impl="_test_capped",
-        apply=lambda *a, **k: None, priority=99,
-        caps=Capabilities(max_seq_elems=1024, supports_grad=True)))
-    try:
-        registry._FALLBACK_WARNED.clear()
-        ctr = default_registry().counter("attn/fallback")
-        before = ctr.value
-        # under the cap the capped backend wins outright: no fallback
-        assert A.resolve(spec, seq_len=16).impl == "_test_capped"
-        assert ctr.value == before
-        # past the cap: fall back to the best eligible backend, warn
-        with pytest.warns(RuntimeWarning,
-                          match=r"fell back from full/_test_capped"):
-            assert A.resolve(spec, seq_len=256).impl == "xla"
-        assert ctr.value == before + 1
-        # second occurrence: counted again, but not re-warned
-        with warnings.catch_warnings():
-            warnings.simplefilter("error", RuntimeWarning)
-            A.resolve(spec, seq_len=256)
-        assert ctr.value == before + 2
-    finally:
-        A.unregister("full", "_test_capped")
-        registry._FALLBACK_WARNED.clear()
+@pytest.mark.parametrize("call", sorted(_AUTO_CALLS))
+@pytest.mark.parametrize("variant", ["routing", "local+routing"])
+def test_auto_resolution_table(variant, call):
+    """The registry is the one place that chooses a routing kernel; this
+    table pins every row of that choice. On TPU, apply, padded and
+    differentiated calls take the fused kernel (priority 20, registered
+    before pallas_paged, which wins the tie for decode only, where fused
+    declares no decode path); off TPU and on a GSPMD mesh (every Pallas
+    backend declares supports_mesh=False) the reference serves."""
+    kwargs, want = _AUTO_CALLS[call]
+    assert A.resolve(_spec(variant), **kwargs).impl == want
 
 
 def test_mixed_local_half_uses_window_kernel(monkeypatch):
@@ -441,18 +387,6 @@ def test_cache_layout_protocol():
         # deprecated Backend accessors still delegate to the layout
         assert b.init_cache is lo.init and b.prefill_fill is lo.fill
         assert b.cache_head_axes == lo.head_axes
-
-
-def test_register_rejects_contradictory_layout_string():
-    """A backend whose deprecated caps.cache_layout string disagrees with
-    its typed layout is a registration error, not a silent shadowing."""
-    lo = A.CacheLayout(name="append", init=lambda *a: {}, fill=lambda *a: {})
-    with pytest.raises(ValueError, match="contradicts"):
-        registry.register(Backend(
-            variant="full", impl="_test_badlayout",
-            apply=lambda *a, **k: None, layout=lo,
-            caps=Capabilities(cache_layout="ring")))
-    A.unregister("full", "_test_badlayout")
 
 
 # ---------------------------------------------------------------------------

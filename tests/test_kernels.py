@@ -1,7 +1,8 @@
 """Pallas kernels vs pure-jnp oracles: shape/dtype sweeps (interpret=True),
-the custom-VJP gradient-parity suite, and fused-vs-gathered routing parity.
-The gather-free HLO guarantee of the fused kernel is checked on the
-compiled TPU program in tests/test_tpu_compile.py."""
+the custom-VJP gradient-parity suite, and the fused routing kernel against
+the XLA routing reference in both memory plans. The gather-free HLO
+guarantee of the fused kernel is checked on the compiled TPU program in
+tests/test_tpu_compile.py."""
 import jax
 import jax.numpy as jnp
 import pytest
@@ -59,6 +60,33 @@ def test_local_attention_sweep(dtype, B, H, Hkv, N, dh, w, causal):
     assert err < TOL[dtype], err
 
 
+def _rows(x, idx):
+    """(B,H,N,d) rows at (B,H,k,w) member indices -> (B,H,k,w,d)."""
+    B, H, kc, w = idx.shape
+    return jnp.take_along_axis(x, idx.reshape(B, H, -1, 1),
+                               axis=2).reshape(B, H, kc, w, x.shape[-1])
+
+
+def _seq_at(x, idx):
+    """(B,N) per-token values at (B,H,k,w) member indices."""
+    B, H, kc, w = idx.shape
+    src = jnp.broadcast_to(x[:, None], (B, H) + x.shape[1:])
+    return jnp.take_along_axis(src, idx.reshape(B, H, -1),
+                               axis=2).reshape(B, H, kc, w)
+
+
+def _fused_reference(q, k, v, qi, ki, pos, causal, kvalid):
+    """The XLA routing reference (core.routing._block_attention) on the
+    blocks the fused kernel reads in place: k None = shared-QK keys."""
+    from repro.core.routing import _block_attention
+    kk, kidx = (q, qi) if k is None else (k, ki)
+    vk = None if kvalid is None else _seq_at(kvalid, kidx)
+    og, _ = _block_attention(_rows(q, qi), _rows(kk, kidx), _rows(v, kidx),
+                             _seq_at(pos, qi), _seq_at(pos, kidx), causal,
+                             vk, False)
+    return og
+
+
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("B,H,kc,w,dh,bq,bk,causal,valid", [
     (2, 2, 4, 128, 64, 64, 64, True, False),
@@ -66,23 +94,32 @@ def test_local_attention_sweep(dtype, B, H, Hkv, N, dh, w, causal):
     (1, 1, 8, 128, 128, 128, 64, True, False),
     (2, 2, 2, 64, 64, 32, 64, False, False),
 ])
-def test_routed_blocks_sweep(dtype, B, H, kc, w, dh, bq, bk, causal, valid):
-    ks = jax.random.split(KEY, 6)
-    qg = _mk((B, H, kc, w, dh), dtype, ks[0])
-    kg = _mk((B, H, kc, w, dh), dtype, ks[1])
-    vg = _mk((B, H, kc, w, dh), dtype, ks[2])
-    pq = jax.random.randint(ks[3], (B, H, kc, w), 0, 4096)
-    pk = pq if causal else jax.random.randint(ks[4], (B, H, kc, w), 0, 4096)
-    vk = jax.random.bernoulli(ks[5], 0.85, (B, H, kc, w)) if valid else None
-    o = ops.routed_attention_blocks(qg, kg, vg, pq, pk, causal=causal,
-                                    valid_k=vk, bq=bq, bk=bk)
-    r = ref.routed_attention_blocks_ref(qg, kg, vg, pq, pk, causal=causal,
-                                        valid_k=vk)
-    err = float(jnp.abs(o.astype(jnp.float32) - r.astype(jnp.float32)).max())
+def test_fused_sweep(dtype, B, H, kc, w, dh, bq, bk, causal, valid):
+    """The fused kernel over shapes and dtypes, on sorted random members
+    (repeats allowed) at random positions: causal cases share QK, the
+    non-causal ones take separate keys. The reference runs in float32 on
+    the same (rounded) inputs."""
+    N = kc * w
+    ks = jax.random.split(KEY, 7)
+    q = _mk((B, H, N, dh), dtype, ks[0])
+    k = None if causal else _mk((B, H, N, dh), dtype, ks[1])
+    v = _mk((B, H, N, dh), dtype, ks[2])
+    qi = jnp.sort(jax.random.randint(ks[3], (B, H, kc, w), 0, N), axis=-1)
+    ki = None if causal else jnp.sort(
+        jax.random.randint(ks[4], (B, H, kc, w), 0, N), axis=-1)
+    pos = jax.random.randint(ks[5], (B, N), 0, 4096)
+    kvalid = jax.random.bernoulli(ks[6], 0.85, (B, N)) if valid else None
+    o = ops.routed_attention_fused(q, k, v, qi, ki, pos, causal=causal,
+                                   kvalid=kvalid, bq=bq, bk=bk)
+    f32 = lambda x: None if x is None else x.astype(jnp.float32)
+    r = _fused_reference(f32(q), f32(k), f32(v), qi, ki, pos, causal,
+                         kvalid)
+    assert o.dtype == jnp.dtype(dtype)
+    err = float(jnp.abs(o.astype(jnp.float32) - r).max())
     assert err < TOL[dtype], err
 
 
-def test_routing_module_pallas_equals_xla():
+def test_routing_module_fused_equals_xla():
     from repro.configs.base import RoutingConfig
     from repro.core.kmeans import init_kmeans
     from repro.core.routing import routed_attention
@@ -93,10 +130,10 @@ def test_routing_module_pallas_equals_xla():
     st = init_kmeans(ks[2], H, 4, dh)
     cfg = RoutingConfig(num_clusters=4)
     o_x = routed_attention(q, None, v, st, cfg, impl="xla").out
-    o_p = routed_attention(q, None, v, st, cfg, impl="pallas").out
     o_f = routed_attention(q, None, v, st, cfg, impl="pallas_fused").out
-    assert float(jnp.abs(o_x - o_p).max()) < 1e-5
     assert float(jnp.abs(o_x - o_f).max()) < 1e-5
+    with pytest.raises(ValueError, match="impl 'pallas'"):
+        routed_attention(q, None, v, st, cfg, impl="pallas")
 
 
 # ---------------------------------------------------------------------------
@@ -172,23 +209,26 @@ def _routing_case(case):
                                              share_qk=False), k, None),
         "noncausal_padded": (RoutingConfig(num_clusters=4, causal=False,
                                            share_qk=False), k, pm),
+        "causal_separate": (RoutingConfig(num_clusters=4, share_qk=False),
+                            k, None),
         "segmented": (RoutingConfig(num_clusters=4, segments=2), None,
                       None),
     }[case]
 
 
-@pytest.mark.parametrize("impl", ["pallas", "pallas_fused",
-                                  "pallas_fused_paged"])
+@pytest.mark.parametrize("plan", ["resident", "paged"])
 @pytest.mark.parametrize("case", ["causal_shared", "causal_shared_padded",
                                   "noncausal_separate", "noncausal_padded",
-                                  "segmented"])
-def test_routing_grad_parity(impl, case):
-    """Kernel outputs and VJPs (gathered, and fused in both memory plans)
-    vs the XLA reference through the full routing module, on every
-    mask/sharing regime, at dh = 64 (the fused kernel's rows padded to
-    128 lanes)."""
+                                  "causal_separate", "segmented"])
+def test_routing_grad_parity(plan, case, request):
+    """Fused kernel outputs and VJPs, in both memory plans, vs the XLA
+    reference through the full routing module, on every mask/sharing
+    regime, at dh = 64 (the kernel's rows padded to 128 lanes). Causal
+    separate keys read the band from other positions than the queries'."""
     from repro.core.kmeans import init_kmeans
     from repro.core.routing import routed_attention
+    if plan == "paged":
+        request.getfixturevalue("paged_plan")
     B, H, N, dh = 2, 4, 256, 64
     ks = jax.random.split(KEY, 4)
     q = jax.random.normal(ks[0], (B, H, N, dh))
@@ -205,7 +245,7 @@ def test_routing_grad_parity(impl, case):
         return f
 
     args = (0, 2) if k is None else (0, 1, 2)
-    (_, o), g = jax.value_and_grad(loss(impl), argnums=args,
+    (_, o), g = jax.value_and_grad(loss("pallas_fused"), argnums=args,
                                    has_aux=True)(q, k, v)
     (_, o_r), gr = jax.value_and_grad(loss("xla"), argnums=args,
                                       has_aux=True)(q, k, v)
@@ -213,93 +253,33 @@ def test_routing_grad_parity(impl, case):
     assert _grad_maxdiff(g, gr) < GRAD_TOL
 
 
-def test_routed_blocks_kernel_grad_parity():
-    """Gathered-kernel VJP vs the module reference directly at the kernel
-    interface (random memberships incl. degenerate no-attendable-key
-    rows, which must produce zero output and zero gradient)."""
-    from repro.core.routing import _block_attention
-    B, H, N, dh, kc, w = 2, 2, 256, 64, 4, 64
-    ks = jax.random.split(KEY, 7)
-    q = jax.random.normal(ks[0], (B, H, N, dh))
-    k = jax.random.normal(ks[1], (B, H, N, dh))
-    v = jax.random.normal(ks[2], (B, H, N, dh))
-    qi = jnp.sort(jax.random.randint(ks[3], (B, H, kc, w), 0, N), axis=-1)
-    ki = jnp.sort(jax.random.randint(ks[4], (B, H, kc, w), 0, N), axis=-1)
-    wt = jax.random.normal(ks[5], (B, H, kc, w, dh))
-    pos = jnp.broadcast_to(jnp.arange(N, dtype=jnp.int32), (B, N))
-
-    def gath(x, idx):
-        return jnp.take_along_axis(x, idx.reshape(B, H, -1, 1),
-                                   axis=2).reshape(B, H, kc, w, dh)
-
-    def posg(idx):
-        return jnp.take_along_axis(
-            jnp.broadcast_to(pos[:, None], (B, H, N)),
-            idx.reshape(B, H, -1), axis=2).reshape(B, H, kc, w)
-
-    pq, pk = posg(qi), posg(ki)
-
-    def f(q, k, v):
-        og = ops.routed_attention_blocks(gath(q, qi), gath(k, ki),
-                                         gath(v, ki), pq, pk, causal=True,
-                                         bq=32, bk=32)
-        return (og * wt).sum()
-
-    def fr(q, k, v):
-        og, _ = _block_attention(gath(q, qi), gath(k, ki), gath(v, ki),
-                                 pq, pk, True, None, False)
-        return (og * wt).sum()
-
-    g = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(fr, argnums=(0, 1, 2))(q, k, v)
-    assert _grad_maxdiff(g, gr) < GRAD_TOL
-
-
 # ---------------------------------------------------------------------------
-# Fused kernel: forward parity with the gathered kernel
+# Fused kernel: forward parity with the XLA reference
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("bq,bk", [(32, 32), (64, 32)])
 @pytest.mark.parametrize("shared,causal,valid", [
     (False, True, False), (False, False, True),
     (True, True, False), (True, True, True),
 ])
-def test_fused_forward_matches_gathered_kernel(shared, causal, valid, bq,
-                                               bk):
-    """Bit-level forward parity at the same (bq, bk): the fused kernel
-    fetches each cluster's rows once and loops over the sub-tiles the
-    gathered kernel's grid visits, in the same order. Shared-QK mode
-    takes the one index array (k_idx None)."""
+def test_fused_forward_matches_reference(shared, causal, valid, bq, bk):
+    """Forward parity with the XLA routing reference at several (bq, bk):
+    the fused kernel fetches each cluster's rows once and runs online
+    softmax over its sub-tiles. Shared-QK mode takes the one index array
+    (k_idx None)."""
     B, H, N, dh, kc, w = 2, 2, 256, 64, 4, 64
     ks = jax.random.split(KEY, 6)
     q = jax.random.normal(ks[0], (B, H, N, dh))
-    k = jax.random.normal(ks[1], (B, H, N, dh))
+    k = None if shared else jax.random.normal(ks[1], (B, H, N, dh))
     v = jax.random.normal(ks[2], (B, H, N, dh))
     qi = jnp.sort(jax.random.randint(ks[3], (B, H, kc, w), 0, N), axis=-1)
-    ki = qi if shared else jnp.sort(
+    ki = None if shared else jnp.sort(
         jax.random.randint(ks[4], (B, H, kc, w), 0, N), axis=-1)
     pos = jnp.broadcast_to(jnp.arange(N, dtype=jnp.int32), (B, N))
     kvalid = jax.random.bernoulli(ks[5], 0.9, (B, N)) if valid else None
-    kk = q if shared else k
-
-    def gath(x, idx):
-        return jnp.take_along_axis(x, idx.reshape(B, H, -1, 1),
-                                   axis=2).reshape(B, H, kc, w, dh)
-
-    def seqg(x, idx):
-        return jnp.take_along_axis(
-            jnp.broadcast_to(x[:, None], (B, H, N)),
-            idx.reshape(B, H, -1), axis=2).reshape(B, H, kc, w)
-
-    vk = None if kvalid is None else seqg(kvalid, ki)
-    og = ops.routed_attention_blocks(gath(q, qi), gath(kk, ki),
-                                     gath(v, ki), seqg(pos, qi),
-                                     seqg(pos, ki), causal=causal,
-                                     valid_k=vk, bq=bq, bk=bk)
-    of = ops.routed_attention_fused(q, None if shared else k, v, qi,
-                                    None if shared else ki, pos,
-                                    causal=causal, kvalid=kvalid, bq=bq,
-                                    bk=bk)
-    assert float(jnp.abs(og - of).max()) < 1e-6
+    of = ops.routed_attention_fused(q, k, v, qi, ki, pos, causal=causal,
+                                    kvalid=kvalid, bq=bq, bk=bk)
+    ref = _fused_reference(q, k, v, qi, ki, pos, causal, kvalid)
+    assert float(jnp.abs(of - ref).max()) < TOL["float32"]
 
 
 def _unique_members(key, B, H, N, kc):
@@ -332,8 +312,9 @@ def test_fused_forward_and_lse_bitwise_at_twelve_tiles(monkeypatch, members,
     """w/bq = 12, the rt-imagenet64 cell's sub-tiles a cluster side (w 1536
     at 128): with unique sorted members at increasing positions the
     causal kernel visits the band alone (78 of 144 pairs), with repeated
-    members every pair; either way output and lse equal the gathered
-    kernel's bit for bit."""
+    members every pair. The band's output and lse equal, bit for bit,
+    the same kernel's with every cluster's band flag down (every pair
+    visited)."""
     import repro.kernels.routing_attention as ra
     B, H, N, dh, kc, w, b = 1, 2, 768, 32, 4, 192, 16
     ks = jax.random.split(KEY, 6)
@@ -343,33 +324,29 @@ def test_fused_forward_and_lse_bitwise_at_twelve_tiles(monkeypatch, members,
           jnp.sort(jax.random.randint(ks[3], (B, H, kc, w), 0, N), axis=-1))
     pos = jnp.broadcast_to(jnp.arange(N, dtype=jnp.int32), (B, N))
     kvalid = jax.random.bernoulli(ks[5], 0.9, (B, N)) if valid else None
-
-    def rows(x):
-        return jnp.take_along_axis(x, qi.reshape(B, H, -1, 1),
-                                   axis=2).reshape(B, H, kc, w, dh)
-
-    def seqg(x):
-        return jnp.take_along_axis(jnp.broadcast_to(x[:, None], (B, H, N)),
-                                   qi.reshape(B, H, -1),
-                                   axis=2).reshape(B, H, kc, w)
-
-    pq = seqg(pos)
+    pq = _seq_at(pos, qi)
     band = ra.band_clusters(pq, pq, causal, b, b)
     assert bool(band.all()) == (causal and members == "unique")
     rec = {}
-    _spy_lse(monkeypatch, ra, "_g_fwd_call", rec)
     _spy_lse(monkeypatch, ra, "_fused_fwd_call", rec)
-    og = ops.routed_attention_blocks(
-        rows(q), rows(q), rows(v), pq, pq, causal=causal,
-        valid_k=None if kvalid is None else seqg(kvalid), bq=b, bk=b)
-    of = ops.routed_attention_fused(q, None if causal else q, v, qi,
-                                    None if causal else qi, pos,
-                                    causal=causal, kvalid=kvalid, bq=b, bk=b)
-    jax.block_until_ready((og, of))
-    assert bool(jnp.array_equal(og, of)), float(jnp.abs(og - of).max())
-    lse_g = rec["_g_fwd_call"].reshape(-1)
-    lse_f = rec["_fused_fwd_call"].reshape(-1)
-    assert bool(jnp.array_equal(lse_g, lse_f))
+
+    def fused():
+        o = ops.routed_attention_fused(q, None if causal else q, v, qi,
+                                       None if causal else qi, pos,
+                                       causal=causal, kvalid=kvalid, bq=b,
+                                       bk=b)
+        jax.block_until_ready(o)
+        jax.effects_barrier()
+        return o, rec.pop("_fused_fwd_call").reshape(-1)
+
+    o_band, lse_band = fused()
+    monkeypatch.setattr(ra, "band_clusters", lambda pq, pk, causal, bq, bk:
+                        jnp.zeros(pq.shape[:-1], bool))
+    jax.clear_caches()
+    o_every, lse_every = fused()
+    assert bool(jnp.array_equal(o_band, o_every)), \
+        float(jnp.abs(o_band - o_every).max())
+    assert bool(jnp.array_equal(lse_band, lse_every))
 
 
 def _band_case(case, B, H, N, kc):
@@ -529,18 +506,24 @@ def test_paged_double_buffer_chunk_counts(w):
 
 
 @pytest.mark.parametrize("case", ["causal_shared", "padded",
-                                  "noncausal_separate", "segmented"])
-def test_paged_fused_beyond_cliff_parity(case):
-    """The acceptance case: N*dh beyond the resident plan's registry cap
-    (8448*128 > FUSED_RESIDENT_ELEMS), run on the paged plan. Forward and
-    gradient parity vs the XLA reference through the full routing module,
-    across mask regimes."""
+                                  "noncausal_separate", "causal_separate",
+                                  "segmented"])
+def test_paged_fused_beyond_cliff_parity(case, monkeypatch, paged_plan):
+    """The paged plan at a paper-scale N (8448 = 33 clusters of 256, dh
+    128): forward and gradient parity vs the XLA reference through the
+    full routing module, across mask regimes. The auto plan here is
+    resident for shared QK (two planes, 17.3 MB of the 24 MiB budget) and
+    paged for separate keys; the fixture pages every call, and each
+    kernel call's plan is recorded."""
+    import repro.kernels.routing_attention as ra
     from repro.configs.base import RoutingConfig
     from repro.core.kmeans import init_kmeans
     from repro.core.routing import routed_attention
-    from repro.kernels.common import FUSED_RESIDENT_ELEMS
     B, H, N, dh, kc = 1, 1, 8448, 128, 33
-    assert N * dh > FUSED_RESIDENT_ELEMS
+    plans = []
+    auto = ra.fused_paged_default
+    monkeypatch.setattr(ra, "fused_paged_default",
+                        lambda *a: plans.append(auto(*a)) or plans[-1])
     ks = jax.random.split(KEY, 4)
     q = jax.random.normal(ks[0], (B, H, N, dh))
     v = jax.random.normal(ks[1], (B, H, N, dh))
@@ -548,17 +531,14 @@ def test_paged_fused_beyond_cliff_parity(case):
     st = init_kmeans(ks[2], H, kc, dh)
     pm = (jnp.broadcast_to(jnp.arange(N)[None, :] < N - 300, (B, N))
           if case == "padded" else None)
-    if case == "noncausal_separate":
-        cfg = RoutingConfig(num_clusters=kc, causal=False, share_qk=False)
+    if case in ("noncausal_separate", "causal_separate"):
+        cfg = RoutingConfig(num_clusters=kc, causal=case == "causal_separate",
+                            share_qk=False)
         k = jax.random.normal(jax.random.PRNGKey(11), (B, H, N, dh))
     else:
         cfg = RoutingConfig(num_clusters=kc,
                             segments=2 if case == "segmented" else 1)
         k = None
-    # shared-QK planes (2 x 8448 x 128 f32) still fit the resident byte
-    # budget and the segmented case halves the per-call N, so the paged
-    # plan is forced
-    impl = "pallas_fused_paged"
 
     def loss(impl):
         def f(q, k, v):
@@ -569,13 +549,14 @@ def test_paged_fused_beyond_cliff_parity(case):
 
     args = (0, 2) if k is None else (0, 1, 2)
     o = routed_attention(q, k, v, st, cfg, pad_mask=pm,
-                         update_state=False, impl=impl).out
+                         update_state=False, impl="pallas_fused").out
     orf = routed_attention(q, k, v, st, cfg, pad_mask=pm,
                            update_state=False, impl="xla").out
     assert float(jnp.abs(o - orf).max()) < TOL["float32"]
-    g = jax.grad(loss(impl), argnums=args)(q, k, v)
+    g = jax.grad(loss("pallas_fused"), argnums=args)(q, k, v)
     gr = jax.grad(loss("xla"), argnums=args)(q, k, v)
     assert _grad_maxdiff(g, gr) < GRAD_TOL
+    assert plans and all(plans), plans
 
 
 def _spy_paged_grid_specs(monkeypatch, calls):
@@ -664,6 +645,26 @@ def test_fused_auto_pages_past_residency_budget(monkeypatch):
     assert calls, "paged=None did not route past the shrunk budget"
 
 
+@pytest.mark.parametrize("arch,n,paged", [
+    ("rt-enwik8", 8192, False),        # 16 MiB of planes
+    ("rt-imagenet64", 12288, False),   # 24 MiB: exactly the budget
+    ("rt-enwik8", 16384, True),        # 32 MiB: past it
+])
+def test_fused_plan_at_cell_shapes(arch, n, paged):
+    """The plan the benchmark cells were measured on: shared-QK planes at
+    rt-enwik8's dh 128 and N 8192 stay resident, so do rt-imagenet64's
+    at dh 64 (128 lanes) and N 12288, at the budget exactly; twice
+    rt-enwik8's sequence pages."""
+    from repro.configs import get_config
+    from repro.kernels import common
+    dh = get_config(arch).head_dim_
+    planes = common.fused_resident_bytes(n, dh, 2)
+    assert common.fused_paged_default(n, dh, 2) is paged
+    assert (planes > common.FUSED_RESIDENT_BYTES) is paged
+    if arch == "rt-imagenet64":
+        assert planes == common.FUSED_RESIDENT_BYTES
+
+
 def test_fused_refuses_cluster_past_vmem_budget():
     """A cluster is held whole in VMEM, so its size w = N/k is bounded:
     rt-enwik8's w = 256 and N = 32768 in 32 clusters (w = 1024, separate
@@ -690,13 +691,13 @@ def test_interpret_default_derived_from_platform(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# Train path: impl="pallas" is legal under jax.grad end to end
+# Train path: the fused kernel is legal under jax.grad end to end
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("impl", ["pallas", "pallas_fused"])
-def test_train_step_on_pallas_kernels_decreases_loss(impl):
-    """make_train_step(impl=...) runs a 20-step loss-decreasing fit with
-    the Pallas kernels on the train path (interpret mode on CPU) — no
-    silent fallback to the XLA reference."""
+@pytest.mark.parametrize("plan", ["resident", "paged"])
+def test_train_step_on_pallas_kernels_decreases_loss(plan, request):
+    """make_train_step(impl="pallas_fused") runs a 20-step loss-decreasing
+    fit with the Pallas kernel on the train path (interpret mode on CPU),
+    in either memory plan — no silent fallback to the XLA reference."""
     from repro.configs.base import (ModelConfig, RoutingConfig, RunConfig,
                                     TrainConfig)
     from repro.data.synthetic import SyntheticLoader
@@ -709,8 +710,10 @@ def test_train_step_on_pallas_kernels_decreases_loss(impl):
     run = RunConfig(model=cfg, train=TrainConfig(
         global_batch=8, seq_len=64, steps=20, lr=3e-3, schedule="const",
         warmup_steps=5, remat="none"))
+    if plan == "paged":
+        request.getfixturevalue("paged_plan")
     ts = init_train_state(run, KEY)
-    step = jax.jit(make_train_step(run, impl=impl))
+    step = jax.jit(make_train_step(run, impl="pallas_fused"))
     loader = SyntheticLoader("markov", cfg.vocab_size, 8, 64)
     losses = []
     for _, b in zip(range(run.train.steps), loader):

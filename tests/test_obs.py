@@ -129,8 +129,7 @@ def test_committed_records_and_docs_pass_checks():
     import pathlib
     root = pathlib.Path(__file__).resolve().parent.parent
     from repro.obs.schema import validate_json_file
-    records = ([root / "BENCH_routing.json"]
-               + sorted((root / "benchmarks").glob("*smoke*.json")))
+    records = sorted((root / "benchmarks").glob("*smoke*.json"))
     assert records
     for rec in records:
         validate_json_file(str(rec))

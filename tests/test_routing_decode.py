@@ -134,7 +134,7 @@ def test_decode_resolution_prefers_paged_on_tpu(variant):
     assert attn.decode_backend(spec, platform="cpu").impl == "xla"
     # the priority-20 tie with pallas_fused breaks toward fused for apply
     # (registration order); paged only owns decode
-    assert registry.resolve(spec, seq_len=128, needs_grad=True,
+    assert registry.resolve(spec, needs_grad=True,
                             platform="tpu").impl == "pallas_fused"
     # same cluster-page cache layout on both decode paths: engines can
     # prefill under one impl and decode under the other

@@ -173,20 +173,31 @@ def test_local_attention_grad_parity(causal):
     assert _grad_maxdiff(g, gr) < GRAD_TOL
 
 
-@pytest.mark.parametrize("causal", [True, False])
-def test_local_attention_sub_tiled_grad_parity(causal):
+@pytest.mark.parametrize("H,Hkv,N,causal", [
+    pytest.param(2, 1, 2048, True, id="True"),
+    pytest.param(2, 1, 2048, False, id="False"),
+    # three blocks: the first (clamped previous block), a middle one and
+    # the last (clamped next block) each skip their own tiles
+    pytest.param(4, 2, 3072, True, id="3blocks-gqa-True"),
+    pytest.param(4, 2, 3072, False, id="3blocks-gqa-False"),
+    pytest.param(2, 2, 3072, True, id="3blocks-True"),
+])
+def test_local_attention_sub_tiled_grad_parity(H, Hkv, N, causal):
     """A window larger than its query sub-tile: the forward and dq run
-    four 256-row query sub-tiles of each 1024-row block against its 2048
-    keys, dk/dv four 256-row key sub-tiles against the whole query
-    blocks that attend them."""
+    four 256-row query sub-tiles of each 1024-row block against the key
+    tiles the mask leaves, dk/dv four 256-row key sub-tiles against the
+    query tiles that attend them."""
     from repro.kernels.local_attention import sub_tile
-    B, H, Hkv, N, dh, w = 1, 2, 1, 2048, 64, 1024
+    B, dh, w = 1, 64, 1024
     assert sub_tile(w, dh) == 256 and sub_tile(256, 128) == 256
     ks = jax.random.split(KEY, 4)
     q = jax.random.normal(ks[0], (B, H, N, dh))
     k = jax.random.normal(ks[1], (B, Hkv, N, dh))
     v = jax.random.normal(ks[2], (B, Hkv, N, dh))
     wt = jax.random.normal(ks[3], (B, H, N, dh))
+    o = ops.local_attention(q, k, v, window=w, causal=causal)
+    r = ref.local_attention_ref(q, k, v, window=w, causal=causal)
+    assert float(jnp.abs(o - r).max()) < TOL["float32"]
     f = lambda q, k, v: (ops.local_attention(q, k, v, window=w,
                                              causal=causal) * wt).sum()
     fr = lambda q, k, v: (ref.local_attention_ref(q, k, v, window=w,
@@ -194,6 +205,27 @@ def test_local_attention_sub_tiled_grad_parity(causal):
     g = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
     gr = jax.grad(fr, argnums=(0, 1, 2))(q, k, v)
     assert _grad_maxdiff(g, gr) < GRAD_TOL
+
+
+@pytest.mark.parametrize("n,w,dh,causal,share", [
+    # rt-imagenet64: 6 blocks of 16 sub-tiles of 128 rows
+    (12288, 2048, 64, True, 268288 / 393216),
+    # one sub-tile a block (rt-enwik8's w 256, every w <= 512): untouched
+    (8192, 256, 128, True, 1.0),
+    (8192, 256, 128, False, 1.0),
+    (512, 512, 64, True, 1.0),
+    # encoder mode skips only the two clamped edge blocks: (3·6 - 2)/(3·6)
+    (12288, 2048, 64, False, 16 / 18),
+    (3072, 1024, 64, False, 7 / 9),
+    # one block: its own r + 1 tiles of the parent's 2·nq a sub-tile
+    (1024, 1024, 64, True, 10 / 32),
+])
+def test_local_live_tile_share(n, w, dh, causal, share):
+    """The share of the whole-window kernels' score tiles the sub-tiled
+    kernels compute, counted from shapes."""
+    from repro.kernels.local_attention import live_tile_share
+    assert live_tile_share(n, w, dh, causal) == pytest.approx(share,
+                                                              rel=1e-12)
 
 
 def _routing_case(case):

@@ -108,13 +108,27 @@ IMG_N, IMG_DH, IMG_KC, IMG_WINDOW, IMG_HEADS = 12288, 64, 8, 2048, 8
 
 
 def test_local_kernel_compiles_past_its_sub_tile(one_chip):
-    """w = 2048: 128-row query sub-tiles against 4096 keys (a one-shot
-    (w x 2w) float32 score tile would be 32 MiB), with the gradient."""
+    """w = 2048: 128-row query sub-tiles against the key tiles the causal
+    mask leaves (a one-shot (w x 2w) float32 score tile would be 32 MiB),
+    with the gradient."""
     from repro.kernels.local_attention import (local_attention_kernel,
                                                sub_tile)
     assert sub_tile(IMG_WINDOW, IMG_DH) == 128
     x = _shape(one_chip, (1, IMG_HEADS, IMG_N, IMG_DH), "float32")
     fn = lambda q, k, v: local_attention_kernel(q, k, v, IMG_WINDOW,
+                                                interpret=False)
+    text = _compile(_grad(fn, (0, 1, 2)), x, x, x)
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
+def test_local_kernel_compiles_past_its_sub_tile_encoder(one_chip):
+    """Encoder mode at w = 2048: each sub-tile reads three whole (w, dh)
+    key/value blocks (and dk/dv three query blocks) beside its score
+    chunks, within the sub-tiled grid's scoped VMEM."""
+    from repro.kernels.local_attention import local_attention_kernel
+    x = _shape(one_chip, (1, IMG_HEADS, IMG_N, IMG_DH), "float32")
+    fn = lambda q, k, v: local_attention_kernel(q, k, v, IMG_WINDOW,
+                                                causal=False,
                                                 interpret=False)
     text = _compile(_grad(fn, (0, 1, 2)), x, x, x)
     assert text.count('custom_call_target="tpu_custom_call"') == 3
